@@ -1,8 +1,8 @@
 """Thin CLI shim over the bench suite (ethrex_tpu/perf/bench_suite.py).
 
-All measurement logic, backend probing, the CPU fallback, the
-append-only bench_history.jsonl, and the --check-regression gate live
-in the package module; this file stays at the repo root so
+All measurement logic, the no-chip refusal, the append-only
+bench_history.jsonl, and the --check-regression gate live in the
+package module; this file stays at the repo root so
 `python bench.py [--measure|--measure-N|--check-regression]` and the
 suite's own child-process re-invocations keep their historical entry
 point.  Everything public is re-exported so `import bench` users (tests,
@@ -10,8 +10,6 @@ CI scripts) see the same API as before the move.
 """
 
 from __future__ import annotations
-
-import subprocess  # noqa: F401 — tests monkeypatch bench.subprocess.run
 
 from ethrex_tpu.perf.bench_suite import *  # noqa: F401,F403
 from ethrex_tpu.perf.bench_suite import cli as _cli

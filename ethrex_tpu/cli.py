@@ -183,8 +183,10 @@ def _add_node_flags(parser: argparse.ArgumentParser):
                         "prover hydrates compiled programs from here in "
                         "deserialize time instead of recompiling — ship "
                         "it in a deploy image to kill cold-start "
-                        "(docs/PERFORMANCE.md); default: a "
-                        "host-fingerprinted /tmp directory")
+                        "(docs/PERFORMANCE.md); default: exec/ "
+                        "under the compile-cache root "
+                        "(JAX_COMPILATION_CACHE_DIR, else "
+                        "<checkout>/.jax_cache)")
 
 
 def _enable_compile_caches(args):
@@ -495,12 +497,14 @@ def _install_signal_handlers(stop_event: threading.Event):
     return stop_event
 
 
-def run_l2(args) -> int:
-    """`ethrex-tpu l2`: launch the sequencer stack — L2 node + block
-    producer + committer + proof coordinator + proof sender (+ optional
-    in-process prover) against a datadir with durable checkpoints
-    (reference: cmd/ethrex/cli.rs:562-676 `l2` subcommand tree +
-    crates/l2/sequencer/mod.rs start_l2)."""
+def start_l2_stack(args):
+    """Wire and start the sequencer stack — L2 node + block producer +
+    committer + proof coordinator + proof sender + JSON-RPC server (+
+    optional in-process prover clients) — from parsed `l2` arguments.
+    Returns a namespace (node, l1, seq, rollup, server, clients), or an
+    int exit code when the arguments cannot be satisfied.  `run_l2` and
+    chip_smoke.py both start the stack here, so the smoke drives exactly
+    what the CLI runs."""
     from .l2.l1_client import InMemoryL1
     from .l2.rollup_store import PersistentRollupStore, RollupStore
     from .l2.sequencer import Sequencer, SequencerConfig
@@ -596,6 +600,22 @@ def run_l2(args) -> int:
             client.start()
             clients.append(client)
             print(f"in-process {ptype} prover polling the coordinator")
+    import types
+
+    return types.SimpleNamespace(node=node, l1=l1, seq=seq, rollup=rollup,
+                                 server=server, clients=clients)
+
+
+def run_l2(args) -> int:
+    """`ethrex-tpu l2`: launch the sequencer stack (start_l2_stack)
+    against a datadir with durable checkpoints and serve until a signal
+    (reference: cmd/ethrex/cli.rs:562-676 `l2` subcommand tree +
+    crates/l2/sequencer/mod.rs start_l2)."""
+    stack = start_l2_stack(args)
+    if isinstance(stack, int):
+        return stack
+    node, seq, rollup = stack.node, stack.seq, stack.rollup
+    server, clients = stack.server, stack.clients
 
     # observability: sampler + SLO alerts + optional flight recorder
     # (fatal actor errors auto-snapshot via Sequencer's on_fatal hook)
@@ -641,7 +661,7 @@ def run_l2(args) -> int:
     return code
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     flags = argparse.ArgumentParser(add_help=False)
     _add_node_flags(flags)
     parser = argparse.ArgumentParser(
@@ -706,8 +726,11 @@ def main(argv=None):
     p_mon.add_argument("--url", default=_env("RPC_URL",
                                              "http://127.0.0.1:8545"))
     p_mon.add_argument("--interval", type=float, default=2.0)
+    return parser
 
-    args = parser.parse_args(argv)
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
     # repl/monitor subcommands don't take the shared node flags
     from .utils.tracing import setup_logging

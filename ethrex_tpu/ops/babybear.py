@@ -280,6 +280,20 @@ def inv_host(a: int) -> int:
     return pow(a, P - 2, P)
 
 
+def batch_inv_host(a: np.ndarray) -> np.ndarray:
+    """Elementwise a^(P-2) mod P on the host: canonical uint32 in and
+    out, exact in uint64 (P < 2^31, so every product fits)."""
+    base = np.asarray(a, dtype=np.uint64) % P
+    out = np.ones_like(base)
+    e = P - 2
+    while e:
+        if e & 1:
+            out = out * base % P
+        base = base * base % P
+        e >>= 1
+    return out.astype(np.uint32)
+
+
 def powers_host(base: int, n: int) -> np.ndarray:
     """[1, base, base^2, ...] canonical, as numpy uint32 (host precompute)."""
     out = np.empty(n, dtype=np.uint32)

@@ -135,25 +135,24 @@ def coset_evals_from_coeffs(coeffs, n_out: int, shift: int = bb.GENERATOR):
 
 def interpolate_host(values: np.ndarray) -> np.ndarray:
     """Canonical host interpolation: evaluations over the size-p subgroup
-    (natural order) -> coefficient vector.  O(p^2) naive inverse DFT —
-    used for small periodic/preprocessed columns only."""
+    (natural order) -> coefficient vector.  O(p^2) inverse DFT as one
+    numpy table product — used for small periodic/preprocessed columns
+    only."""
     p_len = len(values)
     log_p = p_len.bit_length() - 1
     if 1 << log_p != p_len:
         raise ValueError("periodic length must be a power of two")
     w_inv = bb.inv_host(bb.root_of_unity(log_p))
     n_inv = bb.inv_host(p_len)
-    out = np.empty(p_len, dtype=np.uint32)
-    vals = [int(v) % bb.P for v in values]
-    for k in range(p_len):
-        acc = 0
-        wk = pow(w_inv, k, bb.P)
-        term = 1
-        for i in range(p_len):
-            acc = (acc + vals[i] * term) % bb.P
-            term = term * wk % bb.P
-        out[k] = acc * n_inv % bb.P
-    return out
+    # out[k] = n^-1 * sum_i vals[i] * w^(-ik): one (p, p) table of
+    # w^(-ik mod p) in uint64 numpy — every product is reduced below
+    # 2^31 before the row sum, so p of them cannot overflow
+    idx = np.arange(p_len, dtype=np.int64)
+    table = bb.powers_host(w_inv, p_len).astype(np.uint64)[
+        np.outer(idx, idx) % p_len]
+    vals = np.asarray(values, dtype=np.uint64) % bb.P
+    acc = (table * vals[None, :] % bb.P).sum(axis=1) % bb.P
+    return (acc * n_inv % bb.P).astype(np.uint32)
 
 
 def domain_points(log_size: int, shift: int) -> np.ndarray:

@@ -34,7 +34,7 @@ import threading
 
 from ..utils.metrics import METRICS
 
-# taxonomy (docs/PERFORMANCE.md): the cross-device collectives GSPMD
+# classification (docs/PERFORMANCE.md): the cross-device collectives GSPMD
 # inserts at sharding boundaries, plus intra-device reshard copies
 COLLECTIVE_KINDS = ("all-gather", "all-reduce", "reduce-scatter",
                     "collective-permute", "all-to-all")

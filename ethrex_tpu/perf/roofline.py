@@ -9,102 +9,57 @@ continuous profiler provides, applied to proving kernels.
 
 Caveats (documented in docs/PERFORMANCE.md and carried in the report):
 
-- cost_analysis shape varies by jaxlib version (list of dicts, a bare
-  dict, None on some backends) and may omit either key; every form is
-  tolerated and missing numbers surface as null, never an error.
-- XLA counts u32 modular-arithmetic ops as "flops"; utilization against
-  a floating-point peak is a consistent *relative* signal across runs
-  on one backend, not an absolute MXU occupancy.
-- The peak is an estimate: override with ``ETHREX_PEAK_FLOPS`` (flop/s)
-  for a calibrated roof; otherwise a per-backend default is used.
+- XLA counts u32 modular-arithmetic ops as "flops"; the prover does
+  integer work, for which no published peak exists, so utilization
+  against the bf16 peak is a *relative* signal across runs on one
+  device kind, not an MXU occupancy.
+- The peak comes from a table keyed by the device's ``device_kind``.
+  A kind that is not in the table has no peak and no utilization
+  ("not measured") — never a default.
 
-Every entry point is exception-guarded: a failing cost_analysis can
-never fail a prove (acceptance criterion).
+The recording hooks are exception-guarded: a failing cost_analysis can
+never fail a prove.
 """
 
 from __future__ import annotations
 
-import os
 import threading
 
 from ..utils.metrics import record_kernel_flops
 
-# rough per-backend peak-FLOP/s defaults (override: ETHREX_PEAK_FLOPS).
-# tpu: one modern TPU chip's dense-unit order of magnitude; cpu: cores x
-# ~8 u32 SIMD lanes x ~2GHz — both deliberately coarse anchors.
-_PEAK_DEFAULTS = {"tpu": 275.0e12, "gpu": 80.0e12}
+# Published per-chip peaks, keyed by jax.devices()[0].device_kind.
+# "TPU v5 lite" is how a v5e reports itself.  Source: Google Cloud
+# documentation, "TPU v5e" system-architecture page — 197 TFLOP/s bf16,
+# 393 TOP/s int8, 16 GB HBM2e at 819 GB/s per chip.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197.0e12, "int8_ops": 393.0e12,
+                    "hbm_bytes_per_sec": 819.0e9},
+}
 
 
-def _cpu_peak() -> float:
-    return float(os.cpu_count() or 1) * 8.0 * 2.0e9
+def peak_flops_estimate(device_kind: str | None = None) -> float | None:
+    """bf16 peak FLOP/s of `device_kind` (default: the first device's
+    kind); None for a kind the table does not know."""
+    if device_kind is None:
+        import jax
 
-
-def peak_flops_estimate(backend: str | None = None) -> float | None:
-    env = os.environ.get("ETHREX_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    if backend is None:
-        try:
-            import jax
-
-            backend = jax.default_backend()
-        except Exception:
-            return None
-    if backend == "cpu":
-        return _cpu_peak()
-    return _PEAK_DEFAULTS.get(backend)
-
-
-def _cost_field(entry, key: str, attr: str):
-    """One cost/memory number from a dict entry (``entry[key]``) or an
-    attribute-style entry (``entry.attr``, newer jaxlib properties);
-    None when absent, non-numeric, or negative."""
-    if isinstance(entry, dict):
-        v = entry.get(key)
-    else:
-        try:
-            v = getattr(entry, attr, None)
-            if callable(v):
-                v = v()
-        except Exception:  # raising properties/accessors -> absent field
-            return None
-    if isinstance(v, bool) or not isinstance(v, (int, float)) or v < 0:
-        return None
-    return float(v)
+        device_kind = jax.devices()[0].device_kind
+    return DEVICE_PEAKS.get(device_kind, {}).get("bf16_flops")
 
 
 def _parse_cost(cost) -> dict:
-    """Normalize any cost_analysis() shape to {'flops', 'bytes'} with
-    float-or-None values.  jax 0.4.x returns a list with one dict per
-    computation; older/newer versions return a bare dict; newer jaxlib
-    AOT surfaces hand back property objects (``.flops`` /
-    ``.bytes_accessed``); CPU backends may return None or omit keys.
-    Every form degrades to partial rows, never an error."""
+    """Normalize cost_analysis() to {'flops', 'bytes'} with
+    float-or-None values.  jax 0.9 returns one dict for a compiled
+    executable (keys "flops", "bytes accessed"), or None where the
+    backend has no cost model; either key may be missing."""
     out = {"flops": None, "bytes": None}
-    if cost is None:
+    if not isinstance(cost, dict):
         return out
-    entries = cost if isinstance(cost, (list, tuple)) else [cost]
-    flops = 0.0
-    nbytes = 0.0
-    saw_flops = saw_bytes = False
-    for entry in entries:
-        if entry is None or isinstance(entry, (int, float, str)):
-            continue
-        f = _cost_field(entry, "flops", "flops")
-        if f is not None:
-            flops += f
-            saw_flops = True
-        b = _cost_field(entry, "bytes accessed", "bytes_accessed")
-        if b is not None:
-            nbytes += b
-            saw_bytes = True
-    if saw_flops:
-        out["flops"] = flops
-    if saw_bytes:
-        out["bytes"] = nbytes
+    for key, name in (("flops", "flops"), ("bytes accessed", "bytes")):
+        v = cost.get(key)
+        if isinstance(v, (int, float)) and not isinstance(v, bool) \
+                and v >= 0:
+            out[name] = float(v)
     return out
 
 
@@ -199,8 +154,8 @@ class RooflineRegistry:
                     if achieved and peak else None,
             })
         return {"peakFlopsEstimate": peak,
-                "peakSource": "env" if os.environ.get("ETHREX_PEAK_FLOPS")
-                else "default",
+                "peakSource": "device_kind table" if peak
+                else "not measured",
                 "kernels": kernels}
 
     def reset(self) -> None:

@@ -30,7 +30,7 @@ blob fails the frame check and is discarded for a clean fresh prove
 
 Env knobs (documented in docs/PROVER_RESILIENCE.md):
   ETHREX_PROOF_CKPT_DIR  checkpoint directory (default
-                         /tmp/ethrex_tpu_proof_ckpt_<host fingerprint>)
+                         <cache root>/proof_ckpt, utils/jax_cache)
   ETHREX_PROOF_CKPT_OFF  "1" disables checkpoint stores and loads
 """
 
@@ -164,9 +164,9 @@ def checkpoint_dir() -> str:
     env = os.environ.get("ETHREX_PROOF_CKPT_DIR")
     if env:
         return env
-    from ..utils.jax_cache import cache_dir as _fingerprinted
+    from ..utils.jax_cache import cache_dir as _cache_root
 
-    return _fingerprinted(prefix="/tmp/ethrex_tpu_proof_ckpt")
+    return os.path.join(_cache_root(), "proof_ckpt")
 
 
 def enabled() -> bool:

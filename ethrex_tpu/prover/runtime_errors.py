@@ -1,4 +1,4 @@
-"""Runtime-error taxonomy and the degraded-mesh fallback ladder.
+"""Runtime-error classification and the degraded-mesh fallback ladder.
 
 A crash inside the 86-96s `TpuBackend.prove` wall used to be
 indistinguishable from a poison batch: any exception burned the
@@ -9,8 +9,9 @@ accelerator runtime actually threw and routes each class differently:
     oom          XLA RESOURCE_EXHAUSTED / allocation failure — the
                  batch does not fit the current mesh.  Transient:
                  retry the failed phase down the degradation ladder
-                 (mesh/2 -> single device -> forced CPU); never burns
-                 quarantine budget.
+                 (mesh/2 -> single device of the same mesh); never
+                 burns quarantine budget.  Below one device there is
+                 nowhere to fall: the device's own error propagates.
     device_lost  a device or slice dropped out (connection to the
                  accelerator lost, slice health check failed, or the
                  injected `device.lost` fault).  Transient: same
@@ -27,10 +28,13 @@ with `parallel.mesh` device slicing, phase programs for a fallback
 layout hydrate through the same `stark/prover._phases` path (PR-12
 exec-cache hydration applies), and completed-phase checkpoints
 (prover/checkpoint) carry across rungs because proofs are
-bit-identical on any layout.  A `memory_gate` consults the AOT
-roofline bytes (`perf/roofline`, captured at compile time) against
-live device memory (`utils/jax_cache.runtime_telemetry`) to walk the
-same ladder BEFORE an OOM instead of after.
+bit-identical on any layout.  A `memory_gate` compares the per-device
+working set XLA's `memory_analysis()` reported when the AIR's phase
+programs were compiled (`perf/hlo_introspect`) against live free device
+memory (`utils/jax_cache.runtime_telemetry`) to walk the same ladder
+BEFORE an OOM instead of after.  Neither the ladder nor the gate ever
+leaves the mesh's platform: a layout that fits nowhere raises
+`MemoryGateError`.
 
 Env knobs (documented in docs/PROVER_RESILIENCE.md):
   ETHREX_MESH_DEGRADE_OFF    "1" disables the ladder and the memory
@@ -46,12 +50,6 @@ import os
 import threading
 
 from ..utils import faults
-
-try:  # jax.errors.JaxRuntimeError IS jaxlib's XlaRuntimeError
-    from jax.errors import JaxRuntimeError as XlaRuntimeError
-except Exception:  # pragma: no cover - jax always present in-tree
-    class XlaRuntimeError(RuntimeError):
-        """Stand-in when jax is unavailable (doc builds, lint)."""
 
 
 _LOCK = threading.Lock()
@@ -93,7 +91,7 @@ class TransientPhaseError(RuntimeError):
 
 
 def classify(exc: BaseException) -> str:
-    """Map an exception from a device phase onto the taxonomy."""
+    """Map an exception from a device phase onto the classification."""
     if isinstance(exc, NanPoisonError):
         return "nan_poison"
     if isinstance(exc, TransientPhaseError):
@@ -158,7 +156,7 @@ def check_phase_outputs(phase: str, arts) -> None:
 
 
 def guard_phase(phase: str, air_name: str, fn):
-    """Run one device phase under the fault legs and the taxonomy.
+    """Run one device phase under the fault legs and the classification.
 
     Fires the `backend.phase` error/delay legs and the `device.lost`
     site on entry (an error rule there simulates a slice dropping out
@@ -202,18 +200,13 @@ def ladder_enabled() -> bool:
     return os.environ.get("ETHREX_MESH_DEGRADE_OFF") != "1"
 
 
-def _mesh_identity(mesh):
-    if mesh is None:
-        return None
-    return (tuple(int(d.id) for d in mesh.devices.flat),
-            tuple(getattr(d, "platform", "?") for d in mesh.devices.flat))
-
-
 def degradation_ladder(mesh) -> list:
     """The fallback rungs below `mesh`, best first: half the devices,
-    a single device, then forced CPU.  Rungs equal to the current
-    layout are dropped; an empty list means nowhere left to fall."""
-    if not ladder_enabled():
+    then a single device — always devices of `mesh` itself, never
+    another platform.  Rungs equal to the current layout are dropped;
+    an empty list (no mesh, or one device) means nowhere left to fall
+    and the caller re-raises the device's own error."""
+    if mesh is None or not ladder_enabled():
         return []
     import numpy as np
 
@@ -221,28 +214,13 @@ def degradation_ladder(mesh) -> list:
 
     from ..parallel import mesh as mesh_lib
 
-    rungs, seen = [], {_mesh_identity(mesh)}
-
-    def push(m):
-        key = _mesh_identity(m)
-        if key not in seen:
-            seen.add(key)
-            rungs.append(m)
-
-    if mesh is not None:
-        devs = list(mesh.devices.flat)
-        if len(devs) >= 4:
-            push(Mesh(np.array(devs[: len(devs) // 2]), (mesh_lib.AXIS,)))
-        if len(devs) >= 2:
-            push(Mesh(np.array(devs[:1]), (mesh_lib.AXIS,)))
-    try:  # forced-CPU floor: host cores always exist and never OOM first
-        import jax
-
-        cpu = jax.local_devices(backend="cpu")[0]
-        push(Mesh(np.array([cpu]), (mesh_lib.AXIS,)))
-    except Exception:
-        if mesh is not None:
-            push(None)
+    devs = list(mesh.devices.flat)
+    rungs = []
+    if len(devs) >= 4:
+        rungs.append(Mesh(np.array(devs[: len(devs) // 2]),
+                          (mesh_lib.AXIS,)))
+    if len(devs) >= 2:
+        rungs.append(Mesh(np.array(devs[:1]), (mesh_lib.AXIS,)))
     return rungs
 
 
@@ -299,55 +277,67 @@ def _note_nan_poison(phase: str) -> None:
 
 # -- pre-prove memory gate --------------------------------------------------
 
-def _estimated_bytes(air_name: str):
-    """Peak per-phase bytes for this AIR from the AOT roofline records
-    (cost_analysis captured at compile time); None without data."""
-    try:
-        from ..perf import roofline
+class MemoryGateError(RuntimeError):
+    """The AIR's working set fits no layout of its own platform.  The
+    gate never moves a proof to another platform; it says what it
+    compared and lets the caller decide."""
 
-        best = None
-        for cell in roofline.report().get("kernels", []):
-            if cell.get("air") != air_name:
-                continue
-            b = cell.get("bytes")
-            if b and (best is None or b > best):
-                best = float(b)
-        return best
-    except Exception:
-        return None
+    def __init__(self, air_name: str, est_bytes: float,
+                 free_bytes: float, layout: str):
+        self.air_name = air_name
+        self.est_bytes = est_bytes
+        self.free_bytes = free_bytes
+        super().__init__(
+            f"memory gate: {air_name} needs an estimated "
+            f"{int(est_bytes)} bytes per device but layout {layout} has "
+            f"{int(free_bytes)} bytes free per device, and no smaller "
+            "layout of the same platform fits")
+
+
+def _estimated_bytes(air_name: str):
+    """Largest per-device working set among this AIR's compiled phase
+    programs: XLA's memory_analysis() (argument + output + temp + alias
+    bytes) as captured by perf/hlo_introspect at the same compile that
+    built them.  None before the AIR's first compile."""
+    from ..perf import hlo_introspect
+
+    peaks = [cell["memory"]["peakBytes"]
+             for cell in hlo_introspect.REGISTRY.report().get("kernels", [])
+             if cell.get("air") == air_name
+             and (cell.get("memory") or {}).get("peakBytes")]
+    return max(peaks) if peaks else None
 
 
 def _available_bytes(mesh):
-    """Free accelerator memory across the layout's devices from live
-    telemetry; None when the backend does not report limits (CPU)."""
-    try:
-        from ..utils.jax_cache import runtime_telemetry
+    """Free memory of the layout's tightest device (the estimate is per
+    device) from live telemetry; None when the backend does not report
+    limits (CPU)."""
+    from ..utils.jax_cache import runtime_telemetry
 
-        ids = (None if mesh is None
-               else {int(d.id) for d in mesh.devices.flat})
-        total = 0
-        saw = False
-        for dev in runtime_telemetry().get("devices", []):
-            if ids is not None and dev.get("id") not in ids:
-                continue
-            memory = dev.get("memory") or {}
-            limit = memory.get("bytes_limit")
-            if not limit:
-                continue
-            total += max(0, int(limit) - int(memory.get("bytes_in_use", 0)))
-            saw = True
-        return total if saw else None
-    except Exception:
-        return None
+    devices = runtime_telemetry().get("devices", [])
+    if mesh is None:
+        devices = devices[:1]               # the default device
+    else:
+        ids = {int(d.id) for d in mesh.devices.flat}
+        devices = [d for d in devices if d.get("id") in ids]
+    free = [max(0, int(m["bytes_limit"]) - int(m.get("bytes_in_use", 0)))
+            for m in (d.get("memory") or {} for d in devices)
+            if m.get("bytes_limit")]
+    return min(free) if free else None
 
 
 def memory_gate(air_name: str, mesh, est_bytes=None, avail_fn=None):
-    """Shrink the mesh BEFORE an OOM: if the AIR's estimated working
-    set exceeds the headroom share of free device memory on the
-    current layout, walk the degradation ladder until a rung fits (a
-    rung with unreported limits — CPU — always fits).  Returns the
-    layout to prove on; identical to `mesh` when data is missing or
-    everything fits."""
+    """Shrink the mesh BEFORE an OOM: if the AIR's estimated per-device
+    working set exceeds the headroom share of free memory on the
+    current layout, walk the degradation ladder — smaller meshes of the
+    same devices, whose tightest device may have more room; the
+    estimate stays the one compiled for the current layout, so a rung
+    is taken optimistically and the OOM ladder still stands behind it —
+    until a rung fits.  Returns the layout to prove on;
+    identical to `mesh` when data is missing (first compile, or a
+    backend that reports no limit) or everything fits.  Raises
+    MemoryGateError when nothing on the ladder fits: the proof never
+    leaves its platform."""
     if not ladder_enabled():
         return mesh
     est = est_bytes if est_bytes is not None else _estimated_bytes(air_name)
@@ -360,23 +350,22 @@ def memory_gate(air_name: str, mesh, est_bytes=None, avail_fn=None):
     avail_of = avail_fn or _available_bytes
     from ..parallel import mesh as mesh_lib
 
-    cur = mesh
-    avail = avail_of(cur)
+    avail = avail_of(mesh)
     if avail is None or est <= headroom * avail:
-        return cur
-    for rung in degradation_ladder(cur):
-        avail = avail_of(rung)
-        fits = avail is None or est <= headroom * avail
-        note_degradation(mesh_lib.shape_label(cur),
-                         mesh_lib.shape_label(rung), reason="memory_gate")
-        cur = rung
-        if fits:
-            return cur
-    return cur
+        return mesh
+    for rung in degradation_ladder(mesh):
+        rung_avail = avail_of(rung)
+        if rung_avail is None or est <= headroom * rung_avail:
+            note_degradation(mesh_lib.shape_label(mesh),
+                             mesh_lib.shape_label(rung),
+                             reason="memory_gate")
+            return rung
+    raise MemoryGateError(air_name, est, avail,
+                          mesh_lib.shape_label(mesh))
 
 
 def runtime_stats() -> dict:
-    """Live taxonomy/ladder counters for ethrex_health
+    """Live classification/ladder counters for ethrex_health
     (l2.prover.runtime) and the monitor panel."""
     with _LOCK:
         out = {"oomRetries": STATS["oom_retries"],

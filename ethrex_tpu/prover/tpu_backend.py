@@ -713,6 +713,24 @@ class TpuBackend(ProverBackend):
                     jobs.append((f"vm_circuits/BytecodeAir{idx}",
                                  "vm_circuits", _bc_job))
 
+        # the binding statement needs only public inputs, all known by
+        # now: build it first so that its phase programs — and, on the
+        # one-device path where the jobs run one after the other, the
+        # transfer circuit's — compile while the first job proves (a
+        # cold prover spends most of its first proof compiling)
+        digest = pub[16:24]
+        limbs = binding_limbs(encoded, r_pre, r_post, digest, vm_pub,
+                              tok_pub, bc_pubs)
+        bind_air = pair.Poseidon2SpongeAir(num_chunks=len(limbs) // 8)
+        bind_trace = pair.generate_sponge_trace(limbs)
+        bind_pub = pair.sponge_public_inputs(limbs)
+        if vm_batch is not None and self.mesh is None:
+            vm_rows = ta.segment_count(len(vm_batch.segs)) * ta.SEG_LEN
+            stark_prover.compile_ahead(vm_air, vm_rows, PARAMS)
+            stark_prover.warm_fri_programs(vm_rows, PARAMS)
+        stark_prover.compile_ahead(bind_air, bind_trace.shape[0], PARAMS,
+                                   self.mesh)
+
         results = _run_proof_jobs(jobs, self.mesh)
         state_proof = results["state_proof"]
         if vm_batch is not None:
@@ -721,15 +739,9 @@ class TpuBackend(ProverBackend):
                 tok_proof = results["vm_circuits/TokenAir"]
             bc_proofs = [results[f"vm_circuits/BytecodeAir{i}"]
                          for i in range(len(bc_airs))]
-        digest = pub[16:24]
 
         with tracing.span("prove.binding", stage="binding"), \
                 ckpt_mod.job_scope("binding"):
-            limbs = binding_limbs(encoded, r_pre, r_post, digest, vm_pub,
-                                  tok_pub, bc_pubs)
-            bind_air = pair.Poseidon2SpongeAir(num_chunks=len(limbs) // 8)
-            bind_trace = pair.generate_sponge_trace(limbs)
-            bind_pub = pair.sponge_public_inputs(limbs)
             bind_proof = stark_prover.prove(bind_air, bind_trace,
                                             bind_pub, PARAMS,
                                             mesh=self.mesh)

@@ -14,16 +14,17 @@ and the first proof after a restart runs at steady-state wall.
 Key schema: an entry's filename is the SHA-256 of its JSON-canonical
 key parts — the program identity (AIR cache key, log_n, blowup, shift,
 kernel, mesh device layout) — joined with the environment parts
-(backend platform, jax/jaxlib versions).  A jaxlib upgrade or a backend
-switch therefore changes every key: stale entries are structurally
-unreachable, not a correctness hazard.  Corruption, truncation, or an
+(backend platform, device kind and count, jax/jaxlib versions).  A
+jaxlib upgrade, a backend switch or another host layout therefore
+changes every key: stale entries are structurally unreachable, not a
+correctness hazard.  Corruption, truncation, or an
 unpicklable payload is a clean miss (plus `executable_cache_errors_total`
 and a best-effort unlink); retention is bounded by pruning
 least-recently-used entries past a cap.
 
 Env knobs (documented in docs/PERFORMANCE.md "Cold start"):
-  ETHREX_EXEC_CACHE_DIR  cache directory (default
-                         /tmp/ethrex_tpu_exec_cache_<host fingerprint>)
+  ETHREX_EXEC_CACHE_DIR  cache directory (default <cache root>/exec,
+                         see utils/jax_cache.cache_dir)
   ETHREX_EXEC_CACHE_MAX  max entries retained after a store (default 512)
   ETHREX_EXEC_CACHE_OFF  "1" disables both lookup and store
 """
@@ -73,7 +74,7 @@ def record_exec_cache_error() -> None:
 
 def set_cache_dir(path: str | None) -> None:
     """Explicit cache directory (the `--executable-cache-dir` CLI flag);
-    overrides ETHREX_EXEC_CACHE_DIR and the /tmp default."""
+    overrides ETHREX_EXEC_CACHE_DIR and the <cache root>/exec default."""
     global _CONFIGURED_DIR
     with _LOCK:
         _CONFIGURED_DIR = path
@@ -87,9 +88,9 @@ def cache_dir() -> str:
     env = os.environ.get("ETHREX_EXEC_CACHE_DIR")
     if env:
         return env
-    from .jax_cache import cache_dir as _fingerprinted
+    from .jax_cache import cache_dir as _cache_root
 
-    return _fingerprinted(prefix="/tmp/ethrex_tpu_exec_cache")
+    return os.path.join(_cache_root(), "exec")
 
 
 def enabled() -> bool:
@@ -104,6 +105,22 @@ def mesh_fingerprint(mesh) -> tuple | None:
         return None
     return (tuple(int(d.id) for d in mesh.devices.flat),
             tuple(mesh.axis_names), tuple(mesh.devices.shape))
+
+
+def _execution_devices(parts: dict) -> list:
+    """The devices an entry's executable is bound to: its mesh's (by
+    id), or the default device for a single-device program.  Left to
+    itself, deserialize_and_load binds the executable to EVERY device
+    of the backend — a single-device program stored on a one-chip host
+    and hydrated on a four-chip host then demands four shards of every
+    argument."""
+    import jax
+
+    mesh = parts.get("mesh")
+    if mesh is None:
+        return [jax.devices()[0]]
+    by_id = {int(d.id): d for d in jax.devices()}
+    return [by_id[int(i)] for i in mesh[0]]
 
 
 _CODE_FINGERPRINT: str | None = None
@@ -151,8 +168,13 @@ def _env_parts() -> dict:
     import jax
     import jaxlib
 
+    devs = jax.devices()
+    # the host's device layout is part of the environment: an
+    # executable stored where the backend had one device has failed to
+    # run when hydrated where it has four (PR 25's --chips 4 rehearsal)
     return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
             "backend": jax.default_backend(),
+            "devices": [devs[0].device_kind, len(devs)],
             "code": _code_fingerprint()}
 
 
@@ -190,7 +212,8 @@ def load(parts: dict):
         from jax.experimental import serialize_executable
 
         compiled = serialize_executable.deserialize_and_load(
-            entry["payload"], entry["in_tree"], entry["out_tree"])
+            entry["payload"], entry["in_tree"], entry["out_tree"],
+            execution_devices=_execution_devices(parts))
     except Exception:
         # corruption / truncation / version drift inside the payload:
         # count the error, drop the entry, and report a clean miss
@@ -231,8 +254,9 @@ def store(parts: dict, compiled) -> bool:
         # as an error; a warm XLA cache + empty executable cache
         # therefore stays unpopulated (cold starts are still XLA-cache
         # fast) until a genuinely fresh compile comes along.
-        serialize_executable.deserialize_and_load(payload, in_tree,
-                                                  out_tree)
+        serialize_executable.deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=_execution_devices(parts))
         entry = {"schema": _SCHEMA, "parts": parts, "env": _env_parts(),
                  "payload": payload, "in_tree": in_tree,
                  "out_tree": out_tree}

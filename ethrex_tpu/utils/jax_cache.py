@@ -1,50 +1,52 @@
-"""Persistent XLA compile cache keyed by a host-CPU fingerprint, plus
-JAX runtime telemetry.
+"""Where the compile caches live, plus JAX runtime telemetry.
 
-XLA's AOT results embed machine features; loading a cache written on a
-different host SIGSEGVs/SIGILLs (observed as "Compile machine features ...
-doesn't match" warnings before a crash).  Both the test suite and bench.py
-route through this helper so they share one correctly-scoped cache.
+One cache root holds everything this program compiles: the XLA
+persistent compilation cache at the root itself and the
+serialized-executable store (utils/exec_cache) under ``<root>/exec``.
+The root is ``JAX_COMPILATION_CACHE_DIR`` when that is set — JAX reads
+the variable itself, so this module then sets no directory in code —
+and otherwise ``<checkout>/.jax_cache`` (git-ignored), computed from
+this file's location so every process of a checkout agrees on it: the
+path is part of a cache entry's key, and a directory that moves never
+hits.
+
+The chip tool copies the checkout as it stands on disk, so a
+``.jax_cache`` filled here on the CPU would travel to the machine with
+the chip.  Decision: it does not travel — ``.chiprunignore`` lists
+``.jax_cache/`` (and ``.gitignore`` does, so the driver's checkout never
+has one).  XLA's cache key carries the platform, so a CPU entry would
+never be offered to the TPU anyway; what is not safe is a CPU entry read
+back on a *different* CPU host (XLA:CPU AOT results embed machine
+features and have crashed when loaded elsewhere), and the copy has a
+size limit the test suite's cache would break.
 
 Telemetry: jax.monitoring listeners count backend compiles (with
 durations) and persistent-cache hits/misses; runtime_telemetry() adds
 per-device memory stats and live-array counts for the flight recorder,
 and update_metrics_gauges() mirrors them into the Metrics registry.
-Every telemetry path is exception-guarded — a missing jax.monitoring
-API or a backend without memory_stats() degrades to empty data, never
-an error in the prover path.
+Every telemetry path is exception-guarded — a backend without
+memory_stats() degrades to empty data, never an error in the prover
+path.
 """
 
 from __future__ import annotations
 
-import hashlib
 import os
-import platform
 import threading
 
 _LOCK = threading.Lock()
 _MONITORING_INSTALLED = False
-_DEFAULT_PREFIX = "/tmp/ethrex_tpu_jax_cache"
+_CHECKOUT = os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__))))
 STATS = {"compiles": 0, "compile_seconds": 0.0,
          "cache_hits": 0, "cache_misses": 0}
 
 
-def cache_dir(prefix: str = _DEFAULT_PREFIX) -> str:
-    """Host-fingerprinted cache directory.  The XLA compile cache's /tmp
-    default is overridable via ETHREX_JAX_CACHE_DIR (used verbatim, no
-    fingerprint suffix — the operator owns its scoping); callers with
-    their own prefix (the executable store, utils/exec_cache) keep it."""
-    if prefix == _DEFAULT_PREFIX:
-        env = os.environ.get("ETHREX_JAX_CACHE_DIR")
-        if env:
-            return env
-    try:
-        with open("/proc/cpuinfo") as f:
-            cpu = [ln for ln in f if ln.startswith("flags")][0]
-    except (OSError, IndexError):
-        cpu = platform.processor() or "unknown"
-    fp = hashlib.sha256(cpu.encode()).hexdigest()[:12]
-    return f"{prefix}_{fp}"
+def cache_dir() -> str:
+    """The one cache root: JAX_COMPILATION_CACHE_DIR, else
+    <checkout>/.jax_cache."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_CHECKOUT, ".jax_cache"))
 
 
 def _on_duration(event: str, duration: float, **kw) -> None:
@@ -79,27 +81,24 @@ def _on_event(event: str, **kw) -> None:
 
 
 def install_monitoring() -> bool:
-    """Attach jax.monitoring listeners (idempotent, never raises).
-    Returns whether listeners are installed."""
+    """Attach jax.monitoring listeners (idempotent).  Returns whether
+    listeners are installed."""
     global _MONITORING_INSTALLED
-    with _LOCK:
-        if _MONITORING_INSTALLED:
-            return True
-        try:
-            from jax import monitoring
+    from jax import monitoring
 
+    with _LOCK:
+        if not _MONITORING_INSTALLED:
             monitoring.register_event_duration_secs_listener(_on_duration)
             monitoring.register_event_listener(_on_event)
             _MONITORING_INSTALLED = True
-        except Exception:
-            return False
     return True
 
 
 def enable_persistent_cache(min_compile_secs: float = 1.0) -> None:
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir())
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", cache_dir())
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       min_compile_secs)
     install_monitoring()

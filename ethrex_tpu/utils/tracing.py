@@ -533,7 +533,7 @@ def _approx_wire_bytes(spans) -> int:
 def _component(s: dict) -> str:
     """Critical-path component of one span.
 
-    The taxonomy the walker attributes wall time to: stage spans become
+    The classification the walker attributes wall time to: stage spans become
     ``compile`` / ``prove/<stage>``, transport and lifecycle spans map
     by name, anything unrecognized is ``other`` (uncovered top-level
     time is ``queue-wait``, added by the walker itself).

@@ -1,0 +1,257 @@
+"""The places where a proof could leave the chip, or a failure pass as
+success, are closed — one pin each (ISSUE 25): chip_smoke.py refuses to
+run without a TPU, the compile cache can be placed from outside and sits
+in the checkout otherwise, `_aot_phases` propagates a compile error, and
+`python bench.py` publishes nothing from a host without a chip.  (The
+degradation ladder and the memory gate are pinned in
+tests/test_runtime_chaos.py, the peak table in tests/test_perf.py.)"""
+
+import json
+import os
+import pathlib
+import shutil
+import subprocess
+import sys
+
+import jax
+import pytest
+
+from ethrex_tpu.models import fibonacci as fib
+from ethrex_tpu.stark import prover as stark_prover
+from ethrex_tpu.utils import exec_cache, jax_cache
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
+SHIFT = stark_prover.StarkParams().shift
+
+
+def _run(argv, cwd=REPO, **env):
+    full = {k: v for k, v in os.environ.items()
+            if k not in ("JAX_COMPILATION_CACHE_DIR", "BENCH_ALLOW_CPU")}
+    full.update(JAX_PLATFORMS="cpu", **env)
+    return subprocess.run([sys.executable, *argv], cwd=cwd, env=full,
+                          capture_output=True, text=True, timeout=300)
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py
+
+@pytest.mark.parametrize("argv", [[], ["--chips", "4"]])
+def test_chip_smoke_without_a_tpu_fails_and_says_so(argv):
+    proc = _run([str(REPO / "chip_smoke.py"), *argv])
+    assert proc.returncode != 0
+    last = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False
+    assert last["device"]["platform"] == "cpu"
+    assert "no TPU" in last["error"]
+    # it did no work: the stack never started
+    assert "l2 stack started" not in proc.stdout
+
+
+def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
+    """The script without the program proves nothing: it must fail."""
+    shutil.copy(REPO / "chip_smoke.py", tmp_path / "chip_smoke.py")
+    proc = _run(["chip_smoke.py"], cwd=tmp_path, PYTHONPATH="")
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout
+
+
+@pytest.mark.slow
+def test_chip_smoke_work_function_on_the_cpu(monkeypatch):
+    """Step-0 rehearsal: the very function main() runs on the chip,
+    with one transfer a batch, on the CPU — finds wrong wiring.  Slow:
+    the 278-column TransferAir takes many minutes to compile on
+    XLA:CPU, so the batch timeout is the one thing stretched."""
+    sys.path.insert(0, str(REPO))
+    try:
+        import chip_smoke
+    finally:
+        sys.path.pop(0)
+    monkeypatch.setattr(chip_smoke, "BATCH_TIMEOUT", 7200.0)
+    chip_smoke.run_batches(1)
+
+
+# ---------------------------------------------------------------------------
+# one cache root, placeable from outside
+
+def _config_updates(monkeypatch):
+    seen = {}
+    real = jax.config.update
+
+    def spy(name, value):
+        seen[name] = value
+        if name != "jax_compilation_cache_dir":
+            real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    return seen
+
+
+def test_cache_dir_from_outside_is_left_to_jax(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself; the
+    code sets no directory, and every other cache follows the root."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.delenv("ETHREX_EXEC_CACHE_DIR", raising=False)
+    seen = _config_updates(monkeypatch)
+    jax_cache.enable_persistent_cache()
+    assert "jax_compilation_cache_dir" not in seen
+    assert jax_cache.cache_dir() == str(tmp_path)
+    assert exec_cache.cache_dir() == str(tmp_path / "exec")
+
+
+def test_cache_dir_defaults_to_the_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("ETHREX_EXEC_CACHE_DIR", raising=False)
+    seen = _config_updates(monkeypatch)
+    jax_cache.enable_persistent_cache()
+    want = str(REPO / ".jax_cache")
+    assert seen["jax_compilation_cache_dir"] == want
+    assert exec_cache.cache_dir() == os.path.join(want, "exec")
+    ignored = (REPO / ".gitignore").read_text().splitlines()
+    assert ".jax_cache/" in ignored
+
+
+def test_cache_dir_is_the_same_in_every_process():
+    """The path is part of a cache entry's key: no pid, time, temporary
+    name or host hash may enter it."""
+    code = ("from ethrex_tpu.utils import exec_cache, jax_cache;"
+            "print(jax_cache.cache_dir()); print(exec_cache.cache_dir())")
+    first = _run(["-c", code], TMPDIR="/tmp/a", HOME="/tmp/home-a")
+    second = _run(["-c", code], TMPDIR="/tmp/b", HOME="/tmp/home-b")
+    assert first.returncode == 0, first.stderr
+    assert first.stdout == second.stdout
+    assert first.stdout.split() == [str(REPO / ".jax_cache"),
+                                    str(REPO / ".jax_cache" / "exec")]
+
+
+def test_hydrated_executable_is_bound_to_its_own_devices(
+        monkeypatch, tmp_path):
+    """Found by the --chips 4 rehearsal: deserialize_and_load binds an
+    executable to EVERY device of the backend unless told otherwise, so
+    a single-device phase program hydrated on a multi-device host
+    demanded one shard per device.  The store names the devices: the
+    entry's mesh, or the default device."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    monkeypatch.setenv("ETHREX_EXEC_CACHE_DIR", str(tmp_path))
+    monkeypatch.delenv("ETHREX_EXEC_CACHE_OFF", raising=False)
+    monkeypatch.setattr(exec_cache, "_CONFIGURED_DIR", None)
+    assert len(jax.devices()) > 1           # the suite's virtual mesh
+    spec = jax.ShapeDtypeStruct((4, 4), jnp.uint32)
+    compiled = jax.jit(lambda x: x + 1).lower(spec).compile()
+    parts = {"kind": "phase", "kernel": "commit", "mesh": None}
+    assert exec_cache.store(parts, compiled)
+    loaded = exec_cache.load(parts)
+    assert loaded.runtime_executable().local_devices() == \
+        [jax.devices()[0]]
+    out = loaded(jnp.zeros((4, 4), jnp.uint32))
+    assert np.array_equal(np.asarray(out), np.ones((4, 4), np.uint32))
+    # and a mesh entry is bound to exactly its mesh's devices, by id
+    pair = (tuple(int(d.id) for d in jax.devices()[2:4]), ("shard",),
+            (2,))
+    assert exec_cache._execution_devices({"mesh": pair}) == \
+        jax.devices()[2:4]
+
+
+# ---------------------------------------------------------------------------
+# _aot_phases: a compile error propagates
+
+@pytest.mark.parametrize("kernel", stark_prover._KERNELS)
+def test_aot_phases_propagates_a_compile_error(kernel):
+    """A phase the compiler refuses fails the build with the compiler's
+    own error: no replicated re-compile, no lazy-jit substitute."""
+    air = fib.FibonacciAir()
+    bodies, plan = stark_prover._build_phases(air, 4, 2, SHIFT)
+
+    def refused(*args):
+        raise NotImplementedError(f"{kernel}: refused by the compiler")
+
+    broken = tuple(refused if k == kernel else b
+                   for k, b in zip(stark_prover._KERNELS, bodies))
+    with pytest.raises(NotImplementedError, match=kernel):
+        stark_prover._aot_phases(air, 4, 2, SHIFT, broken, plan, None)
+    assert not hasattr(stark_prover, "_shard_map_program")
+
+
+def test_phase_programs_build_once_and_compile_ahead_hands_over(
+        monkeypatch):
+    """compile_ahead starts a build on a background thread; the prove
+    that follows waits for the build in flight instead of starting a
+    second one, and both see the same programs."""
+    import threading
+
+    air = fib.FibonacciAir()
+    builds = []
+    gate = threading.Event()
+    real = stark_prover._build_phases
+
+    def counted(*args, **kw):
+        builds.append(args[1])
+        gate.wait(30)
+        return real(*args, **kw)
+
+    monkeypatch.setattr(stark_prover, "_build_phases", counted)
+    stark_prover.clear_phase_cache()
+    params = stark_prover.StarkParams(log_blowup=2)
+    stark_prover.compile_ahead(air, 32, params)
+    for _ in range(200):                    # until the build is in flight
+        if builds:
+            break
+        threading.Event().wait(0.01)
+    got = []
+    waiter = threading.Thread(target=lambda: got.append(
+        stark_prover._phases(air, 5, 2, SHIFT)))
+    waiter.start()
+    gate.set()
+    waiter.join(120)
+    assert builds == [5]                    # one build, not two
+    assert got and got[0] is stark_prover._phases(air, 5, 2, SHIFT)
+    assert not stark_prover._PHASE_BUILDS
+
+
+def test_a_failed_compile_ahead_fails_the_prove_that_waited(monkeypatch):
+    import threading
+
+    air = fib.FibonacciAir()
+    gate = threading.Event()
+
+    def refused(*args, **kw):
+        gate.wait(30)
+        raise NotImplementedError("refused by the compiler")
+
+    monkeypatch.setattr(stark_prover, "_build_phases", refused)
+    stark_prover.clear_phase_cache()
+    stark_prover.compile_ahead(air, 64, stark_prover.StarkParams(
+        log_blowup=2))
+    for _ in range(200):
+        if stark_prover._PHASE_BUILDS:
+            break
+        threading.Event().wait(0.01)
+    errors = []
+
+    def prove_side():
+        try:
+            stark_prover._phases(air, 6, 2, SHIFT)
+        except NotImplementedError as exc:
+            errors.append(str(exc))
+
+    waiter = threading.Thread(target=prove_side)
+    waiter.start()
+    gate.set()
+    waiter.join(60)
+    assert errors == ["refused by the compiler"]
+    assert not stark_prover._PHASE_BUILDS and not any(
+        k[1] == 6 for k in stark_prover._PHASE_CACHE)
+
+
+# ---------------------------------------------------------------------------
+# bench.py, default mode
+
+def test_bench_default_mode_on_a_host_without_a_chip(tmp_path):
+    """The real thing, children and all: `python bench.py` where JAX
+    finds only the CPU exits 3, prints no record, and leaves no
+    history line."""
+    proc = _run([str(REPO / "bench.py")])
+    assert proc.returncode == 3
+    assert proc.stdout.strip() == ""
+    assert "refusing to publish" in proc.stderr

@@ -35,8 +35,10 @@ def _fake_serializer(monkeypatch):
         se, "serialize",
         lambda compiled: (pickle.dumps(compiled), "it", "ot"))
 
-    def _deserialize(payload, in_tree, out_tree):
+    def _deserialize(payload, in_tree, out_tree, execution_devices=None):
         assert (in_tree, out_tree) == ("it", "ot")
+        # never left to the default (every device of the backend)
+        assert execution_devices
         return pickle.loads(payload)
 
     monkeypatch.setattr(se, "deserialize_and_load", _deserialize)
@@ -153,7 +155,7 @@ def test_unloadable_payload_is_rejected_at_store_time(cache_env,
     every subsequent hydration."""
     from jax.experimental import serialize_executable as se
 
-    def _symbols_lost(payload, in_tree, out_tree):
+    def _symbols_lost(payload, in_tree, out_tree, execution_devices=None):
         raise RuntimeError("Symbols not found: [concatenate_fusion.12]")
 
     monkeypatch.setattr(se, "deserialize_and_load", _symbols_lost)

@@ -1,6 +1,6 @@
 """Preemption-tolerant proving battery (docs/PROVER_RESILIENCE.md
 "Runtime failures, phase checkpoints, and the degradation ladder"):
-the error taxonomy, the phase-checkpoint envelope (kill at every phase
+the error classification, the phase-checkpoint envelope (kill at every phase
 boundary -> resume with at most one phase recomputed, byte-identical
 proof; torn/garbage blobs discarded to a fresh prove), the OOM /
 device-loss degradation ladder, nan-poison zero-retry quarantine, the
@@ -11,7 +11,7 @@ and "device.lost" sites.
 
 Select alone with `-m chaos`; the drills that run a full STARK prove
 (the crash loop and the ladder walks) are `slow` like the PR-14 soak —
-the taxonomy/envelope/coordinator units stay in the fast tier.
+the classification/envelope/coordinator units stay in the fast tier.
 """
 
 import os
@@ -73,10 +73,10 @@ def _material(depth=DEPTH):
 
 
 # ===========================================================================
-# taxonomy units
+# classification units
 # ===========================================================================
 
-def test_classify_taxonomy():
+def test_classify_classification():
     assert rt.classify(RuntimeError(
         "RESOURCE_EXHAUSTED: failed to allocate 4.2G")) == "oom"
     assert rt.classify(MemoryError()) == "oom"
@@ -131,36 +131,104 @@ def test_guard_phase_classifies_and_wraps():
     assert rt.guard_phase("fri", "air", lambda: 41 + 1) == 42
 
 
+def _mesh8():
+    from ethrex_tpu.parallel import mesh as mesh_lib
+
+    return mesh_lib.make_mesh(8)
+
+
 def test_degradation_ladder_and_kill_switch(monkeypatch):
-    rungs = rt.degradation_ladder(None)
-    assert len(rungs) == 1          # forced-CPU floor below the default
-    assert [d.platform for d in rungs[0].devices.flat] == ["cpu"]
-    monkeypatch.setenv("ETHREX_MESH_DEGRADE_OFF", "1")
+    """8 -> 4 -> 1, always devices of the mesh itself: no rung is None
+    and none sits on another platform; below one device (or with no
+    mesh) there is nowhere to fall."""
+    mesh = _mesh8()
+    rungs = rt.degradation_ladder(mesh)
+    assert [r.devices.size for r in rungs] == [4, 1]
+    own = set(mesh.devices.flat)
+    for rung in rungs:
+        assert rung is not None
+        assert set(rung.devices.flat) <= own
+    assert rt.degradation_ladder(rungs[-1]) == []
     assert rt.degradation_ladder(None) == []
+    monkeypatch.setenv("ETHREX_MESH_DEGRADE_OFF", "1")
+    assert rt.degradation_ladder(mesh) == []
     assert rt.ladder_enabled() is False
 
 
 def test_memory_gate_shrinks_before_oom(monkeypatch):
+    mesh = _mesh8()
     # fits in headroom: layout untouched, nothing counted
-    assert rt.memory_gate("air", None, est_bytes=100,
-                          avail_fn=lambda m: 10_000) is None
+    assert rt.memory_gate("air", mesh, est_bytes=100,
+                          avail_fn=lambda m: 10_000) is mesh
     assert rt.STATS["memory_gate_shrinks"] == 0
-    # over budget on the current layout, the CPU rung (unreported
-    # limits) absorbs it — one pre-emptive degradation, no OOM thrown
+    # the full mesh's tightest device lacks room, the 4-device rung's
+    # does not — one pre-emptive degradation, no OOM thrown
     gated = rt.memory_gate(
-        "air", None, est_bytes=100,
-        avail_fn=lambda m: 10 if m is None else None)
-    assert gated is not None
+        "air", mesh, est_bytes=100,
+        avail_fn=lambda m: 10 if m.devices.size == 8 else 10_000)
+    assert gated.devices.size == 4
+    assert set(gated.devices.flat) <= set(mesh.devices.flat)
     assert rt.STATS["memory_gate_shrinks"] == 1
     assert rt.runtime_stats()["lastDegradation"]["reason"] == "memory_gate"
     # the kill switch disables the gate with the ladder
     monkeypatch.setenv("ETHREX_MESH_DEGRADE_OFF", "1")
-    assert rt.memory_gate("air", None, est_bytes=100,
-                          avail_fn=lambda m: 1) is None
+    assert rt.memory_gate("air", mesh, est_bytes=100,
+                          avail_fn=lambda m: 1) is mesh
     # unknown availability -> never shrink on a guess
     monkeypatch.delenv("ETHREX_MESH_DEGRADE_OFF")
-    assert rt.memory_gate("air", None, est_bytes=100,
-                          avail_fn=lambda m: None) is None
+    assert rt.memory_gate("air", mesh, est_bytes=100,
+                          avail_fn=lambda m: None) is mesh
+
+
+@pytest.mark.parametrize("layout", ["none", "mesh8"])
+def test_memory_gate_raises_instead_of_leaving_the_platform(layout):
+    """Nothing on the ladder fits: the gate names the AIR, its estimate
+    and the free bytes in a typed error — it never answers with a CPU
+    device, None-for-a-mesh, or any layout outside the mesh."""
+    mesh = None if layout == "none" else _mesh8()
+    with pytest.raises(rt.MemoryGateError) as ei:
+        rt.memory_gate("TransferAir", mesh, est_bytes=2_000,
+                       avail_fn=lambda m: 1_000)
+    err = ei.value
+    assert (err.air_name, err.est_bytes, err.free_bytes) == \
+        ("TransferAir", 2_000, 1_000)
+    assert "TransferAir" in str(err) and "2000" in str(err) \
+        and "1000" in str(err)
+    assert rt.STATS["memory_gate_shrinks"] == 0
+
+
+def test_memory_gate_estimate_is_the_compiled_working_set():
+    """The estimate is memory_analysis() of the AIR's own phase
+    programs, captured at their compile (perf/hlo_introspect) — not
+    cost_analysis bytes accessed.  No compile yet -> no estimate."""
+    from ethrex_tpu.perf import hlo_introspect
+
+    assert rt._estimated_bytes("NeverCompiledAir") is None
+
+    class Mem:
+        argument_size_in_bytes = 100
+        output_size_in_bytes = 20
+        temp_size_in_bytes = 3
+        alias_size_in_bytes = 0
+
+    class Compiled:
+        def __init__(self, scale):
+            self.scale = scale
+
+        def as_text(self):
+            return ""
+
+        def memory_analysis(self):
+            m = Mem()
+            m.temp_size_in_bytes = 3 * self.scale
+            return m
+
+    try:
+        hlo_introspect.record("GateAir", "commit", Compiled(1))
+        hlo_introspect.record("GateAir", "quotient", Compiled(10))
+        assert rt._estimated_bytes("GateAir") == 150.0
+    finally:
+        hlo_introspect.REGISTRY.reset()
 
 
 # ===========================================================================
@@ -298,8 +366,8 @@ def test_torn_checkpoints_fall_back_to_fresh_prove():
 @pytest.mark.slow
 def test_oom_walks_the_ladder_byte_identical():
     """A RESOURCE_EXHAUSTED mid-phase classifies as oom, burns no
-    quarantine budget, and retries the attempt on the next rung (the
-    forced-CPU floor here); exact u32 arithmetic keeps the proof
+    quarantine budget, and retries the attempt on the next rung (8
+    devices -> 4 of them); exact u32 arithmetic keeps the proof
     byte-identical across layouts."""
     air, trace, pub = _material()
     baseline = prover.prove(air, trace, pub, PARAMS)
@@ -308,14 +376,15 @@ def test_oom_walks_the_ladder_byte_identical():
         exc=RuntimeError("RESOURCE_EXHAUSTED: failed to allocate"),
         times=1))
     try:
-        p = prover.prove(air, trace, pub, PARAMS)
+        p = prover.prove(air, trace, pub, PARAMS, mesh=_mesh8())
     finally:
         faults.clear()
     assert pickle.dumps(p) == pickle.dumps(baseline)
     stats = rt.runtime_stats()
     assert stats["oomRetries"] == 1
     assert stats["degradations"] == 1
-    assert stats["lastDegradation"]["reason"] == "ladder"
+    assert stats["lastDegradation"] == {"from": "8", "to": "4",
+                                        "reason": "ladder"}
 
 
 @pytest.mark.slow
@@ -324,11 +393,33 @@ def test_device_loss_retries_on_next_rung():
     baseline = prover.prove(air, trace, pub, PARAMS)
     faults.install(FaultPlan(seed=6).error("device.lost", times=1))
     try:
-        p = prover.prove(air, trace, pub, PARAMS)
+        p = prover.prove(air, trace, pub, PARAMS, mesh=_mesh8())
     finally:
         faults.clear()
     assert pickle.dumps(p) == pickle.dumps(baseline)
     assert rt.runtime_stats()["deviceLostRetries"] == 1
+    assert rt.runtime_stats()["lastDegradation"]["to"] == "4"
+
+
+def test_oom_on_one_device_reraises_the_devices_own_error(monkeypatch):
+    """With no mesh there is no rung to fall to: a chip OOM surfaces as
+    the device's own error — it does not end as a proof from somewhere
+    else — and nothing is counted as a degradation."""
+    air, trace, pub = _material()
+    boom = RuntimeError("RESOURCE_EXHAUSTED: failed to allocate 1.4G")
+    attempts = []
+
+    def attempt(air_, trace_, pub_, params_, mesh_):
+        attempts.append(mesh_)
+        raise rt.TransientPhaseError("oom", "quotient", boom)
+
+    monkeypatch.setattr(prover, "_prove_attempt", attempt)
+    with pytest.raises(RuntimeError) as ei:
+        prover.prove(air, trace, pub, PARAMS)
+    assert ei.value is boom
+    assert attempts == [None]
+    stats = rt.runtime_stats()
+    assert stats["degradations"] == 0 and stats["oomRetries"] == 0
 
 
 @pytest.mark.slow
