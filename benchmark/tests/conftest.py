@@ -1,0 +1,13 @@
+"""The benchmark's own tests: run on the CPU, never on the chip.
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
+"""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+_BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for path in (_BENCH, os.path.dirname(_BENCH)):
+    if path not in sys.path:
+        sys.path.insert(0, path)
