@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 
 import numpy as np
 import jax
@@ -30,6 +31,7 @@ from . import babybear as bb
 from . import ext
 from . import merkle
 from . import ntt as _ntt
+from ..utils import tracing
 from .challenger import Challenger
 
 _INV2 = int(bb.inv_host(2))
@@ -102,6 +104,10 @@ class FriProver:
             codeword, NamedSharding(self.mesh, P(mesh_lib.AXIS, None)))
 
     def commit_phase(self, codeword, challenger: Challenger):
+        """Fold and commit layer by layer.  One `fri.layer` span per
+        layer: its `d2h_s` is the `device_get` alone, which blocks on the
+        layer's Merkle program (and the fold before it), so it is the
+        layer's device wait plus the copy, not the copy's bandwidth."""
         p = self.params
         log_n = codeword.shape[0].bit_length() - 1
         shift = p.shift % bb.P
@@ -110,30 +116,44 @@ class FriProver:
         self.roots = []
         codeword = self._shard(codeword)
         while log_n > p.log_final_size:
-            leaves = _pair_leaves(codeword)
-            levels = merkle.commit_levels(leaves)
-            # one bulk device->host transfer per layer (codeword + levels)
-            cw_np, levels_np = jax.device_get((codeword, tuple(levels)))
-            levels_c = [bb.from_mont_host(l) for l in levels_np]
-            root = levels_c[-1][0]
-            challenger.absorb_elems(int(x) for x in root)
-            self.layers.append((bb.from_mont_host(cw_np), levels_c))
-            self.roots.append([int(x) for x in root])
-            beta = ext.to_device(challenger.sample_ext())
-            inv_pts = jnp.asarray(_fold_inv_points(log_n, shift))
-            codeword = self._shard(_fold(codeword, beta, inv_pts, inv2))
-            shift = (shift * shift) % bb.P
-            log_n -= 1
-        coeffs_dev = _ntt.coset_intt(codeword.T, shift=shift).T
-        coeffs = bb.from_mont_host(np.asarray(coeffs_dev))
-        self.final_coeffs = [tuple(int(v) for v in row) for row in coeffs]
-        deg_bound = (1 << p.log_final_size) >> p.log_blowup
-        for row in self.final_coeffs[deg_bound:]:
-            if row != (0, 0, 0, 0):
-                raise ValueError("FRI final polynomial exceeds degree bound "
-                                 "(input codeword was not low-degree)")
-        for row in self.final_coeffs:
-            challenger.absorb_ext(row)
+            with tracing.span("fri.layer", log_n=log_n) as sp:
+                leaves = _pair_leaves(codeword)
+                levels = merkle.commit_levels(leaves)
+                # one bulk device->host transfer per layer (codeword +
+                # levels)
+                t_get = time.perf_counter()
+                cw_np, levels_np = jax.device_get((codeword, tuple(levels)))
+                tracing.set_attrs(
+                    sp, d2h_s=time.perf_counter() - t_get,
+                    d2h_bytes=cw_np.nbytes + sum(l.nbytes
+                                                 for l in levels_np))
+                levels_c = [bb.from_mont_host(l) for l in levels_np]
+                root = levels_c[-1][0]
+                challenger.absorb_elems(int(x) for x in root)
+                self.layers.append((bb.from_mont_host(cw_np), levels_c))
+                self.roots.append([int(x) for x in root])
+                beta = ext.to_device(challenger.sample_ext())
+                inv_pts = jnp.asarray(_fold_inv_points(log_n, shift))
+                # the fold is dispatched, not waited for: the next
+                # layer's device_get (or fri.final's copy) pays for it
+                codeword = self._shard(_fold(codeword, beta, inv_pts, inv2))
+                shift = (shift * shift) % bb.P
+                log_n -= 1
+        with tracing.span("fri.final") as sp:
+            coeffs_dev = _ntt.coset_intt(codeword.T, shift=shift).T
+            coeffs_np = np.asarray(coeffs_dev)
+            tracing.set_attrs(sp, d2h_bytes=coeffs_np.nbytes)
+            coeffs = bb.from_mont_host(coeffs_np)
+            self.final_coeffs = [tuple(int(v) for v in row)
+                                 for row in coeffs]
+            deg_bound = (1 << p.log_final_size) >> p.log_blowup
+            for row in self.final_coeffs[deg_bound:]:
+                if row != (0, 0, 0, 0):
+                    raise ValueError(
+                        "FRI final polynomial exceeds degree bound "
+                        "(input codeword was not low-degree)")
+            for row in self.final_coeffs:
+                challenger.absorb_ext(row)
         return self.roots, self.final_coeffs
 
     def open_queries(self, indices) -> list:
@@ -155,11 +175,16 @@ class FriProver:
         """Full FRI round.  Returns (FriProof, query_indices); the caller
         (the STARK prover) opens its own commitments at the same indices."""
         self.commit_phase(codeword, challenger)
-        nonce = challenger.grind(self.params.grinding_bits)
+        # no `stage=`: it runs inside the `fri_fold` stage, whose seconds
+        # hold it (the profiler sums a component's stages)
+        with tracing.span("fri.grind") as sp:
+            nonce = challenger.grind(self.params.grinding_bits)
+            tracing.set_attrs(sp, tries=nonce + 1)
         n0 = self.layers[0][0].shape[0]
         bits = (n0 // 2).bit_length() - 1
         indices = challenger.sample_indices(bits, self.params.num_queries)
-        queries = self.open_queries(indices)
+        with tracing.span("fri.open_queries"):
+            queries = self.open_queries(indices)
         return (FriProof(self.roots, self.final_coeffs, queries, nonce),
                 indices)
 

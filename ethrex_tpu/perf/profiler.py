@@ -39,10 +39,13 @@ log = logging.getLogger("ethrex_tpu.perf")
 MAX_KEYS = 512
 
 # tracing-span stage -> component for the observer (spans carry a stage
-# attr but no component; the split mirrors where each span lives)
+# attr but no component; the split mirrors where each span lives).  A
+# component's stages never nest in one another, so their seconds add up
+# and `tree()`'s shares are shares: `ckpt` (the checkpoint copies and
+# writes) runs between the STARK's phases, under none of them
 _STARK_STAGES = frozenset(
     ("trace_lde", "merkle_commit", "quotient", "openings", "fri_fold",
-     "query"))
+     "query", "ckpt"))
 _BACKEND_STAGES = frozenset(
     ("execute", "state_proof", "vm_circuits", "binding", "aggregate",
      "groth16_wrap"))

@@ -46,13 +46,17 @@ import threading
 import time
 import zlib
 
+from ..utils import tracing
+
 _SCHEMA = 1
 _MAGIC = b"ETPC"
 _SUFFIX = ".ckpt"
 
 _LOCK = threading.Lock()
 _CONFIGURED_DIR: str | None = None
-STATS = {"stores": 0, "loads": 0, "discards": 0}
+# `misses` counts loads that found no envelope (every phase of a fresh
+# prove probes once); a hit is a `ckpt.load` span, a miss only this.
+STATS = {"stores": 0, "loads": 0, "discards": 0, "misses": 0}
 
 # The per-thread prove context: ProverClient activates one around
 # backend.prove; TpuBackend re-activates it on its job worker threads
@@ -220,30 +224,37 @@ def store(batch_id, parts: dict, payload, meta: dict | None = None) -> bool:
     True when the record landed."""
     if not enabled():
         return False
-    try:
-        blob = pickle.dumps({"schema": _SCHEMA, "parts": parts,
-                             "meta": dict(meta or {}), "payload": payload},
-                            protocol=pickle.HIGHEST_PROTOCOL)
-        frame = (_MAGIC + zlib.crc32(blob).to_bytes(4, "big")
-                 + len(blob).to_bytes(8, "big") + blob)
-        path = _entry_path(batch_id, parts)
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path),
-                                   suffix=".tmp")
+    # one span for the whole write (pickle, crc, frame copy, file): the
+    # execute envelope and every per-proof phase pass through here
+    with tracing.span("ckpt.store", stage="ckpt") as sp:
         try:
-            with os.fdopen(fd, "wb") as f:
-                f.write(frame)
-            os.replace(tmp, path)
-        except BaseException:
-            with contextlib.suppress(OSError):
-                os.unlink(tmp)
-            raise
-        with _LOCK:
-            STATS["stores"] += 1
-        record_ckpt_store()
-        return True
-    except Exception:
-        return False
+            tracing.set_attrs(sp, phase=parts.get("phase"),
+                              job=parts.get("job"))
+            blob = pickle.dumps({"schema": _SCHEMA, "parts": parts,
+                                 "meta": dict(meta or {}),
+                                 "payload": payload},
+                                protocol=pickle.HIGHEST_PROTOCOL)
+            frame = (_MAGIC + zlib.crc32(blob).to_bytes(4, "big")
+                     + len(blob).to_bytes(8, "big") + blob)
+            path = _entry_path(batch_id, parts)
+            os.makedirs(os.path.dirname(path), exist_ok=True)
+            fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path),
+                                       suffix=".tmp")
+            try:
+                with os.fdopen(fd, "wb") as f:
+                    f.write(frame)
+                os.replace(tmp, path)
+            except BaseException:
+                with contextlib.suppress(OSError):
+                    os.unlink(tmp)
+                raise
+            tracing.set_attrs(sp, disk_bytes=len(frame))
+            with _LOCK:
+                STATS["stores"] += 1
+            record_ckpt_store()
+            return True
+        except Exception:
+            return False
 
 
 def load(batch_id, parts: dict):
@@ -253,10 +264,13 @@ def load(batch_id, parts: dict):
     if not enabled():
         return None
     path = _entry_path(batch_id, parts)
+    wall0 = time.time()
     try:
         with open(path, "rb") as f:
             frame = f.read()
     except OSError:
+        with _LOCK:
+            STATS["misses"] += 1
         return None
     try:
         if frame[:4] != _MAGIC or len(frame) < 16:
@@ -272,6 +286,11 @@ def load(batch_id, parts: dict):
         with _LOCK:
             STATS["loads"] += 1
         record_ckpt_load()
+        # spanned only when an envelope was found: a fresh prove's
+        # probes are failed opens, counted above
+        tracing.record_span("ckpt.load", wall0, time.time() - wall0,
+                            phase=parts.get("phase"), job=parts.get("job"),
+                            disk_bytes=len(frame))
         return rec["payload"]
     except Exception:
         with contextlib.suppress(OSError):
@@ -282,19 +301,24 @@ def load(batch_id, parts: dict):
         return None
 
 
-def complete(batch_id) -> None:
+def complete(batch_id) -> int:
     """Drop every checkpoint of a settled batch (proof accepted): the
-    envelope is recovery state, not an artifact."""
+    envelope is recovery state, not an artifact.  Returns the bytes
+    removed."""
     bdir = _batch_dir(batch_id)
+    removed = 0
     try:
-        names = os.listdir(bdir)
+        entries = list(os.scandir(bdir))
     except OSError:
-        return
-    for name in names:
+        return removed
+    for entry in entries:
         with contextlib.suppress(OSError):
-            os.unlink(os.path.join(bdir, name))
+            size = entry.stat().st_size
+            os.unlink(entry.path)
+            removed += size
     with contextlib.suppress(OSError):
         os.rmdir(bdir)
+    return removed
 
 
 def runtime_stats() -> dict:

@@ -154,6 +154,12 @@ class ProverClient:
         self.degraded: dict | None = None
         self.endpoint_states: dict[tuple[str, int], EndpointState] = {
             ep: EndpointState() for ep in endpoints}
+        # the wait for work, as the `prover.idle` span of the batch that
+        # ends it: wall clock of the end of the previous batch (or of
+        # run_forever's start; None before either) and the requests that
+        # came back empty since
+        self._idle_since: float | None = None
+        self._idle_polls = 0
         # pre-warm: hydrate the backend's AOT executables from the
         # on-disk cache in the background, so the first assignment can
         # run at steady-state wall; `warm` rides every InputRequest
@@ -260,6 +266,7 @@ class ProverClient:
 
     def _poll_endpoint(self, host: str, port: int) -> int:
         # connection 1: request work (closed before the proof starts)
+        t_request = time.time()
         with socket.create_connection((host, port), timeout=30) as sock:
             protocol.send_msg(sock, {
                 "type": protocol.INPUT_REQUEST,
@@ -274,14 +281,37 @@ class ProverClient:
             raise ValueError(
                 f"prover version mismatch: need {resp.get('expected')}")
         if rtype != protocol.INPUT_RESPONSE:
+            self._idle_polls += 1
             return 0
+        program_input = ProgramInput.from_json(resp["input"])
+        t_fetched = time.time()
+        # what came before the trace was known joins it now: the wait
+        # this batch ended, and its fetch (request sent to input decoded).
+        # The wait is the client's time, not the batch's: it is one of
+        # tracing.OFF_PATH_SPANS, which the critical path leaves out
+        with tracing.trace_context(resp.get("trace_id"), resp.get("span_id")):
+            if self._idle_since is not None:
+                tracing.record_span(
+                    "prover.idle", self._idle_since,
+                    t_request - self._idle_since,
+                    polls=self._idle_polls, batch=resp["batch_id"])
+            tracing.record_span(
+                "prover.fetch_input", t_request, t_fetched - t_request,
+                batch=resp["batch_id"])
+        try:
+            return self._prove_and_submit(host, port, resp, program_input)
+        finally:
+            self._idle_since = time.time()
+            self._idle_polls = 0
+
+    def _prove_and_submit(self, host: str, port: int, resp: dict,
+                          program_input: ProgramInput) -> int:
         batch_id = resp["batch_id"]
         lease_token = resp.get("lease_token")
         # continue the trace the coordinator opened at assignment, so the
         # whole batch lifecycle shares one trace ID across the TCP seam
         trace_id = resp.get("trace_id")
         parent_span = resp.get("span_id")
-        program_input = ProgramInput.from_json(resp["input"])
         # the batch context scopes this attempt's phase checkpoints (a
         # restart with a fresh lease resumes from the last completed
         # phase) and carries the advisory state heartbeats report
@@ -353,7 +383,11 @@ class ProverClient:
         if ack.get("type") == protocol.SUBMIT_ACK:
             # the proof is accepted: its recovery state has no further
             # value, drop the batch's checkpoints
-            ckpt_mod.complete(batch_id)
+            with tracing.trace_context(trace_id, parent_span), \
+                    tracing.span("prover.ckpt_complete",
+                                 batch=batch_id) as done:
+                tracing.set_attrs(
+                    done, disk_bytes=ckpt_mod.complete(batch_id))
             self.proved.append(batch_id)
             return 1
         # application-level rejection (invalid proof, stale token): the
@@ -395,6 +429,8 @@ class ProverClient:
     def run_forever(self):
         from ..utils.metrics import record_poll_error
 
+        self._idle_since = time.time()
+        self._idle_polls = 0
         while not self._stop.wait(self.poll_interval):
             try:
                 self.poll_once()

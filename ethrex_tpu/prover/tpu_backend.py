@@ -393,6 +393,16 @@ def expected_vm_mode(program_input: ProgramInput) -> str:
                                   output.initial_state_root)
 
 
+def _traced_gen(air_name: str, generate, *args):
+    """A job's trace generation (host numpy) under its leaf span.  No
+    `stage=`: it runs inside the job's own stage span."""
+    with tracing.span("prove.trace_gen", air=air_name) as sp:
+        trace = generate(*args)
+        tracing.set_attrs(sp, rows=int(trace.shape[0]),
+                          width=int(trace.shape[1]))
+    return trace
+
+
 def _run_proof_jobs(jobs: list, mesh) -> dict:
     """Run independent STARK proving jobs, concurrently when the mesh
     has devices to split.
@@ -586,6 +596,8 @@ class TpuBackend(ProverBackend):
 
     def _prove_impl(self, program_input: ProgramInput,
                     proof_format: str) -> dict:
+        import time as _time
+
         from ..guest import transfer_log as tl_mod
         from ..guest.witness_oracles import WitnessOracles
         from ..models import token_air as tka
@@ -628,6 +640,12 @@ class TpuBackend(ProverBackend):
                                meta={"lease_token": ckpt_ctx.lease_token})
             faults.inject("backend.phase", None, kinds=("drop",))
 
+        # `prove.vm_batch`: everything between the execution and the first
+        # trace: the VM batch, the access records, every job's public
+        # inputs and the binding statement (host Python; the job closures
+        # below only close over what is made here and run later).  One
+        # finished interval, recorded where it ends.
+        vmb_wall0 = _time.time()
         vm_batch = None
         try:
             oracles = WitnessOracles(program_input.witness, initial_root)
@@ -656,8 +674,9 @@ class TpuBackend(ProverBackend):
         pub = sua.state_update_public_inputs(records, r_pre, r_post, S)
 
         def _state_job(job_mesh):
-            trace = sua.generate_state_update_trace(records, r_pre,
-                                                    depth, S)
+            trace = _traced_gen("StateUpdateAir",
+                                sua.generate_state_update_trace,
+                                records, r_pre, depth, S)
             return stark_prover.prove(air, trace, pub, PARAMS,
                                       mesh=job_mesh)
 
@@ -677,7 +696,9 @@ class TpuBackend(ProverBackend):
             vm_pub = ta.transfer_public_inputs(vm_batch.segs)
 
             def _transfer_job(job_mesh):
-                trace = ta.generate_transfer_trace(vm_batch.segs)
+                trace = _traced_gen("TransferAir",
+                                    ta.generate_transfer_trace,
+                                    vm_batch.segs)
                 return stark_prover.prove(vm_air, trace, vm_pub,
                                           PARAMS, mesh=job_mesh)
 
@@ -688,7 +709,9 @@ class TpuBackend(ProverBackend):
                 tok_pub = tka.token_public_inputs(vm_batch.tok_segs)
 
                 def _token_job(job_mesh):
-                    trace = tka.generate_token_trace(vm_batch.tok_segs)
+                    trace = _traced_gen("TokenAir",
+                                        tka.generate_token_trace,
+                                        vm_batch.tok_segs)
                     return stark_prover.prove(tok_air, trace, tok_pub,
                                               PARAMS, mesh=job_mesh)
 
@@ -705,7 +728,8 @@ class TpuBackend(ProverBackend):
 
                     def _bc_job(job_mesh, _air=air_bc, _call=call,
                                 _pub=pub_bc):
-                        trace = bca.generate_bytecode_trace(
+                        trace = _traced_gen(
+                            "BytecodeAir", bca.generate_bytecode_trace,
                             _call.steps, _call.snaps)
                         return stark_prover.prove(_air, trace, _pub,
                                                   PARAMS, mesh=job_mesh)
@@ -722,14 +746,21 @@ class TpuBackend(ProverBackend):
         limbs = binding_limbs(encoded, r_pre, r_post, digest, vm_pub,
                               tok_pub, bc_pubs)
         bind_air = pair.Poseidon2SpongeAir(num_chunks=len(limbs) // 8)
-        bind_trace = pair.generate_sponge_trace(limbs)
         bind_pub = pair.sponge_public_inputs(limbs)
-        if vm_batch is not None and self.mesh is None:
-            vm_rows = ta.segment_count(len(vm_batch.segs)) * ta.SEG_LEN
-            stark_prover.compile_ahead(vm_air, vm_rows, PARAMS)
-            stark_prover.warm_fri_programs(vm_rows, PARAMS)
-        stark_prover.compile_ahead(bind_air, bind_trace.shape[0], PARAMS,
-                                   self.mesh)
+        tracing.record_span("prove.vm_batch", vmb_wall0,
+                            _time.time() - vmb_wall0)
+        bind_trace = _traced_gen("Poseidon2SpongeAir",
+                                 pair.generate_sponge_trace, limbs)
+        # look-ups when the programs are warm; cold, the builds run on
+        # background threads and a job pays for one it has to wait for
+        # under its `prove.phase_build`
+        with tracing.span("prove.compile_ahead"):
+            if vm_batch is not None and self.mesh is None:
+                vm_rows = ta.segment_count(len(vm_batch.segs)) * ta.SEG_LEN
+                stark_prover.compile_ahead(vm_air, vm_rows, PARAMS)
+                stark_prover.warm_fri_programs(vm_rows, PARAMS)
+            stark_prover.compile_ahead(bind_air, bind_trace.shape[0],
+                                       PARAMS, self.mesh)
 
         results = _run_proof_jobs(jobs, self.mesh)
         state_proof = results["state_proof"]

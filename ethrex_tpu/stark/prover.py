@@ -155,18 +155,24 @@ def _phases(air: Air, log_n: int, lb: int, shift: int,
         pending = _PHASE_BUILDS.get(key)
         if pending is None:
             mine = _PHASE_BUILDS[key] = Future()
+    # a miss is spanned (a hit is a dictionary look-up): the build
+    # itself, or the wait for the one compile_ahead has in flight
     if pending is not None:
-        return pending.result()
+        with tracing.span("prove.phase_build", air=type(air).__name__):
+            return pending.result()
     try:
-        t0 = time.perf_counter()
-        bodies, plan = _build_phases(air, log_n, lb, shift, mesh)
-        built = PhasePrograms(
-            _aot_phases(air, log_n, lb, shift, bodies, plan, mesh), plan)
+        with tracing.span("prove.phase_build",
+                          air=type(air).__name__) as build:
+            bodies, plan = _build_phases(air, log_n, lb, shift, mesh)
+            built = PhasePrograms(
+                _aot_phases(air, log_n, lb, shift, bodies, plan, mesh),
+                plan)
         # retrace telemetry: every miss here is a fresh set of programs
         from ..parallel import mesh as mesh_lib
 
-        record_kernel_build(type(air).__name__, time.perf_counter() - t0,
-                            mesh=mesh_lib.shape_label(mesh))
+        if build is not None:
+            record_kernel_build(type(air).__name__, build.seconds,
+                                mesh=mesh_lib.shape_label(mesh))
     except BaseException as exc:
         with _PHASE_LOCK:
             del _PHASE_BUILDS[key]
@@ -268,6 +274,21 @@ def _record_phase_wall(air_name: str, kernel: str, seconds: float) -> None:
         hlo_introspect.record_collective_share(air_name, kernel, seconds)
     except Exception:
         pass
+
+
+def _nbytes(arrays) -> int:
+    """Bytes of the host arrays in a (nested) tuple or list: attribute
+    reads, no pass over the data."""
+    return sum(a.nbytes for a in jax.tree_util.tree_leaves(arrays))
+
+
+def _ckpt_copy(phase: str, arrays):
+    """The device-to-host copy of a phase's outputs for its checkpoint
+    (and for the query phase's host mirrors), under its leaf span."""
+    with tracing.span("prove.ckpt_copy", stage="ckpt", phase=phase) as sp:
+        got = jax.device_get(arrays)
+        tracing.set_attrs(sp, d2h_bytes=_nbytes(got))
+    return got
 
 
 def _record_prove_throughput(cells: int, seconds: float) -> None:
@@ -814,8 +835,8 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
                 "trace_root": [int(x) for x in _canon(trace_root)]})
             ch.absorb_digest(trace_root)
         if store is not None:
-            lc_np, lr_np, lt_np = jax.device_get(
-                (lde_cols, lde_rows, tuple(levels_t)))
+            lc_np, lr_np, lt_np = _ckpt_copy(
+                "commit", (lde_cols, lde_rows, tuple(levels_t)))
             host.update(lde_cols=lc_np, lde_rows=lr_np,
                         levels_t=list(lt_np))
             store.store("commit", {"lde_cols": lc_np, "lde_rows": lr_np,
@@ -859,8 +880,8 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
                 "quotient_root": [int(x) for x in _canon(q_root)]})
             ch.absorb_digest(q_root)
         if store is not None:
-            ck_np, ql_np, qr_np, lq_np = jax.device_get(
-                (chunks, q_lde, q_rows, tuple(levels_q)))
+            ck_np, ql_np, qr_np, lq_np = _ckpt_copy(
+                "quotient", (chunks, q_lde, q_rows, tuple(levels_q)))
             host.update(chunks=ck_np, q_lde=ql_np, q_rows=qr_np,
                         levels_q=list(lq_np))
             store.store("quotient", {"chunks": ck_np, "q_lde": ql_np,
@@ -913,8 +934,8 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
             for tup in t_at_z + t_at_zg + q_at_z:
                 ch.absorb_ext(tup)
         if store is not None:
-            tz_np, tzg_np, qz_np = jax.device_get(
-                (t_z_dev, t_zg_dev, q_z_dev))
+            tz_np, tzg_np, qz_np = _ckpt_copy(
+                "open", (t_z_dev, t_zg_dev, q_z_dev))
             host.update(t_z=tz_np, t_zg=tzg_np, q_z=qz_np)
             store.store("open", {"t_z": tz_np, "t_zg": tzg_np,
                                  "q_z": qz_np, "t_at_z": t_at_z,
@@ -945,14 +966,14 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
             zeta_dev = progs.put_small(ext.to_device(zeta))
             zeta_g_dev = progs.put_small(ext.to_device(zeta_g))
             gamma_dev = progs.put_small(ext.to_device(gamma))
-            t_k = time.perf_counter()
-            F = rt.guard_phase(
-                "fri", air_name,
-                lambda: p_deep(lde_rows, q_lde, t_z_dev, t_zg_dev,
-                               q_z_dev, zeta_dev, zeta_g_dev, gamma_dev))
-            jax.block_until_ready(F)
-            _record_phase_wall(air_name, "deep",
-                               time.perf_counter() - t_k)
+            with tracing.span("prove.deep") as deep:
+                F = rt.guard_phase(
+                    "fri", air_name,
+                    lambda: p_deep(lde_rows, q_lde, t_z_dev, t_zg_dev,
+                                   q_z_dev, zeta_dev, zeta_g_dev, gamma_dev))
+                jax.block_until_ready(F)
+            if deep is not None:
+                _record_phase_wall(air_name, "deep", deep.seconds)
             fparams = fri.FriParams(
                 log_blowup=lb, num_queries=params.num_queries,
                 log_final_size=params.log_final_size, shift=shift,
@@ -980,30 +1001,36 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
     # ---- openings of trace/quotient at the query indices -----------------
     with tracing.span("prove.query", stage="query",
                       num_queries=params.num_queries):
-        if all(k in host for k in ("lde_rows", "levels_t", "q_rows",
-                                   "levels_q")):
-            rows_np, q_rows_np = host["lde_rows"], host["q_rows"]
-            lt_np, lq_np = host["levels_t"], host["levels_q"]
-        else:
-            rows_np, q_rows_np, lt_np, lq_np = jax.device_get(
-                (lde_rows, q_rows, tuple(levels_t), tuple(levels_q)))
-        lde_rows_c = bb.from_mont_host(rows_np)
-        q_rows_c = bb.from_mont_host(q_rows_np)
-        levels_t_c = [bb.from_mont_host(l) for l in lt_np]
-        levels_q_c = [bb.from_mont_host(l) for l in lq_np]
+        with tracing.span("query.canon") as canon:
+            d2h_bytes = 0
+            if all(k in host for k in ("lde_rows", "levels_t", "q_rows",
+                                       "levels_q")):
+                rows_np, q_rows_np = host["lde_rows"], host["q_rows"]
+                lt_np, lq_np = host["levels_t"], host["levels_q"]
+            else:
+                rows_np, q_rows_np, lt_np, lq_np = jax.device_get(
+                    (lde_rows, q_rows, tuple(levels_t), tuple(levels_q)))
+                d2h_bytes = _nbytes((rows_np, q_rows_np, lt_np, lq_np))
+            lde_rows_c = bb.from_mont_host(rows_np)
+            q_rows_c = bb.from_mont_host(q_rows_np)
+            levels_t_c = [bb.from_mont_host(l) for l in lt_np]
+            levels_q_c = [bb.from_mont_host(l) for l in lq_np]
+            tracing.set_attrs(canon, d2h_bytes=d2h_bytes)
         half = N // 2
         openings = []
-        for q in indices:
-            entry = {}
-            for name, rows_c, levels_c in (
-                ("trace", lde_rows_c, levels_t_c),
-                ("quotient", q_rows_c, levels_q_c),
-            ):
-                for tag, idx in (("lo", q), ("hi", q + half)):
-                    entry[f"{name}_{tag}"] = [int(v) for v in rows_c[idx]]
-                    entry[f"{name}_{tag}_path"] = \
-                        merkle.open_path_canonical(levels_c, idx)
-            openings.append(entry)
+        with tracing.span("query.paths"):
+            for q in indices:
+                entry = {}
+                for name, rows_c, levels_c in (
+                    ("trace", lde_rows_c, levels_t_c),
+                    ("quotient", q_rows_c, levels_q_c),
+                ):
+                    for tag, idx in (("lo", q), ("hi", q + half)):
+                        entry[f"{name}_{tag}"] = [int(v)
+                                                  for v in rows_c[idx]]
+                        entry[f"{name}_{tag}_path"] = \
+                            merkle.open_path_canonical(levels_c, idx)
+                openings.append(entry)
 
     # live throughput gauge: trace cells proven per end-to-end second
     # (transcript + host query openings included — the honest number)

@@ -29,8 +29,25 @@ import time
 
 # Completed traces kept in memory (oldest evicted first).
 TRACE_CAPACITY = 256
-# Spans kept per trace (runaway-loop protection).
+# Spans kept per trace (runaway-loop protection).  Over the cap the
+# OLDEST spans go first (`Tracer.trimmed` counts them): that would be
+# `prover.idle` and `prover.assign`, which the benchmark reads.
 SPANS_PER_TRACE = 512
+# What one BASELINE-1 batch trace may hold: three quarters of
+# WIRE_MAX_SPANS, so that a batch ships whole with room for a retry's
+# spans.  The wire drops the SHORTEST spans first, which are the leaf
+# spans that say where the time went.  Nothing per query, per row or per
+# segment gets a span; tests/test_distributed_tracing.py holds the count.
+BATCH_SPAN_BUDGET = 192
+# Spans that ride a batch's trace but are no part of the batch's
+# lifecycle: the prover client's wait for work, recorded into the batch
+# that ended it, and its clean-up after the ack.  They say where the
+# CLIENT's time went (benchmark/metrics/prover_idle_s, ckpt_s).  The
+# batch was not waiting on either, so `critical_path` and the trace
+# summaries leave them out of the wall and of every component: an idle
+# fleet's hours are not prove time, and a second prover's empty polls
+# do not cover a reassigned batch's queue-wait.
+OFF_PATH_SPANS = frozenset(("prover.idle", "prover.ckpt_complete"))
 
 # -- span-shipping wire format (docs/OBSERVABILITY.md "Distributed
 # tracing").  A prover attaches ``export_wire(trace_id)`` to ProofSubmit
@@ -136,7 +153,13 @@ class Tracer:
         self.capacity = capacity
         self._traces: "collections.OrderedDict[str, dict]" = \
             collections.OrderedDict()
+        # whole traces evicted from the ring
         self.dropped = 0
+        # spans cut from the front of a trace over SPANS_PER_TRACE
+        self.trimmed = 0
+        # spans ``export_wire`` left out of a payload (cumulative
+        # heartbeat payloads count a span each time it is left out)
+        self.wire_truncated = 0
         # spans merged from / dropped by remote payloads (``ingest``)
         self.ingested = 0
         self.ingest_dropped = 0
@@ -154,8 +177,10 @@ class Tracer:
                 # A late span keeps its trace warm in the ring.
                 self._traces.move_to_end(span.trace_id)
             rec["spans"].append(span.to_json())
-            if len(rec["spans"]) > SPANS_PER_TRACE:
-                del rec["spans"][:len(rec["spans"]) - SPANS_PER_TRACE]
+            over = len(rec["spans"]) - SPANS_PER_TRACE
+            if over > 0:
+                del rec["spans"][:over]
+                self.trimmed += over
 
     def ingest(self, payload, source: "str | None" = None) -> int:
         """Merge a shipped span payload (``export_wire``) into the ring.
@@ -295,11 +320,14 @@ class Tracer:
             spans = [s for s in spans if isinstance(s, dict)]
             if not spans:
                 continue
-            start = min(s.get("start") or 0.0 for s in spans)
+            # the extent is the batch's, not the client's (OFF_PATH_SPANS)
+            timed = [s for s in spans
+                     if s.get("name") not in OFF_PATH_SPANS] or spans
+            start = min(s.get("start") or 0.0 for s in timed)
             root = next((s for s in spans if not s.get("parentId")), None)
             if root is not None:
                 end = max((s.get("start") or 0.0) + (s.get("seconds") or 0.0)
-                          for s in spans)
+                          for s in timed)
                 seconds = max(0.0, end - start)
             else:
                 # Rootless trace: late or shipped spans kept it warm in
@@ -307,11 +335,11 @@ class Tracer:
                 # unknowable.  The longest single span stands in for the
                 # duration — a partial trace must not skew the slowest
                 # sort with a fabricated extent (or raise on render).
-                seconds = max(s.get("seconds") or 0.0 for s in spans)
+                seconds = max(s.get("seconds") or 0.0 for s in timed)
             entry = {
                 "traceId": tid,
                 "name": (root if root is not None else
-                         min(spans, key=lambda s: s.get("start") or 0.0)
+                         min(timed, key=lambda s: s.get("start") or 0.0)
                          ).get("name") or "?",
                 "start": start,
                 "seconds": seconds,
@@ -349,6 +377,8 @@ class Tracer:
         with self.lock:
             self._traces.clear()
             self.dropped = 0
+            self.trimmed = 0
+            self.wire_truncated = 0
             self.ingested = 0
             self.ingest_dropped = 0
 
@@ -465,6 +495,35 @@ class trace_context:
         return False
 
 
+def set_attrs(sp: "Span | None", **attrs) -> None:
+    """Attributes known only inside the span's body (a byte count, a
+    number of tries).  `sp` is what ``with span(...) as sp`` gave, None
+    included.  Never raises."""
+    if sp is not None:
+        for key, value in attrs.items():
+            sp.set_attr(key, value)
+
+
+def record_span(name: str, start: float, seconds: float, **attrs) -> None:
+    """Record a finished interval as a span under the current context.
+
+    For work that is only known to belong to a trace once it is over:
+    the prover client's wait for a batch joins that batch's trace when
+    the batch arrives.  ``start`` is on the wall clock (``time.time()``)
+    like every span's.  With no enclosing context a new trace is
+    started.  Never raises: bad input degrades to a missing span.
+    """
+    try:
+        st = _stack()
+        trace_id, parent_id = st[-1] if st else (new_trace_id(), None)
+        sp = Span(trace_id, new_span_id(), parent_id, str(name), attrs)
+        sp.start = float(start)
+        sp.seconds = max(0.0, float(seconds))
+        TRACER.record(sp)
+    except Exception:
+        pass
+
+
 # ---------------------------------------------------------------------------
 # Span shipping, critical-path analysis, Perfetto export
 # (docs/OBSERVABILITY.md "Distributed tracing")
@@ -492,6 +551,7 @@ def export_wire(trace_id, max_spans: int = WIRE_MAX_SPANS,
         if not spans:
             return None
         truncated = False
+        held = len(spans)
         if len(spans) > max_spans:
             spans.sort(key=lambda s: s.get("seconds") or 0.0, reverse=True)
             spans = spans[:max(1, max_spans)]
@@ -507,6 +567,9 @@ def export_wire(trace_id, max_spans: int = WIRE_MAX_SPANS,
                            reverse=True)
                 spans = spans[:max(1, len(spans) // 2)]
                 truncated = True
+        if truncated:
+            with t.lock:
+                t.wire_truncated += held - len(spans)
         spans.sort(key=lambda s: s.get("start") or 0.0)
         return {"v": WIRE_VERSION, "spans": spans, "truncated": truncated}
     except Exception:
@@ -536,7 +599,10 @@ def _component(s: dict) -> str:
     The classification the walker attributes wall time to: stage spans become
     ``compile`` / ``prove/<stage>``, transport and lifecycle spans map
     by name, anything unrecognized is ``other`` (uncovered top-level
-    time is ``queue-wait``, added by the walker itself).
+    time is ``queue-wait``, added by the walker itself; there a span
+    that is ``other`` by itself takes the component of the nearest
+    stage span it runs inside, so ``fri.layer`` inside
+    ``prove.fri_fold`` stays ``prove/fri_fold``).
     """
     attrs = s.get("attrs")
     stage = attrs.get("stage") if isinstance(attrs, dict) else None
@@ -544,7 +610,8 @@ def _component(s: dict) -> str:
         stage = str(stage)
         return "compile" if "compile" in stage else f"prove/{stage}"
     name = str(s.get("name") or "")
-    if name == "prover.assign":
+    if name in ("prover.assign", "prover.fetch_input"):
+        # the assignment, as the coordinator and as the client time it
         return "assign"
     if name in ("prover.submit", "prover.store_proof"):
         return "transport"
@@ -573,14 +640,16 @@ def critical_path(trace: "dict | None") -> dict:
     child may outlive its parent — the shipped ``prover.prove`` span
     runs long after its milliseconds-long ``prover.assign`` parent
     closed — and still claims its segments.  Segments nothing covers
-    are ``queue-wait``.
+    are ``queue-wait``.  ``OFF_PATH_SPANS`` are left out altogether:
+    they neither stretch the wall nor cover a gap.
     """
     tid = trace.get("traceId") if isinstance(trace, dict) else None
     raw = trace.get("spans") if isinstance(trace, dict) else None
     spans = [s for s in (raw or [])
              if isinstance(s, dict)
              and isinstance(s.get("start"), (int, float))
-             and isinstance(s.get("seconds"), (int, float))]
+             and isinstance(s.get("seconds"), (int, float))
+             and s.get("name") not in OFF_PATH_SPANS]
     out = {"traceId": tid, "start": None, "wallSeconds": 0.0,
            "spanCount": len(spans), "components": {}, "chain": [],
            "sources": [], "partial": False}
@@ -615,6 +684,24 @@ def critical_path(trace: "dict | None") -> dict:
             cur = parent
         return d
 
+    def _inherited(s):
+        # a span that names nothing the walker knows (`fri.layer`,
+        # `query.canon`) belongs to the stage it runs inside; under no
+        # stage it stays `other`, as its seconds were before it existed
+        comp = _component(s)
+        cur = s
+        for _ in range(64):
+            if comp != "other":
+                break
+            pid = cur.get("parentId")
+            cur = ids.get(pid) if isinstance(pid, str) else None
+            if cur is None:
+                break
+            attrs = cur.get("attrs")
+            if isinstance(attrs, dict) and attrs.get("stage"):
+                comp = _component(cur)
+        return comp
+
     ranked = [((_depth(s), s["start"]), s) for s in spans]
     wall_lo = min(s["start"] for s in spans)
     wall_hi = max(_end(s) for s in spans)
@@ -635,7 +722,7 @@ def critical_path(trace: "dict | None") -> dict:
             comps["queue-wait"] = comps.get("queue-wait", 0.0) + (b - a)
             continue
         sp = best[1]
-        comp = _component(sp)
+        comp = _inherited(sp)
         comps[comp] = comps.get(comp, 0.0) + (b - a)
         last = chain[-1] if chain else None
         if last is not None and last["spanId"] == sp.get("spanId") \

@@ -255,3 +255,36 @@ def test_bench_default_mode_on_a_host_without_a_chip(tmp_path):
     assert proc.returncode == 3
     assert proc.stdout.strip() == ""
     assert "refusing to publish" in proc.stderr
+
+
+@pytest.mark.parametrize("event, key", [
+    ("/jax/core/compile/jaxpr_trace_duration", "trace_seconds"),
+    ("/jax/core/compile/jaxpr_to_mlir_module_duration", "lower_seconds"),
+    ("/jax/compilation_cache/cache_retrieval_time_sec",
+     "cache_retrieval_seconds"),
+    ("/jax/core/compile/backend_compile_duration", "compile_seconds"),
+])
+def test_duration_listener_splits_a_programs_way_to_the_device(event, key):
+    """Trace, lower, backend compile and cache retrieval each have a
+    key of their own in jax_cache.STATS (the set-up log's split); an
+    event of another name moves none of them."""
+    before = dict(jax_cache.STATS)
+    jax_cache._on_duration(event, 0.25)
+    jax_cache._on_duration("/jax/some/other_duration", 9.0)
+    moved = {k for k in before if jax_cache.STATS[k] != before[k]}
+    assert key in moved and moved <= {key, "compiles"}
+    assert jax_cache.STATS[key] == pytest.approx(before[key] + 0.25)
+    assert jax_cache.STATS["compiles"] - before["compiles"] == \
+        (1 if key == "compile_seconds" else 0)
+
+
+def test_a_real_jit_moves_the_trace_and_lower_seconds():
+    import jax
+    import jax.numpy as jnp
+
+    jax_cache.install_monitoring()
+    before = dict(jax_cache.STATS)
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+    assert jax_cache.STATS["trace_seconds"] > before["trace_seconds"]
+    assert jax_cache.STATS["lower_seconds"] > before["lower_seconds"]
+    assert jax_cache.STATS["compiles"] > before["compiles"]

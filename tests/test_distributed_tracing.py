@@ -9,6 +9,8 @@ import subprocess
 import sys
 import time
 
+import pytest
+
 from ethrex_tpu.crypto import secp256k1
 from ethrex_tpu.l2.l1_client import InMemoryL1
 from ethrex_tpu.l2.sequencer import Sequencer, SequencerConfig
@@ -242,6 +244,79 @@ def test_critical_path_is_defensive():
         "junk", {"spanId": "no-times"},
         {"spanId": "ok", "name": "n", "start": 1.0, "seconds": 1.0}]})
     assert cp["spanCount"] == 1 and cp["wallSeconds"] == 1.0
+
+
+def _reassigned_batch(idle_seconds):
+    """Prover x takes the batch and dies; prover y, whose polls came
+    back empty all the while, gets it 30 s later."""
+    return [
+        _span("a1", "prover.assign", 100.0, 0.01),
+        _span("p1", "prover.prove", 100.01, 4.99, parent="a1", source="x"),
+        _span("a2", "prover.assign", 135.0, 0.01),
+        _span("i2", "prover.idle", 135.0 - idle_seconds, idle_seconds,
+              parent="a2", source="y"),
+        _span("f2", "prover.fetch_input", 135.0, 0.02, parent="a2",
+              source="y"),
+        _span("p2", "prover.prove", 135.02, 9.98, parent="a2", source="y"),
+        _span("c2", "prover.ckpt_complete", 145.5, 0.5, parent="a2",
+              source="y"),
+    ]
+
+
+@pytest.mark.parametrize("idle_seconds", [1.0, 40.0, 7200.0])
+def test_critical_path_leaves_the_clients_wait_out(idle_seconds):
+    spans = _reassigned_batch(idle_seconds)
+    cp = critical_path(_trace(spans))
+    bare = critical_path(_trace(
+        [s for s in spans if s["name"] not in tracing.OFF_PATH_SPANS]))
+    # neither the wait before the batch nor the clean-up after the ack
+    # moves the wall or any component, however long the fleet idled
+    assert cp == bare
+    assert cp["start"] == 100.0 and abs(cp["wallSeconds"] - 45.0) < 1e-9
+    assert abs(cp["components"]["prove"] - (4.99 + 9.98)) < 1e-9
+    # the reassigned batch keeps its queue-wait: y's empty polls do not
+    # cover the 30 s in which nobody held it
+    assert abs(cp["components"]["queue-wait"] - 30.0) < 1e-9
+    # the fetch is the assignment as the client times it
+    assert abs(cp["components"]["assign"] - 0.03) < 1e-9
+    assert abs(sum(cp["components"].values()) - 45.0) < 1e-9
+
+
+def test_trace_summaries_leave_the_clients_wait_out():
+    t = Tracer(capacity=8)
+    tid = "cd" * 8
+    root = _record(t, tid, "prover.assign", 1000.0, 0.01)
+    _record(t, tid, "prover.idle", 1000.0 - 7200.0, 7200.0, parent=root)
+    _record(t, tid, "prover.prove", 1000.01, 2.0, parent=root)
+    _record(t, "ce" * 8, "root", 0.0, 3.0)
+    slowest = t.slowest(5)
+    assert [e["traceId"] for e in slowest] == ["ce" * 8, tid]
+    assert slowest[1]["start"] == 1000.0
+    assert abs(slowest[1]["seconds"] - 2.01) < 1e-6
+    assert slowest[1]["spanCount"] == 3     # the span is still listed
+
+
+def test_critical_path_leaf_span_takes_its_stages_component():
+    cp = critical_path(_trace([
+        _span("p", "prover.prove", 0.0, 10.0),
+        _span("b", "backend.prove", 0.0, 10.0, parent="p"),
+        _span("v", "prove.vm_batch", 0.0, 1.0, parent="b"),
+        _span("j", "prove.vm_circuits/TransferAir", 1.0, 9.0, parent="b",
+              stage="vm_circuits"),
+        _span("g", "prove.trace_gen", 1.0, 2.0, parent="j"),
+        _span("c", "ckpt.store", 3.0, 1.0, parent="j", stage="ckpt"),
+        _span("f", "prove.fri_fold", 4.0, 3.0, parent="j",
+              stage="fri_fold"),
+        _span("l", "fri.layer", 4.0, 1.0, parent="f"),
+        _span("r", "fri.grind", 5.0, 2.0, parent="f"),
+        _span("q", "prove.query", 7.0, 3.0, parent="j", stage="query"),
+        _span("n", "query.canon", 7.0, 2.9, parent="q"),
+    ]))
+    # the leaf spans name no stage and leave the components where they
+    # were before the spans existed; `ckpt` is the one new component
+    assert {k: round(v, 9) for k, v in cp["components"].items()} == {
+        "prove/vm_circuits": 2.0, "prove/ckpt": 1.0,
+        "prove/fri_fold": 3.0, "prove/query": 3.0, "other": 1.0}
 
 
 # ---------------------------------------------------------------------------
@@ -559,3 +634,414 @@ def test_bench_measure_reports_critical_path():
     # and the breakdown comes from the tracing walker, not a hand-rolled
     # sum that could drift from the RPC's attribution
     assert "critical_path" in inspect.getsource(bench_suite.measure)
+
+
+# ---------------------------------------------------------------------------
+# leaf spans of a batch (PERF.md section 3): every host second of a
+# batch under a span that names the work, with bytes and tries as
+# attributes
+
+
+import hashlib  # noqa: E402
+import importlib.util  # noqa: E402
+import threading  # noqa: E402
+
+import numpy as np  # noqa: E402
+
+from ethrex_tpu.models import fibonacci as fib  # noqa: E402
+from ethrex_tpu.prover import checkpoint as ckpt  # noqa: E402
+from ethrex_tpu.prover import tpu_backend  # noqa: E402
+from ethrex_tpu.prover.client import ProverClient  # noqa: E402
+from ethrex_tpu.stark import prover as stark_prover  # noqa: E402
+from ethrex_tpu.stark.prover import StarkParams  # noqa: E402
+
+SMALL = StarkParams(log_blowup=2, num_queries=16, log_final_size=4)
+FIB_N = 64
+# sha256 of json.dumps(proof, sort_keys=True) of the Fibonacci proof
+# below, made at the commit before the leaf spans went in (05733cb)
+FIB_PROOF_SHA256 = \
+    "2ef55ed21958e7c8cf41396345f444aa9d562ae6831b4a59d19d4c92c37eb68a"
+# FRI layers of the three STARKs of a BASELINE-1 batch (codewords 2^17,
+# 2^17 and 2^12 down to a final 2^4), which the test-size batch swaps
+# for its own before it is held to the budget
+BASELINE1_FRI_LAYERS = 13 + 13 + 8
+
+LEAF_SPANS = {
+    "prover.idle": ("polls", "batch"),
+    "prover.fetch_input": ("batch",),
+    "prover.ckpt_complete": ("disk_bytes",),
+    "prove.vm_batch": (),
+    "prove.compile_ahead": (),
+    "prove.trace_gen": ("air", "rows", "width"),
+    "prove.deep": (),
+    "prove.ckpt_copy": ("phase", "d2h_bytes"),
+    "ckpt.store": ("phase", "job", "disk_bytes"),
+    "fri.layer": ("log_n", "d2h_bytes", "d2h_s"),
+    "fri.final": ("d2h_bytes",),
+    "fri.grind": ("tries",),
+    "fri.open_queries": (),
+    "query.canon": ("d2h_bytes",),
+    "query.paths": (),
+}
+
+
+def _fib_material():
+    trace = fib.generate_trace(FIB_N)
+    return fib.FibonacciAir(), trace, fib.public_inputs(trace)
+
+
+def _unspanned_reader():
+    """benchmark/metrics/unspanned_s.py: the test holds the trace to the
+    same arithmetic the benchmark's metric uses."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "metrics",
+        "unspanned_s.py")
+    spec = importlib.util.spec_from_file_location("unspanned_s", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+class _SmallStark:
+    """`stark/prover.py` as `TpuBackend` sees it, with every AIR swapped
+    for the 64-row Fibonacci AIR: the backend's own host work runs on
+    the real batch, the real `_prove_attempt` runs at test size."""
+
+    def __init__(self):
+        self.airs = []
+
+    def prove(self, air, trace, pub, params, mesh=None):
+        self.airs.append(type(air).__name__)
+        return stark_prover.prove(*_fib_material(), SMALL)
+
+    def compile_ahead(self, *args, **kwargs):
+        pass
+
+    def warm_fri_programs(self, *args, **kwargs):
+        pass
+
+
+@pytest.fixture(scope="module")
+def leaf_batch(tmp_path_factory):
+    """Two batches, one after the other, through coordinator ->
+    ProverClient.run_forever -> TpuBackend on the CPU.  The client
+    starts before the first is committed, so its first polls come back
+    empty.  The tests read the first; the second is there for what only
+    two can show (`next_spans`)."""
+    ckpt.set_checkpoint_dir(str(tmp_path_factory.mktemp("ckpt")))
+    stark_prover.prove(*_fib_material(), SMALL)     # programs built
+    patch = pytest.MonkeyPatch()
+    small = _SmallStark()
+    patch.setattr(tpu_backend, "stark_prover", small)
+    removed = {}
+    complete = ckpt.complete
+
+    def counting_complete(batch_id):
+        bdir = ckpt._batch_dir(batch_id)
+        removed[batch_id] = sorted(os.path.getsize(os.path.join(bdir, n))
+                                   for n in os.listdir(bdir))
+        return complete(batch_id)
+
+    patch.setattr(ckpt, "complete", counting_complete)
+    node = Node(Genesis.from_json(GENESIS))
+    l1 = InMemoryL1(needed_prover_types=[protocol.PROVER_TPU])
+    seq = Sequencer(node, l1, SequencerConfig(
+        needed_prover_types=(protocol.PROVER_TPU,)))
+    seq.coordinator.start()
+    trimmed0 = TRACER.trimmed
+    client = ProverClient(protocol.PROVER_TPU,
+                          [("127.0.0.1", seq.coordinator.port)],
+                          poll_interval=0.05, heartbeat_interval=0,
+                          prewarm=False)
+
+    def prove_batch(number):
+        node.submit_transaction(_transfer(number - 1))
+        seq.produce_block()
+        assert seq.commit_next_batch() is not None
+        deadline = time.monotonic() + 240
+        while len(client.proved) < number and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert client.proved == list(range(1, number + 1))
+        return TRACER.get_trace(seq.coordinator.batch_traces[number])["spans"]
+
+    try:
+        client.start()
+        time.sleep(0.3)
+        spans = prove_batch(1)
+        airs = list(small.airs)
+        next_spans = prove_batch(2)
+        client.stop()
+        batch = {"spans": spans, "next_spans": next_spans,
+                 "proof": seq.rollup.get_proof(1, protocol.PROVER_TPU),
+                 "airs": airs, "removed": removed[1],
+                 "trimmed": TRACER.trimmed - trimmed0}
+    finally:
+        client.stop()
+        seq.stop()
+        patch.undo()
+        ckpt.set_checkpoint_dir(None)
+    return batch
+
+
+@pytest.mark.parametrize("name", sorted(LEAF_SPANS))
+def test_batch_trace_has_leaf_span_with_attributes(leaf_batch, name):
+    found = [s for s in leaf_batch["spans"] if s["name"] == name]
+    assert found, f"no {name} span in the batch's trace"
+    for s in found:
+        attrs = s.get("attrs") or {}
+        for key in LEAF_SPANS[name]:
+            assert key in attrs, f"{name} lacks {key}: {attrs}"
+            if key not in ("air", "phase", "job"):
+                assert isinstance(attrs[key], (int, float)), (name, key)
+        assert s["seconds"] >= 0 and s["status"] == "ok"
+
+
+def test_leaf_spans_count_what_they_say(leaf_batch):
+    spans = leaf_batch["spans"]
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    # one STARK per job, each through the real prover at test size
+    assert leaf_batch["airs"] == ["StateUpdateAir", "TransferAir",
+                                  "Poseidon2SpongeAir"]
+    gens = {s["attrs"]["air"]: s["attrs"] for s in by_name["prove.trace_gen"]}
+    assert set(gens) == set(leaf_batch["airs"])
+    assert gens["TransferAir"]["width"] == 278
+    # checkpoint copies, by the arrays' shapes (Fibonacci: w=2, B=4)
+    w, B, n = 2, 1 << SMALL.log_blowup, FIB_N
+    N = n * B
+    levels = (2 * N - 1) * 8 * 4
+    want = {"commit": 2 * w * N * 4 + levels,
+            "quotient": (B * n * 4 + B * 4 * N + N * B * 4) * 4 + levels,
+            "open": (2 * w + B) * 4 * 4}
+    copies = by_name["prove.ckpt_copy"]
+    assert len(copies) == 9
+    for s in copies:
+        assert s["attrs"]["d2h_bytes"] == want[s["attrs"]["phase"]]
+    # every envelope written is an envelope the ack removed, to the byte
+    stores = by_name["ckpt.store"]
+    assert sorted(s["attrs"]["phase"] for s in stores) == sorted(
+        ["execute"] + 3 * ["commit", "quotient", "open", "fri", "proof"])
+    assert sorted(s["attrs"]["disk_bytes"] for s in stores) == \
+        leaf_batch["removed"]
+    assert by_name["prover.ckpt_complete"][0]["attrs"]["disk_bytes"] == \
+        sum(leaf_batch["removed"])
+    # grinding: tries is the nonce found, plus one
+    proof = leaf_batch["proof"]
+    nonces = [proof[k]["fri"]["pow_nonce"]
+              for k in ("state_proof", "vm_proof", "proof")]
+    assert [s["attrs"]["tries"] for s in by_name["fri.grind"]] == \
+        [nonce + 1 for nonce in nonces]
+    # FRI layers: bytes halve from layer to layer (codeword + tree)
+    layers = [s["attrs"] for s in by_name["fri.layer"][:4]]
+    assert [a["log_n"] for a in layers] == [8, 7, 6, 5]
+    assert [a["d2h_bytes"] for a in layers] == \
+        [(1 << k) * 16 + ((1 << k) - 1) * 32 for k in (8, 7, 6, 5)]
+    # the query phase reads the host mirrors: no copy of its own
+    assert all(s["attrs"]["d2h_bytes"] == 0 for s in by_name["query.canon"])
+
+
+def test_idle_joins_the_batch_that_ended_it(leaf_batch):
+    by_name = {s["name"]: s for s in leaf_batch["spans"]}
+    idle, fetch = by_name["prover.idle"], by_name["prover.fetch_input"]
+    assign, prove = by_name["prover.assign"], by_name["prover.prove"]
+    assert idle["traceId"] == assign["traceId"]
+    assert idle["parentId"] == assign["spanId"]
+    assert idle["start"] < assign["start"]
+    assert idle["attrs"]["polls"] >= 1 and idle["attrs"]["batch"] == 1
+    assert idle["seconds"] >= 0.3
+    # the wait ends where the fetch begins, and the fetch holds the
+    # coordinator's assignment and ends before the prove
+    assert abs(idle["start"] + idle["seconds"] - fetch["start"]) < 1e-6
+    assert fetch["start"] <= assign["start"]
+    assert fetch["start"] + fetch["seconds"] <= prove["start"] + 1e-3
+
+
+def test_batch_critical_path_is_the_batchs_own(leaf_batch):
+    """The client idled >= 0.3 s before batch 1 and deleted its
+    checkpoints after the ack: neither is the batch's lifecycle, so the
+    wall, `prove` and the stage components read as without them."""
+    spans = leaf_batch["spans"]
+    on_path = [s for s in spans if s["name"] not in tracing.OFF_PATH_SPANS]
+    assert len(spans) - len(on_path) == 2
+    cp = critical_path({"traceId": "t", "spans": spans})
+    assert cp == critical_path({"traceId": "t", "spans": on_path})
+    by_name = {s["name"]: s for s in spans}
+    assert cp["start"] == min(s["start"] for s in on_path)
+    assert cp["start"] >= by_name["prover.idle"]["start"] + 0.3
+    assert abs(cp["wallSeconds"] - (
+        max(s["start"] + s["seconds"] for s in on_path) - cp["start"])) < 1e-9
+    # the leaf spans left the stage components standing
+    for component in ("prove/fri_fold", "prove/query", "prove/ckpt",
+                      "prove/vm_circuits", "prove/state_proof"):
+        assert cp["components"].get(component, 0) > 0, cp["components"]
+
+
+def test_stark_stage_spans_never_nest(leaf_batch):
+    """`StageProfiler.tree()` sums the stark component's stages and
+    takes shares of the sum, and `prover_stage_seconds` is read the
+    same way: a stark stage inside a stark stage would count its
+    seconds twice.  So `fri.grind` (inside `fri_fold`) carries no stage,
+    nor does `prove.trace_gen` (inside its job's stage); `ckpt` runs
+    between the phases."""
+    from ethrex_tpu.perf import profiler
+
+    def stark(s):
+        return (s.get("attrs") or {}).get("stage") in profiler._STARK_STAGES
+
+    spans = leaf_batch["spans"]
+    ids = {s["spanId"]: s for s in spans}
+    staged = [s for s in spans if stark(s)]
+    assert {s["attrs"]["stage"] for s in staged} == profiler._STARK_STAGES
+    for s in staged:
+        up = ids.get(s["parentId"])
+        while up is not None:
+            assert not stark(up), (s["name"], up["name"])
+            up = ids.get(up["parentId"])
+    for name in ("fri.grind", "prove.trace_gen"):
+        assert all("stage" not in s["attrs"] for s in spans
+                   if s["name"] == name)
+
+
+def test_batch_extents_tile_the_clients_cycle(leaf_batch):
+    """The next batch's wait starts where this batch's last span ended
+    (its `prover.ckpt_complete`), so the traces' extents lie end to end
+    and sum to the window the benchmark times."""
+    end = max(s["start"] + s["seconds"] for s in leaf_batch["spans"])
+    (idle,) = [s for s in leaf_batch["next_spans"]
+               if s["name"] == "prover.idle"]
+    assert idle["attrs"]["batch"] == 2
+    assert idle["start"] == min(s["start"] for s in leaf_batch["next_spans"])
+    assert 0 <= idle["start"] - end < 0.05
+
+
+def test_batch_trace_stays_inside_the_span_budget(leaf_batch):
+    spans = leaf_batch["spans"]
+    layers = sum(1 for s in spans if s["name"] == "fri.layer")
+    at_full_size = len(spans) - layers + BASELINE1_FRI_LAYERS
+    assert at_full_size <= tracing.BATCH_SPAN_BUDGET, at_full_size
+    assert tracing.BATCH_SPAN_BUDGET <= 0.75 * tracing.WIRE_MAX_SPANS
+    assert leaf_batch["trimmed"] == 0
+    assert len({s["spanId"] for s in spans}) == len(spans)
+
+
+def test_leaf_spans_cover_the_batch(leaf_batch):
+    """A piece of work under no span leaves the same hole in every
+    batch; a stall of the test's machine (a batch is 1-2 s here) leaves
+    it in one.  So the better covered of the two is held to the 90%."""
+    reader = _unspanned_reader()
+    rows = [reader.batch_rows(leaf_batch[k]) for k in ("spans", "next_spans")]
+    shares = [r["covered"] / r["extent"] for r in rows]
+    assert max(shares) >= 0.9, (shares, rows)
+
+
+@pytest.mark.parametrize("checkpoints", [False, True])
+def test_proof_is_byte_identical_to_before_the_spans(tmp_path, checkpoints):
+    ckpt.set_checkpoint_dir(str(tmp_path / "ckpt"))
+    try:
+        if checkpoints:
+            with ckpt.batch_context(77, lease_token="tok"), \
+                    ckpt.job_scope("fib"):
+                proof = stark_prover.prove(*_fib_material(), SMALL)
+        else:
+            proof = stark_prover.prove(*_fib_material(), SMALL)
+    finally:
+        ckpt.complete(77)
+        ckpt.set_checkpoint_dir(None)
+    assert hashlib.sha256(json.dumps(proof, sort_keys=True).encode()) \
+        .hexdigest() == FIB_PROOF_SHA256
+
+
+def test_checkpoint_spans_match_the_files(tmp_path):
+    """`ckpt.store` says the file's size; `ckpt.load` spans a hit and
+    only counts a miss."""
+    ckpt.set_checkpoint_dir(str(tmp_path / "ckpt"))
+    parts = {"kind": "proof_ckpt", "job": "j", "phase": "commit"}
+    payload = {"rows": np.arange(4096, dtype=np.uint32)}
+    try:
+        misses0 = ckpt.STATS["misses"]
+        with tracing.trace_context(None) as tid:
+            assert ckpt.load(5, parts) is None
+            assert ckpt.store(5, parts, payload)
+            size = os.path.getsize(ckpt._entry_path(5, parts))
+            assert np.array_equal(ckpt.load(5, parts)["rows"],
+                                  payload["rows"])
+            assert ckpt.complete(5) == size
+        spans = TRACER.get_trace(tid)["spans"]
+        assert [s["name"] for s in spans] == ["ckpt.store", "ckpt.load"]
+        for s in spans:
+            assert s["attrs"]["disk_bytes"] == size
+            assert s["attrs"]["phase"] == "commit"
+        assert spans[0]["attrs"]["stage"] == "ckpt"
+        assert ckpt.STATS["misses"] == misses0 + 1
+    finally:
+        ckpt.set_checkpoint_dir(None)
+
+
+def test_resumed_proof_spans_its_load(tmp_path):
+    ckpt.set_checkpoint_dir(str(tmp_path / "ckpt"))
+    try:
+        with ckpt.batch_context(78), ckpt.job_scope("fib"):
+            first = stark_prover.prove(*_fib_material(), SMALL)
+            with tracing.trace_context(None) as tid:
+                again = stark_prover.prove(*_fib_material(), SMALL)
+        assert again == first
+        loads = [s for s in TRACER.get_trace(tid)["spans"]
+                 if s["name"] == "ckpt.load"]
+        assert [s["attrs"]["phase"] for s in loads] == ["proof"]
+        assert loads[0]["attrs"]["job"] == "fib"
+    finally:
+        ckpt.complete(78)
+        ckpt.set_checkpoint_dir(None)
+
+
+@pytest.mark.parametrize("name, start, seconds, attrs", [
+    (None, None, None, {}),
+    ("x", "yesterday", 1.0, {}),
+    ("x", 1.0, "long", {}),
+    ("x", float("nan"), float("inf"), {"k": object()}),
+    (object(), -1.0, -5.0, {"k": [1, 2]}),
+])
+def test_record_span_never_raises(name, start, seconds, attrs):
+    with tracing.trace_context(None) as tid:
+        tracing.record_span(name, start, seconds, **attrs)
+        tracing.set_attrs(None, k=1)
+    rec = TRACER.get_trace(tid)
+    for s in (rec or {}).get("spans", []):
+        assert s["seconds"] >= 0
+        json.dumps(s)
+
+
+def test_record_span_lands_under_the_current_context():
+    with tracing.trace_context("ef" * 8, "parent01"):
+        tracing.record_span("waited", 123.5, 2.25, polls=3)
+    (s,) = TRACER.get_trace("ef" * 8)["spans"]
+    assert (s["name"], s["start"], s["seconds"]) == ("waited", 123.5, 2.25)
+    assert s["parentId"] == "parent01" and s["attrs"] == {"polls": 3}
+
+
+def test_trimmed_counts_spans_cut_from_a_trace():
+    t = Tracer(capacity=4)
+    tid = "12" * 8
+    for i in range(tracing.SPANS_PER_TRACE):
+        _record(t, tid, f"s{i}", 100.0 + i, 0.5)
+    assert t.trimmed == 0
+    for i in range(7):
+        _record(t, tid, f"late{i}", 900.0 + i, 0.5)
+    assert t.trimmed == 7
+    kept = t.get_trace(tid)["spans"]
+    assert len(kept) == tracing.SPANS_PER_TRACE
+    assert kept[0]["name"] == "s7"          # oldest first out
+    t.clear()
+    assert t.trimmed == 0
+
+
+def test_wire_truncation_is_counted():
+    t = Tracer(capacity=4)
+    tid = "34" * 8
+    for i in range(10):
+        _record(t, tid, f"s{i}", 100.0 + i, 0.1 * (i + 1))
+    assert export_wire(tid, tracer=t)["truncated"] is False
+    assert t.wire_truncated == 0
+    assert export_wire(tid, max_spans=4, tracer=t)["truncated"] is True
+    assert t.wire_truncated == 6
