@@ -112,7 +112,9 @@ class FriProver:
         log_n = codeword.shape[0].bit_length() - 1
         shift = p.shift % bb.P
         inv2 = jnp.asarray(np.uint32(int(bb.to_mont_host(_INV2))))
-        self.layers = []   # (canonical_np_codeword, canonical_np_levels)
+        # (np_codeword, np_levels) per layer, in Montgomery form as they
+        # left the device: `open_queries` converts only what it opens
+        self.layers = []
         self.roots = []
         codeword = self._shard(codeword)
         while log_n > p.log_final_size:
@@ -127,11 +129,10 @@ class FriProver:
                     sp, d2h_s=time.perf_counter() - t_get,
                     d2h_bytes=cw_np.nbytes + sum(l.nbytes
                                                  for l in levels_np))
-                levels_c = [bb.from_mont_host(l) for l in levels_np]
-                root = levels_c[-1][0]
-                challenger.absorb_elems(int(x) for x in root)
-                self.layers.append((bb.from_mont_host(cw_np), levels_c))
-                self.roots.append([int(x) for x in root])
+                root = bb.from_mont_host(levels_np[-1][0]).tolist()
+                challenger.absorb_elems(root)
+                self.layers.append((cw_np, levels_np))
+                self.roots.append(root)
                 beta = ext.to_device(challenger.sample_ext())
                 inv_pts = jnp.asarray(_fold_inv_points(log_n, shift))
                 # the fold is dispatched, not waited for: the next
@@ -157,18 +158,26 @@ class FriProver:
         return self.roots, self.final_coeffs
 
     def open_queries(self, indices) -> list:
-        out = []
-        for q in indices:
-            per_layer = []
-            idx = q
-            for canon, levels_c in self.layers:
-                half = canon.shape[0] // 2
-                idx %= half
-                lo = tuple(int(v) for v in canon[idx])
-                hi = tuple(int(v) for v in canon[idx + half])
-                path = merkle.open_path_canonical(levels_c, idx)
-                per_layer.append({"values": [lo, hi], "path": path})
-            out.append(per_layer)
+        """Per query, per layer: the pair of codeword values and the
+        Merkle path of their leaf.  Each layer's 2 x len(indices) values
+        and their siblings are gathered first and only those converted
+        out of Montgomery form (`canon_bytes`)."""
+        with tracing.span("fri.open_queries") as sp:
+            out = [[] for _ in indices]
+            idx = np.asarray(indices, dtype=np.int64)
+            canon_bytes = 0
+            for cw_np, levels_np in self.layers:
+                half = cw_np.shape[0] // 2
+                idx = idx % half
+                pairs = bb.from_mont_host(
+                    cw_np[np.stack([idx, idx + half], axis=1)])
+                paths = merkle.open_paths_mont(levels_np, idx)
+                canon_bytes += pairs.nbytes + paths.nbytes
+                for per_layer, pair, path in zip(out, pairs.tolist(),
+                                                 paths.tolist()):
+                    per_layer.append({"values": [tuple(v) for v in pair],
+                                      "path": path})
+            tracing.set_attrs(sp, canon_bytes=canon_bytes)
         return out
 
     def prove(self, codeword, challenger: Challenger):
@@ -183,8 +192,7 @@ class FriProver:
         n0 = self.layers[0][0].shape[0]
         bits = (n0 // 2).bit_length() - 1
         indices = challenger.sample_indices(bits, self.params.num_queries)
-        with tracing.span("fri.open_queries"):
-            queries = self.open_queries(indices)
+        queries = self.open_queries(indices)
         return (FriProof(self.roots, self.final_coeffs, queries, nonce),
                 indices)
 

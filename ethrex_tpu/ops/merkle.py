@@ -131,14 +131,18 @@ def open_path(levels, index: int):
     return path
 
 
-def open_path_canonical(levels_c, index: int) -> list[list[int]]:
-    """Sibling walk over canonical numpy level arrays -> wire-format path."""
-    path = []
-    idx = index
-    for level in levels_c[:-1]:
-        path.append([int(x) for x in level[idx ^ 1]])
-        idx >>= 1
-    return path
+def open_paths_mont(levels, idxs) -> np.ndarray:
+    """Sibling digests bottom-up for every leaf in `idxs`, out of host
+    levels still in Montgomery form -> canonical (len(idxs), depth, 8).
+
+    Select, then convert: the Montgomery map is elementwise, so only the
+    gathered siblings go through `from_mont_host`; `.tolist()` of a row
+    is the wire-format path."""
+    idx = np.asarray(idxs, dtype=np.int64)
+    sibs = np.empty((idx.size, len(levels) - 1, DIGEST_WIDTH), np.uint32)
+    for k, level in enumerate(levels[:-1]):
+        sibs[:, k] = np.asarray(level)[(idx >> k) ^ 1]
+    return bb.from_mont_host(sibs)
 
 
 def verify_path(root_digest, index: int, leaf_digest, path,

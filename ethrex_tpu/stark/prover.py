@@ -1011,25 +1011,27 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
                 rows_np, q_rows_np, lt_np, lq_np = jax.device_get(
                     (lde_rows, q_rows, tuple(levels_t), tuple(levels_q)))
                 d2h_bytes = _nbytes((rows_np, q_rows_np, lt_np, lq_np))
-            lde_rows_c = bb.from_mont_host(rows_np)
-            q_rows_c = bb.from_mont_host(q_rows_np)
-            levels_t_c = [bb.from_mont_host(l) for l in lt_np]
-            levels_q_c = [bb.from_mont_host(l) for l in lq_np]
-            tracing.set_attrs(canon, d2h_bytes=d2h_bytes)
-        half = N // 2
+            # select, then convert: the rows and siblings the queries
+            # open are gathered out of the Montgomery-form arrays, and
+            # only those go through `from_mont_host`
+            half = N // 2
+            idxs = np.array([i for q in indices for i in (q, q + half)],
+                            dtype=np.int64)
+            opened = {
+                name: (bb.from_mont_host(rows[idxs]),
+                       merkle.open_paths_mont(levels, idxs))
+                for name, rows, levels in (("trace", rows_np, lt_np),
+                                           ("quotient", q_rows_np, lq_np))}
+            tracing.set_attrs(canon, d2h_bytes=d2h_bytes,
+                              canon_bytes=_nbytes(opened))
         openings = []
         with tracing.span("query.paths"):
-            for q in indices:
+            for j in range(len(indices)):
                 entry = {}
-                for name, rows_c, levels_c in (
-                    ("trace", lde_rows_c, levels_t_c),
-                    ("quotient", q_rows_c, levels_q_c),
-                ):
-                    for tag, idx in (("lo", q), ("hi", q + half)):
-                        entry[f"{name}_{tag}"] = [int(v)
-                                                  for v in rows_c[idx]]
-                        entry[f"{name}_{tag}_path"] = \
-                            merkle.open_path_canonical(levels_c, idx)
+                for name, (rows_c, paths_c) in opened.items():
+                    for tag, k in (("lo", 2 * j), ("hi", 2 * j + 1)):
+                        entry[f"{name}_{tag}"] = rows_c[k].tolist()
+                        entry[f"{name}_{tag}_path"] = paths_c[k].tolist()
                 openings.append(entry)
 
     # live throughput gauge: trace cells proven per end-to-end second

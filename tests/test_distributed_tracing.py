@@ -679,8 +679,8 @@ LEAF_SPANS = {
     "fri.layer": ("log_n", "d2h_bytes", "d2h_s"),
     "fri.final": ("d2h_bytes",),
     "fri.grind": ("tries",),
-    "fri.open_queries": (),
-    "query.canon": ("d2h_bytes",),
+    "fri.open_queries": ("canon_bytes",),
+    "query.canon": ("d2h_bytes", "canon_bytes"),
     "query.paths": (),
 }
 
@@ -839,6 +839,55 @@ def test_leaf_spans_count_what_they_say(leaf_batch):
         [(1 << k) * 16 + ((1 << k) - 1) * 32 for k in (8, 7, 6, 5)]
     # the query phase reads the host mirrors: no copy of its own
     assert all(s["attrs"]["d2h_bytes"] == 0 for s in by_name["query.canon"])
+
+
+def _fib_opening_bytes():
+    """By the arrays' shapes (Fibonacci: w=2, B=4, 16 queries, FRI from
+    2^8 down to a final 2^4): what the query phase holds on the host,
+    and what it converts out of Montgomery form to open it."""
+    w, B, n, queries = 2, 1 << SMALL.log_blowup, FIB_N, SMALL.num_queries
+    N = n * B
+    depth = N.bit_length() - 1
+    tree = (2 * N - 1) * 8 * 4
+    stark_mirrors = N * w * 4 + N * B * 4 * 4 + 2 * tree
+    stark_opened = 2 * queries * ((w + B * 4) * 4 + 2 * depth * 8 * 4)
+    fri_logs = range(depth, SMALL.log_final_size, -1)
+    fri_mirrors = sum((1 << k) * 16 + ((1 << k) - 1) * 32 for k in fri_logs)
+    fri_opened = queries * sum(2 * 16 + (k - 1) * 32 for k in fri_logs)
+    return stark_mirrors, stark_opened, fri_mirrors, fri_opened
+
+
+def test_query_spans_convert_only_what_they_open(leaf_batch):
+    """`canon_bytes` is what went through `from_mont_host`: the opened
+    rows and path nodes, never the mirrors.  (At this size 32 of a
+    tree's 256 leaves are opened, so the share is a third; at
+    BASELINE-1's it is under a hundredth: tests/test_stark.py.)"""
+    stark_mirrors, stark_opened, fri_mirrors, fri_opened = \
+        _fib_opening_bytes()
+    assert (stark_opened, fri_opened) == (18688, 13312)
+    canons = [s for s in leaf_batch["spans"] if s["name"] == "query.canon"]
+    opens = [s for s in leaf_batch["spans"]
+             if s["name"] == "fri.open_queries"]
+    assert len(canons) == len(opens) == 3
+    for s in canons:
+        assert s["attrs"]["canon_bytes"] == stark_opened < stark_mirrors
+    for s in opens:
+        assert s["attrs"]["canon_bytes"] == fri_opened < fri_mirrors
+
+
+def test_query_canon_without_checkpoints_copies_whole_and_converts_little():
+    """Checkpoints off: the branch still copies the whole arrays off
+    the device (`d2h_bytes`), and converts what it opens all the same."""
+    stark_mirrors, stark_opened, _, fri_opened = _fib_opening_bytes()
+    ckpt.set_checkpoint_dir(None)
+    with tracing.trace_context(None) as tid:
+        stark_prover.prove(*_fib_material(), SMALL)
+    by_name = {s["name"]: s for s in TRACER.get_trace(tid)["spans"]}
+    assert by_name["query.canon"]["attrs"]["d2h_bytes"] == stark_mirrors
+    assert by_name["query.canon"]["attrs"]["canon_bytes"] == stark_opened
+    assert by_name["fri.open_queries"]["attrs"]["canon_bytes"] == fri_opened
+    assert by_name["query.paths"]["parentId"] == \
+        by_name["query.canon"]["parentId"] == by_name["prove.query"]["spanId"]
 
 
 def test_idle_joins_the_batch_that_ended_it(leaf_batch):

@@ -182,3 +182,60 @@ def test_inv_x_minus_zeta_matches_batch_inv():
          jnp.broadcast_to(bb.neg(zeta[1:]), xm.shape + (3,))], axis=-1)
     expect = ext.batch_inv(x_ext)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(expect))
+
+
+def _whole_array_open_queries(layers, indices):
+    """`FriProver.open_queries` as it was: every layer's codeword and
+    tree converted whole out of Montgomery form, then indexed."""
+    out = []
+    for q in indices:
+        per_layer, idx = [], q
+        for cw_np, levels_np in layers:
+            canon = bb.from_mont_host(cw_np)
+            levels_c = [bb.from_mont_host(level) for level in levels_np]
+            half = canon.shape[0] // 2
+            idx %= half
+            path, i = [], idx
+            for level in levels_c[:-1]:
+                path.append([int(x) for x in level[i ^ 1]])
+                i >>= 1
+            per_layer.append({
+                "values": [tuple(int(v) for v in canon[idx]),
+                           tuple(int(v) for v in canon[idx + half])],
+                "path": path})
+        out.append(per_layer)
+    return out
+
+
+@pytest.mark.parametrize("indices", [
+    [0, 31, 63], [5, 5, 63, 0, 5], list(range(64)),
+], ids=["edges", "repeats", "every-leaf"])
+def test_fri_open_queries_match_whole_array_form(indices):
+    from ethrex_tpu.utils import tracing
+
+    params = fri.FriParams(log_blowup=2, num_queries=len(indices),
+                           log_final_size=4)
+    cw = _codeword_from_degree(5, 2, np.random.default_rng(11))  # N = 128
+    prover = fri.FriProver(params)
+    roots, _ = prover.commit_phase(cw, Challenger())
+    assert len(prover.layers) == 3
+    # the layers stay as they left the device: the root alone is canonical
+    for (cw_np, levels_np), root in zip(prover.layers, roots):
+        assert cw_np.dtype == np.uint32 and levels_np[-1].shape == (1, 8)
+        assert bb.from_mont_host(levels_np[-1][0]).tolist() == root
+        assert all(type(x) is int for x in root)
+    with tracing.trace_context(None) as tid:
+        got = prover.open_queries(indices)
+    assert got == _whole_array_open_queries(prover.layers, indices)
+    assert all(type(v) is int for q in got for o in q
+               for v in o["values"][0] + o["values"][1])
+    # canon_bytes: 2 values of 16 B and depth x 32 B of path per query
+    # and layer (leaves 64, 32, 16 -> depths 6, 5, 4)
+    (span,) = [s for s in tracing.TRACER.get_trace(tid)["spans"]
+               if s["name"] == "fri.open_queries"]
+    assert span["attrs"]["canon_bytes"] == \
+        len(indices) * (3 * 2 * 16 + (6 + 5 + 4) * 32)
+    mirrors = sum(c.nbytes + sum(l.nbytes for l in lv)
+                  for c, lv in prover.layers)
+    assert mirrors == sum((1 << k) * 16 + ((1 << k) - 1) * 32
+                          for k in (7, 6, 5))
