@@ -135,24 +135,33 @@ def coset_evals_from_coeffs(coeffs, n_out: int, shift: int = bb.GENERATOR):
 
 def interpolate_host(values: np.ndarray) -> np.ndarray:
     """Canonical host interpolation: evaluations over the size-p subgroup
-    (natural order) -> coefficient vector.  O(p^2) inverse DFT as one
-    numpy table product — used for small periodic/preprocessed columns
-    only."""
+    (natural order) -> coefficient vector.  A radix-2 inverse DFT in
+    numpy uint64, O(p log p) time and O(p) memory: an AIR's `sel_first`
+    column has the trace's own length, and the (p, p) table this once
+    built took 8 p^2 bytes several times over (2 GiB a table at 2^14
+    rows, 32 GiB at 2^16)."""
     p_len = len(values)
     log_p = p_len.bit_length() - 1
     if 1 << log_p != p_len:
         raise ValueError("periodic length must be a power of two")
     w_inv = bb.inv_host(bb.root_of_unity(log_p))
     n_inv = bb.inv_host(p_len)
-    # out[k] = n^-1 * sum_i vals[i] * w^(-ik): one (p, p) table of
-    # w^(-ik mod p) in uint64 numpy — every product is reduced below
-    # 2^31 before the row sum, so p of them cannot overflow
-    idx = np.arange(p_len, dtype=np.int64)
-    table = bb.powers_host(w_inv, p_len).astype(np.uint64)[
-        np.outer(idx, idx) % p_len]
-    vals = np.asarray(values, dtype=np.uint64) % bb.P
-    acc = (table * vals[None, :] % bb.P).sum(axis=1) % bb.P
-    return (acc * n_inv % bb.P).astype(np.uint32)
+    # out[k] = n^-1 * sum_i vals[i] * w^(-ik): decimation in time over
+    # the bit-reversed input; every product is of two values below 2^31
+    idx = np.arange(p_len)
+    rev = np.zeros(p_len, dtype=np.int64)
+    for bit in range(log_p):
+        rev |= ((idx >> bit) & 1) << (log_p - 1 - bit)
+    acc = (np.asarray(values, dtype=np.uint64) % bb.P)[rev]
+    for stage in range(1, log_p + 1):
+        half = 1 << (stage - 1)
+        twiddles = bb.powers_host(
+            pow(w_inv, p_len >> stage, bb.P), half).astype(np.uint64)
+        acc = acc.reshape(-1, 2 * half)
+        lo, hi = acc[:, :half], acc[:, half:] * twiddles % bb.P
+        acc = np.concatenate([(lo + hi) % bb.P, (lo + bb.P - hi) % bb.P],
+                             axis=1)
+    return (acc.reshape(-1) * n_inv % bb.P).astype(np.uint32)
 
 
 def domain_points(log_size: int, shift: int) -> np.ndarray:

@@ -747,20 +747,44 @@ class TpuBackend(ProverBackend):
                               tok_pub, bc_pubs)
         bind_air = pair.Poseidon2SpongeAir(num_chunks=len(limbs) // 8)
         bind_pub = pair.sponge_public_inputs(limbs)
-        tracing.record_span("prove.vm_batch", vmb_wall0,
-                            _time.time() - vmb_wall0)
+        vmb_seconds = _time.time() - vmb_wall0
+        row_kinds = [e[0] for b in blocks_log for e in b]
+        tracing.record_span(
+            "prove.vm_batch", vmb_wall0, vmb_seconds,
+            mode="claimed" if vm_batch is None else _mode_of(vm_batch),
+            txs=sum(len(b.body.transactions)
+                    for b in program_input.blocks),
+            tok_calls=0 if vm_batch is None else len(vm_batch.tok_segs),
+            acct_rows=row_kinds.count("acct"),
+            slot_rows=row_kinds.count("slot"))
         bind_trace = _traced_gen("Poseidon2SpongeAir",
                                  pair.generate_sponge_trace, limbs)
-        # look-ups when the programs are warm; cold, the builds run on
-        # background threads and a job pays for one it has to wait for
-        # under its `prove.phase_build`
+        # look-ups when the programs are warm; cold, the builds run in
+        # the background and a job pays for one it has to wait for under
+        # its `prove.phase_build`.  Every AIR of the batch is asked for,
+        # in the order its job runs (state, transfer, token, binding),
+        # and the builds queue in that order: a job then proves while
+        # the later AIRs still build (queued last, the state circuit's
+        # programs held every job up for the whole of a cold token
+        # batch's builds: 1087 s, then 52 s of proving; PR 29, chip call
+        # 4).  The FRI programs are warmed from the batch's largest
+        # codeword down (the state circuit's, where the batch has slot
+        # rows): a layer size left out compiles inside that STARK's own
+        # FRI loop
         with tracing.span("prove.compile_ahead"):
+            ahead = []
             if vm_batch is not None and self.mesh is None:
+                state_rows = sua.segment_count(len(records)) * S \
+                    * pair.PERIOD
                 vm_rows = ta.segment_count(len(vm_batch.segs)) * ta.SEG_LEN
-                stark_prover.compile_ahead(vm_air, vm_rows, PARAMS)
-                stark_prover.warm_fri_programs(vm_rows, PARAMS)
-            stark_prover.compile_ahead(bind_air, bind_trace.shape[0],
-                                       PARAMS, self.mesh)
+                ahead += [(air, state_rows), (vm_air, vm_rows)]
+                if tok_air is not None:     # one segment a call: < vm_rows
+                    ahead.append((tok_air, tka.segment_count(
+                        len(vm_batch.tok_segs)) * tka.SEG_LEN))
+                stark_prover.warm_fri_programs(max(vm_rows, state_rows),
+                                               PARAMS)
+            ahead.append((bind_air, bind_trace.shape[0]))
+            stark_prover.compile_ahead(ahead, PARAMS, self.mesh)
 
         results = _run_proof_jobs(jobs, self.mesh)
         state_proof = results["state_proof"]

@@ -144,65 +144,95 @@ def _phases(air: Air, log_n: int, lb: int, shift: int,
     therefore times trace + staging + backend compile for a cache miss,
     labelled with the mesh shape.
     """
+    return _queue_phases(air, log_n, lb, shift, mesh)()
+
+
+def _queue_phases(air: Air, log_n: int, lb: int, shift: int, mesh=None):
+    """`_phases` up to the point where a miss has its four compiles on
+    the pool; returns the rest of it, a call that waits for them and
+    caches the set.  `compile_ahead` runs this for every AIR of a batch
+    before it waits for any, so the pool has the builds in the order
+    the AIRs were asked for."""
     key = (air.cache_key(), log_n, lb, shift, _mesh_key(mesh))
+    air_name = type(air).__name__
     # one build per key: a second caller (the prove that compile_ahead
     # ran in front of) waits for the build in flight, and gets its
     # error if it fails
     with _PHASE_LOCK:
         cached = _PHASE_CACHE.get(key)
         if cached is not None:
-            return cached
+            return lambda: cached
         pending = _PHASE_BUILDS.get(key)
         if pending is None:
             mine = _PHASE_BUILDS[key] = Future()
     # a miss is spanned (a hit is a dictionary look-up): the build
     # itself, or the wait for the one compile_ahead has in flight
     if pending is not None:
-        with tracing.span("prove.phase_build", air=type(air).__name__):
-            return pending.result()
-    try:
-        with tracing.span("prove.phase_build",
-                          air=type(air).__name__) as build:
-            bodies, plan = _build_phases(air, log_n, lb, shift, mesh)
-            built = PhasePrograms(
-                _aot_phases(air, log_n, lb, shift, bodies, plan, mesh),
-                plan)
-        # retrace telemetry: every miss here is a fresh set of programs
-        from ..parallel import mesh as mesh_lib
+        def wait():
+            with tracing.span("prove.phase_build", air=air_name):
+                return pending.result()
+        return wait
 
-        if build is not None:
-            record_kernel_build(type(air).__name__, build.seconds,
-                                mesh=mesh_lib.shape_label(mesh))
-    except BaseException as exc:
+    def failed(exc):
         with _PHASE_LOCK:
             del _PHASE_BUILDS[key]
         mine.set_exception(exc)
+
+    start, t0 = time.time(), time.perf_counter()
+    try:
+        bodies, plan = _build_phases(air, log_n, lb, shift, mesh)
+        compiles = _aot_phases(air, log_n, lb, shift, bodies, plan, mesh)
+    except BaseException as exc:
+        failed(exc)
         raise
-    with _PHASE_LOCK:
-        _PHASE_CACHE[key] = built
-        del _PHASE_BUILDS[key]
-    mine.set_result(built)
-    return built
 
-
-def compile_ahead(air: Air, n: int, params: "StarkParams",
-                  mesh=None) -> None:
-    """Start building `air`'s phase programs for an `n`-row trace in the
-    background and return at once.  A cold prover spends most of its
-    first proof compiling, one AIR after the other; XLA compiles outside
-    the GIL, so the AIRs a batch will need next can build while the
-    first one proves.  The builds queue on the shared pool in the order
-    asked for.  The later prove() finds the programs in the cache, or
-    waits for the build in flight — a failed build fails that prove
-    with the compiler's own error."""
-    args = (air, n.bit_length() - 1, params.log_blowup,
-            params.shift % bb.P, mesh)
-
-    def run():
+    def finish():
         try:
-            _phases(*args)
-        except BaseException:   # noqa: BLE001 — the waiting prove has it
-            pass
+            built = PhasePrograms(compiles(), plan)
+        except BaseException as exc:
+            failed(exc)
+            raise
+        # retrace telemetry: every miss here is a fresh set of programs
+        from ..parallel import mesh as mesh_lib
+
+        seconds = time.perf_counter() - t0
+        tracing.record_span("prove.phase_build", start, seconds,
+                            air=air_name)
+        record_kernel_build(air_name, seconds,
+                            mesh=mesh_lib.shape_label(mesh))
+        with _PHASE_LOCK:
+            _PHASE_CACHE[key] = built
+            del _PHASE_BUILDS[key]
+        mine.set_result(built)
+        return built
+    return finish
+
+
+def compile_ahead(asks, params: "StarkParams", mesh=None) -> None:
+    """Start building the phase programs of every `(air, n)` of `asks`
+    (`n` the rows of the trace) in the background and return at once.
+    A cold prover spends most of its first proof compiling, one AIR
+    after the other; XLA compiles outside the GIL, so the AIRs a batch
+    will need next can build while the first one proves.  One thread
+    traces each AIR's programs and puts their compiles on the shared
+    pool in the order of `asks`, then waits for them: ask first for the
+    AIR whose job runs first.  The later prove() finds the programs in
+    the cache, or waits for the build in flight — a failed build fails
+    that prove with the compiler's own error."""
+    def run():
+        waits = []
+        for air, n in asks:
+            try:
+                waits.append(_queue_phases(
+                    air, n.bit_length() - 1, params.log_blowup,
+                    params.shift % bb.P, mesh))
+            except BaseException:   # noqa: BLE001 — the waiting prove
+                pass                # builds again and has the error
+        for wait in waits:
+            try:
+                wait()
+            except BaseException:   # noqa: BLE001 — the waiting prove has it
+                pass
 
     threading.Thread(target=run, name="compile-ahead", daemon=True).start()
 
@@ -372,6 +402,8 @@ def _aot_phases(air: Air, log_n: int, lb: int, shift: int, bodies, plan,
     known) argument shapes and register each executable's XLA cost
     analysis with the roofline registry — mesh and single-device paths
     alike, so sharded programs get the same roofline cost records.
+    Puts the four compiles on the pool and returns a call that waits
+    for them and gives the executables in `_KERNELS` order.
 
     Each kernel asks the on-disk executable cache first
     (utils/exec_cache): a hit hydrates the serialized executable in
@@ -418,7 +450,7 @@ def _aot_phases(air: Air, log_n: int, lb: int, shift: int, bodies, plan,
     fns = dict(zip(_KERNELS, _jit_programs(bodies, plan)))
     futures = {kernel: _COMPILE_POOL.submit(build, kernel, fns[kernel])
                for kernel in _BUILD_ORDER}
-    return tuple(futures[kernel].result() for kernel in _KERNELS)
+    return lambda: tuple(futures[kernel].result() for kernel in _KERNELS)
 
 
 def hydrate_phase_cache(mesh=None) -> int:

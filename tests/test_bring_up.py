@@ -169,7 +169,7 @@ def test_aot_phases_propagates_a_compile_error(kernel):
     broken = tuple(refused if k == kernel else b
                    for k, b in zip(stark_prover._KERNELS, bodies))
     with pytest.raises(NotImplementedError, match=kernel):
-        stark_prover._aot_phases(air, 4, 2, SHIFT, broken, plan, None)
+        stark_prover._aot_phases(air, 4, 2, SHIFT, broken, plan, None)()
     assert not hasattr(stark_prover, "_shard_map_program")
 
 
@@ -193,7 +193,7 @@ def test_phase_programs_build_once_and_compile_ahead_hands_over(
     monkeypatch.setattr(stark_prover, "_build_phases", counted)
     stark_prover.clear_phase_cache()
     params = stark_prover.StarkParams(log_blowup=2)
-    stark_prover.compile_ahead(air, 32, params)
+    stark_prover.compile_ahead([(air, 32)], params)
     for _ in range(200):                    # until the build is in flight
         if builds:
             break
@@ -203,9 +203,51 @@ def test_phase_programs_build_once_and_compile_ahead_hands_over(
         stark_prover._phases(air, 5, 2, SHIFT)))
     waiter.start()
     gate.set()
-    waiter.join(120)
+    waiter.join(600)       # bounds a hang; the build is a minute alone
     assert builds == [5]                    # one build, not two
     assert got and got[0] is stark_prover._phases(air, 5, 2, SHIFT)
+    assert not stark_prover._PHASE_BUILDS
+
+
+def test_compile_ahead_queues_builds_in_the_order_asked(monkeypatch):
+    """The AIR asked for first has its builds first in the pool's queue,
+    however long its `_build_phases` takes beside the others': the job
+    that runs first must not wait for every other AIR's programs (a cold
+    token batch's state circuit did; PR 29).  A build that fails leaves
+    the ones asked for after it their turn."""
+    import threading
+    import time
+
+    queued, done = [], threading.Event()
+
+    def slow_for_the_first(air, log_n, *args, **kw):
+        if log_n == 5:
+            time.sleep(0.5)             # the wider AIR's host work
+        return (log_n,) * 4, None       # four "bodies" that say whose
+
+    class Pool:
+        def submit(self, build, kernel, fn):
+            queued.append(fn)
+            if fn == 6:
+                done.set()
+            raise NotImplementedError("nothing compiles here")
+
+    monkeypatch.setattr(stark_prover, "_build_phases", slow_for_the_first)
+    monkeypatch.setattr(stark_prover, "_COMPILE_POOL", Pool())
+    monkeypatch.setattr(stark_prover, "_jit_programs",
+                        lambda bodies, plan: bodies)
+    stark_prover.clear_phase_cache()
+    params = stark_prover.StarkParams(log_blowup=2)
+    air = fib.FibonacciAir()
+    stark_prover.compile_ahead([(air, 32), (air, 64)], params)
+    assert done.wait(30)
+    # the slow AIR's submit came first; its failure cost the second
+    # nothing, and no build is left in flight
+    assert queued == [5, 6]
+    for _ in range(200):
+        if not stark_prover._PHASE_BUILDS:
+            break
+        time.sleep(0.01)
     assert not stark_prover._PHASE_BUILDS
 
 
@@ -221,7 +263,7 @@ def test_a_failed_compile_ahead_fails_the_prove_that_waited(monkeypatch):
 
     monkeypatch.setattr(stark_prover, "_build_phases", refused)
     stark_prover.clear_phase_cache()
-    stark_prover.compile_ahead(air, 64, stark_prover.StarkParams(
+    stark_prover.compile_ahead([(air, 64)], stark_prover.StarkParams(
         log_blowup=2))
     for _ in range(200):
         if stark_prover._PHASE_BUILDS:
@@ -242,6 +284,55 @@ def test_a_failed_compile_ahead_fails_the_prove_that_waited(monkeypatch):
     assert errors == ["refused by the compiler"]
     assert not stark_prover._PHASE_BUILDS and not any(
         k[1] == 6 for k in stark_prover._PHASE_CACHE)
+
+
+def test_prove_asks_ahead_for_every_air_of_the_batch(monkeypatch):
+    """A token batch lays four jobs (state -> transfer -> token ->
+    binding).  `_prove_impl` asks ahead for the programs of all four
+    AIRs, in the order their jobs run, before the first job runs, and
+    warms the FRI
+    programs from the batch's largest trace down: the token circuit's
+    programs must not start building only when the transfer circuit has
+    proved.  `prove.vm_batch` says what the batch was.  Spies only:
+    nothing compiles, nothing proves."""
+    from ethrex_tpu.prover import tpu_backend
+    from tests.test_erc20_cell import SEEDS, drive
+
+    asked, warmed, recorded = [], [], {}
+
+    class Stop(Exception):
+        pass
+
+    def jobs_reached(jobs, mesh):
+        raise Stop([name for name, _, _ in jobs])
+
+    monkeypatch.setattr(
+        stark_prover, "compile_ahead",
+        lambda asks, params, mesh=None: asked.extend(
+            (type(air).__name__, n) for air, n in asks))
+    monkeypatch.setattr(stark_prover, "warm_fri_programs",
+                        lambda n, params: warmed.append(n))
+    monkeypatch.setattr(tpu_backend, "_run_proof_jobs", jobs_reached)
+    monkeypatch.setattr(
+        tpu_backend.tracing, "record_span",
+        lambda name, start, seconds, **attrs: recorded.update(
+            {name: attrs}))
+    with pytest.raises(Stop) as stopped:
+        # one block of 6 token calls from 2 senders
+        batch = drive("small", SEEDS[0])[1][0][0]
+        tpu_backend.TpuBackend()._prove_impl(batch, "stark")
+    assert stopped.value.args[0] == [
+        "state_proof", "vm_circuits/TransferAir", "vm_circuits/TokenAir"]
+    # 25 access records -> 32 segments x 16 periods x 32 rows; 6 calls:
+    # 13 of 16 transfer segments, 7 of 8 token segments, x 512
+    assert asked == [("StateUpdateAir", 16384), ("TransferAir", 8192),
+                     ("TokenAir", 4096), ("Poseidon2SpongeAir", 512)]
+    # the largest trace is the state circuit's
+    assert warmed == [16384]
+    # 6 senders' rows, 6 coinbase rows and the token's; 2 slots a call
+    assert recorded["prove.vm_batch"] == {
+        "mode": "token", "txs": 6, "tok_calls": 6, "acct_rows": 13,
+        "slot_rows": 12}
 
 
 # ---------------------------------------------------------------------------

@@ -4,8 +4,9 @@ libtpu compiles for a chip that is DESCRIBED (a v5e 2x2 topology), not
 attached: what the compiler refuses here it would refuse on the machine
 with the chip, and here it costs no chip time.  Nothing runs, so these
 tests say nothing about results or speed — only that every program of a
-BASELINE-1 batch (10 transfers, `tpu_backend.PARAMS`) lowers, partitions
-and fits at its real width.
+BASELINE-1 batch (10 transfers, `tpu_backend.PARAMS`), and the token
+circuit's of a `prove-erc20` batch, lowers, partitions and fits at its
+real width.
 
 This is the ONE file of TPU-compiler tests: only one process may hold
 libtpu, a pytest-xdist worker that described the topology keeps it until
@@ -17,8 +18,9 @@ Each case prints its compile seconds and memory_analysis() (`pytest -s`):
 that is the compile bill a cold prover pays on the chip's host.  Tier-1
 keeps the cases that compile in about a minute or less; the slow-marked
 ones (TransferAir's four phases on one chip and on a two-chip slice,
-StateUpdateAir's commit, quotient and open, the width-278 Merkle tree, the
-Groth16 MSM at 13 minutes) are run by hand before a chip call:
+StateUpdateAir's commit, quotient and open, TokenAir's quotient and open,
+the width-278 Merkle tree, the Groth16 MSM at 13 minutes) are run by hand
+before a chip call:
 
     JAX_PLATFORMS=cpu python -m pytest tests/test_chip_compile.py -s -m slow
 """
@@ -35,6 +37,7 @@ from jax.sharding import SingleDeviceSharding
 
 from ethrex_tpu.models import poseidon2_air as pair
 from ethrex_tpu.models import state_update_air as sua
+from ethrex_tpu.models import token_air as tka
 from ethrex_tpu.models import transfer_air as ta
 from ethrex_tpu.ops import bn254_msm, fri, merkle, ntt, poseidon2
 from ethrex_tpu.parallel import mesh as mesh_lib
@@ -56,6 +59,9 @@ AIRS = {
     "Poseidon2SpongeAir": (lambda: pair.Poseidon2SpongeAir(num_chunks=13),
                            9),
     "TransferAir": (ta.TransferAir, 14),
+    # the fourth AIR of a `prove-erc20` batch (15 token calls: 16
+    # segments x 512 rows)
+    "TokenAir": (tka.TokenAir, 13),
 }
 
 
@@ -161,8 +167,12 @@ def _phase_cases():
     # slow: over about a minute here (seconds measured in this sandbox,
     # PR 25) — TransferAir 83/280/131/47, StateUpdateAir commit 66 (149
     # beside five other test workers), quotient 191 and open 103
+    # TokenAir (PR 29, beside another compile job, at 2^14 rows; 2^13
+    # is no quicker: quotient 175): commit 81, quotient 258, open 144,
+    # deep 40
     slow = {("StateUpdateAir", "commit"), ("StateUpdateAir", "quotient"),
-            ("StateUpdateAir", "open")}
+            ("StateUpdateAir", "open"), ("TokenAir", "quotient"),
+            ("TokenAir", "open")}
     for air_name in AIRS:
         for kernel in stark_prover._KERNELS:
             marks = [pytest.mark.slow] if (
