@@ -21,7 +21,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 import time
+from concurrent.futures import Future
 
 import numpy as np
 import jax
@@ -31,7 +33,9 @@ from . import babybear as bb
 from . import ext
 from . import merkle
 from . import ntt as _ntt
+from ..utils import exec_cache
 from ..utils import tracing
+from ..utils.metrics import record_phase_compile
 from .challenger import Challenger
 
 _INV2 = int(bb.inv_host(2))
@@ -63,6 +67,96 @@ def _pair_leaves(codeword):
     return jnp.concatenate([codeword[:half], codeword[half:]], axis=-1)
 
 
+LAYER_KERNELS = ("leaves", "levels", "fold")
+# The commit loop's three programs at each layer size, compiled ahead
+# of time for one device: log_k -> (leaves, levels, fold).  Filled from
+# the executable store by `stark.prover.hydrate_phase_cache`, and on a
+# miss by `layer_programs`.
+_LAYER_PROGRAMS: dict = {}
+_LAYER_LOCK = threading.Lock()
+_LAYER_BUILDS: dict = {}        # log_k -> Future of the build in flight
+# what the mesh path calls: the jits inherit the codeword's sharding
+_LAZY_PROGRAMS = (_pair_leaves, merkle._build_levels, _fold)
+
+
+def clear_layer_programs() -> None:
+    """Drop every layer program (tests / simulated restarts)."""
+    with _LAYER_LOCK:
+        _LAYER_PROGRAMS.clear()
+
+
+def layer_parts(log_k: int, kernel: str) -> dict:
+    """Executable-store identity of one layer program (`air_name` is
+    its label in the build telemetry, as a phase program's is)."""
+    return {"kind": "fri", "air_name": "FriLayer", "kernel": kernel,
+            "log_n": log_k, "mesh": None}
+
+
+def install_layer_programs(log_k: int, programs) -> None:
+    """`programs` in `LAYER_KERNELS` order (the hydration walk)."""
+    with _LAYER_LOCK:
+        _LAYER_PROGRAMS.setdefault(log_k, tuple(programs))
+
+
+def _build_layer_program(log_k: int, kernel: str):
+    """One layer program at codeword length 2^log_k, from the executable
+    store or else compiled against its (statically known) argument
+    shapes and stored; returns it with its source."""
+    size = 1 << log_k
+    S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.uint32)
+    fn, specs = {
+        "leaves": (_pair_leaves, (S((size, 4)),)),
+        "levels": (merkle._build_levels, (S((size // 2, 8)),)),
+        "fold": (_fold, (S((size, 4)), S((4,)), S((size // 2,)), S(()))),
+    }[kernel]
+    parts = layer_parts(log_k, kernel)
+    t_c = time.perf_counter()
+    compiled = exec_cache.load(parts)
+    source = "deserialized"
+    if compiled is None:
+        source = "compiled"
+        compiled = fn.lower(*specs).compile()
+        exec_cache.store(parts, compiled)
+    record_phase_compile(parts["air_name"], kernel,
+                         time.perf_counter() - t_c, source=source)
+    return compiled, source
+
+
+def layer_programs(log_k: int):
+    """(leaves, levels, fold) executables for a 2^log_k codeword on one
+    device.  A miss builds the three under a `prove.fri_build` span, as
+    `_aot_phases` builds a phase program; one build per size, a second
+    caller waits for the one in flight."""
+    with _LAYER_LOCK:
+        programs = _LAYER_PROGRAMS.get(log_k)
+        if programs is not None:
+            return programs
+        pending = _LAYER_BUILDS.get(log_k)
+        if pending is None:
+            mine = _LAYER_BUILDS[log_k] = Future()
+    if pending is not None:
+        with tracing.span("prove.fri_build", log_n=log_k, source="waited"):
+            return pending.result()
+    try:
+        with tracing.span("prove.fri_build", log_n=log_k) as sp:
+            built = [_build_layer_program(log_k, kernel)
+                     for kernel in LAYER_KERNELS]
+            sources = {source for _, source in built}
+            tracing.set_attrs(sp, source="compiled" if "compiled" in sources
+                              else "deserialized")
+        programs = tuple(compiled for compiled, _ in built)
+    except BaseException as exc:
+        with _LAYER_LOCK:
+            del _LAYER_BUILDS[log_k]
+        mine.set_exception(exc)
+        raise
+    with _LAYER_LOCK:
+        programs = _LAYER_PROGRAMS.setdefault(log_k, programs)
+        del _LAYER_BUILDS[log_k]
+    mine.set_result(programs)
+    return programs
+
+
 @dataclasses.dataclass
 class FriParams:
     log_blowup: int = 2
@@ -85,7 +179,10 @@ class FriProver:
 
     `mesh` (optional) shards each layer's codeword across the mesh's
     row axis; the fold/hash jits inherit the input sharding, so XLA runs
-    the layer work distributed (production multi-chip path)."""
+    the layer work distributed (production multi-chip path).  Without
+    one the same three programs come from `layer_programs`: compiled
+    ahead of time and restored from the executable store by a warm
+    process, which then traces and lowers nothing here."""
 
     def __init__(self, params: FriParams, mesh=None):
         self.params = params
@@ -118,9 +215,11 @@ class FriProver:
         self.roots = []
         codeword = self._shard(codeword)
         while log_n > p.log_final_size:
+            pair_leaves, build_levels, fold = (
+                layer_programs(log_n) if self.mesh is None
+                else _LAZY_PROGRAMS)
             with tracing.span("fri.layer", log_n=log_n) as sp:
-                leaves = _pair_leaves(codeword)
-                levels = merkle.commit_levels(leaves)
+                levels = build_levels(pair_leaves(codeword))
                 # one bulk device->host transfer per layer (codeword +
                 # levels)
                 t_get = time.perf_counter()
@@ -137,7 +236,7 @@ class FriProver:
                 inv_pts = jnp.asarray(_fold_inv_points(log_n, shift))
                 # the fold is dispatched, not waited for: the next
                 # layer's device_get (or fri.final's copy) pays for it
-                codeword = self._shard(_fold(codeword, beta, inv_pts, inv2))
+                codeword = self._shard(fold(codeword, beta, inv_pts, inv2))
                 shift = (shift * shift) % bb.P
                 log_n -= 1
         with tracing.span("fri.final") as sp:

@@ -554,13 +554,14 @@ class TpuBackend(ProverBackend):
         self.mesh = mesh
 
     def prewarm(self) -> int:
-        """Restore phase programs from the on-disk executable cache
-        (utils/exec_cache) so the first post-restart proof runs at
-        steady-state wall.  Hydration only — this never compiles; shapes
-        not yet on disk stay cold until first use, where the per-kernel
-        disk lookup still serves them in deserialize time.  Sub-mesh
-        entries (split_mesh slices) are not pre-installed here — they
-        hydrate from disk inside _aot_phases on first use."""
+        """Restore the phase programs and the FRI layer programs from
+        the on-disk executable cache (utils/exec_cache) so the first
+        post-restart proof runs at steady-state wall.  Hydration only —
+        this never compiles; shapes not yet on disk stay cold until
+        first use, where the per-kernel disk lookup still serves them
+        in deserialize time.  Sub-mesh entries (split_mesh slices) are
+        not pre-installed here — they hydrate from disk inside
+        _aot_phases on first use."""
         from ..stark.prover import hydrate_phase_cache
 
         count = hydrate_phase_cache(None)
@@ -767,9 +768,10 @@ class TpuBackend(ProverBackend):
         # the later AIRs still build (queued last, the state circuit's
         # programs held every job up for the whole of a cold token
         # batch's builds: 1087 s, then 52 s of proving; PR 29, chip call
-        # 4).  The FRI programs are warmed from the batch's largest
+        # 4).  The FRI layer programs the process lacks (none, once
+        # `prewarm` has restored them) build from the batch's largest
         # codeword down (the state circuit's, where the batch has slot
-        # rows): a layer size left out compiles inside that STARK's own
+        # rows): a layer size left out builds inside that STARK's own
         # FRI loop
         with tracing.span("prove.compile_ahead"):
             ahead = []

@@ -91,7 +91,8 @@ def _mesh_key(mesh):
 
 
 def clear_phase_cache() -> None:
-    """Drop every cached phase program (tests / simulated restarts)."""
+    """Drop every cached phase program (tests / simulated restarts;
+    `fri.clear_layer_programs` drops the FRI layer programs)."""
     _PHASE_CACHE.clear()
 
 
@@ -238,23 +239,26 @@ def compile_ahead(asks, params: "StarkParams", mesh=None) -> None:
 
 
 def warm_fri_programs(n: int, params: "StarkParams") -> None:
-    """Compile, in the background, the per-layer FRI programs of an
-    `n`-row trace's codeword (pair leaves, Merkle tree, fold — one jitted
-    program each per layer size) by running them once on zeros.  Left to
-    the first proof they compile one after the other inside its FRI
-    loop, thirteen layer sizes deep for a 2^17 codeword, with every
-    phase program built and waiting."""
+    """Build, in the background, the per-layer FRI programs of an
+    `n`-row trace's codeword (`fri.layer_programs`: pair leaves, Merkle
+    tree, fold at each layer size) that the process does not have yet.
+    Left to the first proof of a cold process they compile one after
+    the other inside its FRI loop, thirteen layer sizes deep for a 2^17
+    codeword, with every phase program built and waiting.  A process
+    that has them all (a warm one, after `hydrate_phase_cache`) starts
+    no thread."""
     log_size = (n << params.log_blowup).bit_length() - 1
-
-    def zeros(*shape):
-        return jnp.zeros(shape, jnp.uint32)
+    missing = [log_k for log_k in range(log_size, params.log_final_size, -1)
+               if log_k not in fri._LAYER_PROGRAMS]
+    if not missing:
+        return
 
     def run():
-        for log_k in range(log_size, params.log_final_size, -1):
-            size = 1 << log_k
-            codeword = zeros(size, 4)
-            merkle.commit_levels(fri._pair_leaves(codeword))
-            fri._fold(codeword, zeros(4), zeros(size // 2), zeros())
+        for log_k in missing:
+            try:
+                fri.layer_programs(log_k)
+            except Exception:   # noqa: BLE001 — the FRI loop that needs
+                pass            # this size builds again and has the error
 
     threading.Thread(target=run, name="fri-warm", daemon=True).start()
 
@@ -454,59 +458,83 @@ def _aot_phases(air: Air, log_n: int, lb: int, shift: int, bodies, plan,
 
 
 def hydrate_phase_cache(mesh=None) -> int:
-    """Pre-warm the in-process phase cache from the on-disk executable
-    cache: every complete four-kernel phase group recorded for this
-    environment and mesh layout is deserialized and installed into
-    _PHASE_CACHE, so the first prove of those shapes runs at
-    steady-state wall.  Never compiles — an empty or foreign cache is a
-    no-op — and never raises.  Returns the number of phase-program sets
-    hydrated (the ProverClient warm flag flips once this returns)."""
+    """Pre-warm the in-process program tables from the on-disk
+    executable cache: every complete four-kernel phase group recorded
+    for this environment and mesh layout is deserialized and installed
+    into _PHASE_CACHE, and (single-device call) every complete set of
+    FRI layer programs into `fri`'s table, so the first prove of those
+    shapes runs at steady-state wall and lowers nothing.  Never
+    compiles — an empty or foreign cache is a no-op — and never raises.
+    Returns the number of phase-program sets hydrated (the ProverClient
+    warm flag flips once this returns)."""
     from ..utils import exec_cache
 
     if not exec_cache.enabled():
         return 0
     try:
-        entries = exec_cache.scan("phase")
+        entries = exec_cache.scan()
     except Exception:
         return 0
     mesh_key = _mesh_key(mesh)
-    groups: dict = {}
+    phase_groups: dict = {}     # _PHASE_CACHE key -> kernel -> parts
+    layer_groups: dict = {}     # log_k -> kernel -> parts
     for parts in entries:
         try:
             if parts.get("mesh") != mesh_key:
                 continue
-            gkey = (parts["air"], parts["log_n"], parts["log_blowup"],
-                    parts["shift"], parts["mesh"])
-            groups.setdefault(gkey, {})[parts["kernel"]] = parts
+            if parts.get("kind") == "phase":
+                gkey = (parts["air"], parts["log_n"], parts["log_blowup"],
+                        parts["shift"], parts["mesh"])
+                phase_groups.setdefault(gkey, {})[parts["kernel"]] = parts
+            elif parts.get("kind") == "fri":
+                layer_groups.setdefault(
+                    parts["log_n"], {})[parts["kernel"]] = parts
         except Exception:
             continue
     from ..parallel import mesh as mesh_lib
 
     mesh_label = mesh_lib.shape_label(mesh)
+
+    def load_group(group):
+        """The group's executables in order, or None at the first one
+        that does not load.  One after the other: four at once on the
+        build pool took longer on the chip (PERF.md, PR 30)."""
+        programs = []
+        for parts in group:
+            t_c = time.perf_counter()
+            compiled = exec_cache.load(parts)
+            if compiled is None:
+                return None
+            record_phase_compile(parts["air_name"], parts["kernel"],
+                                 time.perf_counter() - t_c,
+                                 mesh=mesh_label, source="deserialized")
+            programs.append(compiled)
+        return tuple(programs)
+
     hydrated = 0
-    for gkey, kernels in groups.items():
+    for gkey, kernels in phase_groups.items():
         if gkey in _PHASE_CACHE or set(kernels) != set(_KERNELS):
             continue
         try:
-            p0 = kernels["commit"]
-            programs = []
-            ok = True
-            for kernel in _KERNELS:
-                t_c = time.perf_counter()
-                compiled = exec_cache.load(kernels[kernel])
-                if compiled is None:
-                    ok = False
-                    break
-                record_phase_compile(p0["air_name"], kernel,
-                                     time.perf_counter() - t_c,
-                                     mesh=mesh_label, source="deserialized")
-                programs.append(compiled)
-            if not ok:
+            programs = load_group([kernels[kernel] for kernel in _KERNELS])
+            if programs is None:
                 continue
+            p0 = kernels["commit"]
             plan = None if mesh is None else _MeshPlan(
                 mesh, p0["log_n"], p0["log_blowup"], p0["width"], p0["nb"])
-            _PHASE_CACHE[gkey] = PhasePrograms(tuple(programs), plan)
+            _PHASE_CACHE[gkey] = PhasePrograms(programs, plan)
             hydrated += 1
+        except Exception:
+            continue
+    for log_k, kernels in layer_groups.items():
+        if log_k in fri._LAYER_PROGRAMS \
+                or set(kernels) != set(fri.LAYER_KERNELS):
+            continue
+        try:
+            programs = load_group(
+                [kernels[kernel] for kernel in fri.LAYER_KERNELS])
+            if programs is not None:
+                fri.install_layer_programs(log_k, programs)
         except Exception:
             continue
     return hydrated

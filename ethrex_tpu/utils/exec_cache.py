@@ -17,7 +17,9 @@ kernel, mesh device layout) — joined with the environment parts
 (backend platform, device kind and count, jax/jaxlib versions).  A
 jaxlib upgrade, a backend switch or another host layout therefore
 changes every key: stale entries are structurally unreachable, not a
-correctness hazard.  Corruption, truncation, or an
+correctness hazard.  A file is two pickles in a row: a head (schema,
+key parts, environment), which is all the hydration walk reads of it,
+then the body with the executable.  Corruption, truncation, or an
 unpicklable payload is a clean miss (plus `executable_cache_errors_total`
 and a best-effort unlink); retention is bounded by pruning
 least-recently-used entries past a cap.
@@ -38,7 +40,7 @@ import pickle
 import tempfile
 import threading
 
-_SCHEMA = 1
+_SCHEMA = 2
 _SUFFIX = ".exe.pkl"
 _DEFAULT_MAX_ENTRIES = 512
 
@@ -198,21 +200,23 @@ def load(parts: dict):
         return None
     path = _entry_path(parts)
     try:
-        with open(path, "rb") as f:
-            blob = f.read()
+        f = open(path, "rb")
     except OSError:
         with _LOCK:
             STATS["misses"] += 1
         record_exec_cache_miss()
         return None
     try:
-        entry = pickle.loads(blob)
-        if entry.get("schema") != _SCHEMA or entry.get("env") != _env_parts():
-            raise ValueError("executable cache entry schema/env drift")
+        with f:
+            head = pickle.load(f)
+            if head.get("schema") != _SCHEMA \
+                    or head.get("env") != _env_parts():
+                raise ValueError("executable cache entry schema/env drift")
+            body = pickle.load(f)
         from jax.experimental import serialize_executable
 
         compiled = serialize_executable.deserialize_and_load(
-            entry["payload"], entry["in_tree"], entry["out_tree"],
+            body["payload"], body["in_tree"], body["out_tree"],
             execution_devices=_execution_devices(parts))
     except Exception:
         # corruption / truncation / version drift inside the payload:
@@ -257,16 +261,18 @@ def store(parts: dict, compiled) -> bool:
         serialize_executable.deserialize_and_load(
             payload, in_tree, out_tree,
             execution_devices=_execution_devices(parts))
-        entry = {"schema": _SCHEMA, "parts": parts, "env": _env_parts(),
-                 "payload": payload, "in_tree": in_tree,
-                 "out_tree": out_tree}
-        blob = pickle.dumps(entry)
+        # two pickles in a row: a head that says what the entry is
+        # (all `scan` reads) and the body with the executable
+        head = {"schema": _SCHEMA, "parts": parts, "env": _env_parts()}
+        body = {"payload": payload, "in_tree": in_tree,
+                "out_tree": out_tree}
         directory = cache_dir()
         os.makedirs(directory, exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=directory, suffix=".tmp")
         try:
             with os.fdopen(fd, "wb") as f:
-                f.write(blob)
+                pickle.dump(head, f)
+                pickle.dump(body, f)
             os.replace(tmp, _entry_path(parts))
         except BaseException:
             try:
@@ -288,8 +294,9 @@ def store(parts: dict, compiled) -> bool:
 def scan(kind: str | None = None) -> list[dict]:
     """Metadata of every loadable entry for the CURRENT environment
     (optionally filtered by parts["kind"]), oldest first — the hydration
-    walk.  Unreadable entries are skipped silently; pass each returned
-    parts dict to load() for the executable itself."""
+    walk.  Reads each entry's head alone, not its executable (up to
+    240 MB).  Unreadable entries are skipped silently; pass each
+    returned parts dict to load() for the executable itself."""
     try:
         names = [n for n in os.listdir(cache_dir()) if n.endswith(_SUFFIX)]
     except OSError:
@@ -300,7 +307,7 @@ def scan(kind: str | None = None) -> list[dict]:
         path = os.path.join(cache_dir(), name)
         try:
             with open(path, "rb") as f:
-                entry = pickle.loads(f.read())
+                entry = pickle.load(f)
             if entry.get("schema") != _SCHEMA:
                 continue
             if env is None:

@@ -445,9 +445,9 @@ print(json.dumps({"hydrated": hydrated, "digest": digest,
 def test_cross_process_warm_restart_drill(tmp_path):
     """The tentpole's acceptance drill: process A proves cold and
     populates the cache; a fresh process B sharing only the cache
-    directory hydrates every phase program from disk, recompiles no
-    phase kernel (no source="compiled" rows), and produces a
-    byte-identical proof — with the phase build wall collapsing by far
+    directory hydrates every phase program and every FRI layer program
+    (four layer sizes of three) from disk, recompiles neither (no
+    source="compiled" rows), and produces a byte-identical proof — with the phase build wall collapsing by far
     more than the 10x warmup target."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ, ETHREX_EXEC_CACHE_DIR=str(tmp_path),
@@ -463,13 +463,14 @@ def test_cross_process_warm_restart_drill(tmp_path):
 
     cold = child()
     assert cold["hydrated"] == 0
-    assert cold["by_source"] == {"compiled": 4}
-    assert cold["exec_stats"]["stores"] == 4
+    # one series a phase kernel and one a layer kernel (all sizes)
+    assert cold["by_source"] == {"compiled": 4 + 3}
+    assert cold["exec_stats"]["stores"] == 4 + 4 * 3
 
     warm = child()
     assert warm["hydrated"] == 1                     # one 4-kernel group
     assert warm["digest"] == cold["digest"]          # byte-identical proof
-    assert warm["by_source"] == {"deserialized": 4}  # zero phase recompiles
-    assert warm["exec_stats"] == {"hits": 4, "misses": 0, "errors": 0,
-                                  "stores": 0}
+    assert warm["by_source"] == {"deserialized": 4 + 3}  # no recompile
+    assert warm["exec_stats"] == {"hits": 4 + 4 * 3, "misses": 0,
+                                  "errors": 0, "stores": 0}
     assert warm["build_s"]["deserialized"] * 5 < cold["build_s"]["compiled"]
