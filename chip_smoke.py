@@ -117,14 +117,21 @@ def _phase_devices(progs) -> set:
 def _compile_counts() -> tuple[int, dict]:
     """(number of fresh phase-program compiles, seconds per "Air/kernel")
     from what record_phase_compile already keeps."""
-    from ethrex_tpu.perf.bench_suite import _phase_compile_walls
     from ethrex_tpu.utils.metrics import METRICS
 
     hist = (METRICS.snapshot().get("histograms") or {}).get(
         "prover_phase_compile_seconds") or {}
-    fresh = sum(int(row.get("count", 0)) for row in hist.get("series", [])
-                if row.get("labels", {}).get("source") == "compiled")
-    return fresh, _phase_compile_walls()
+    fresh = 0
+    walls: dict = {}
+    for row in hist.get("series", []):
+        lab = row.get("labels", {})
+        if lab.get("source") == "compiled":
+            fresh += int(row.get("count", 0))
+        key = "{}/{}".format(lab.get("air", "?"), lab.get("kernel", "?"))
+        if lab.get("mesh", "none") != "none":
+            key += "@" + lab["mesh"]
+        walls[key] = round(walls.get(key, 0.0) + float(row.get("sum", 0.0)), 4)
+    return fresh, walls
 
 
 def _print_versions() -> None:

@@ -1,8 +1,6 @@
-"""Performance observability: continuous profiling, roofline accounting
-and the bench suite (docs/PERFORMANCE.md).
-
-Three pillars, built on the PR-3 tracing spans and the PR-5
-timeseries/SLO substrate:
+"""Performance telemetry the running program keeps about itself
+(docs/PERFORMANCE.md).  Speed is measured elsewhere: by the benchmark
+under ``benchmark/`` on the chip, with its findings in ``PERF.md``.
 
 - ``profiler``: a process-wide stage-attribution tree unifying the
   block_until_ready-bounded prover stage spans with the L1 import legs
@@ -12,19 +10,19 @@ timeseries/SLO substrate:
 - ``roofline``: XLA cost-model FLOPs/bytes per compiled STARK phase
   program combined with measured wall-clock into achieved-FLOP/s and
   utilization-vs-peak estimates.
-- ``bench_suite``: the measurement logic behind ``bench.py`` (the repo
-  root keeps a thin CLI shim), including the forced-CPU fallback for
-  hosts whose TPU plugin is present but dead, and the append-only
-  ``bench_history.jsonl`` trajectory.
-- ``hlo_introspect`` / ``occupancy`` (PR 18): the scaling autopsy —
-  per-kernel collective/reshard accounting straight from the compiled
-  programs' HLO plus device-occupancy timelines for the parallel
-  prover, consumed by the bench's ``explain_scaling`` diff
-  (docs/PERFORMANCE.md "Reading the scaling autopsy").
+- ``hlo_introspect`` / ``occupancy``: per-kernel collective/reshard
+  accounting from the compiled programs' HLO, and device-occupancy
+  timelines for the parallel prover (docs/PERFORMANCE.md "Reading the
+  scaling autopsy").
+- ``chain_path``: measured queues over the transaction pipeline, a
+  sampled per-tx lifecycle and the live ``block_inclusion_tps`` gauge
+  (docs/OBSERVABILITY.md "Chain-path telemetry").
+- ``loadgen``: the open-loop load harness for the JSON-RPC front door
+  (``python -m ethrex_tpu.perf.loadgen``).
 
-Everything here is telemetry and sits behind the never-raise contract:
-a failing hook degrades to missing numbers, never a failed prove or
-import.
+Everything here but ``loadgen`` is telemetry and sits behind the
+never-raise contract: a failing hook degrades to missing numbers, never
+a failed prove or import.
 """
 
 from . import profiler, roofline  # noqa: F401

@@ -22,8 +22,7 @@ Three layers:
   lifecycle ring (admitted/selected/included/batched/proved/settled
   timestamps, joined to the PR-15 batch trace by trace ID) and a live
   ``block_inclusion_tps`` gauge over a sliding window.
-- ``explain_chain_path()``: the PR-18 ``explain_scaling`` pattern
-  applied to the pipeline — a pure function over the queue stats that
+- ``explain_chain_path()``: a pure function over the queue stats that
   names the dominant bottleneck stage with a human-readable verdict.
 
 Everything here is telemetry on hot paths: every public entry point is
@@ -130,8 +129,7 @@ def record_inclusion_tps(tps: float):
         METRICS.set(
             "block_inclusion_tps", float(tps),
             "Transactions included in sealed blocks per second over the "
-            "chain-path sliding window — the live gauge behind the "
-            "bench --measure-inclusion history gate")
+            "chain-path sliding window")
     except Exception:
         pass
 
@@ -680,8 +678,7 @@ def _jsonable(obj):
 
 
 def explain_chain_path(path: ChainPath | None = None) -> dict:
-    """Name the dominant chain-path bottleneck from the queue stats —
-    the ``explain_scaling`` pattern applied to the tx pipeline.
+    """Name the dominant chain-path bottleneck from the queue stats.
 
     Pure over ``StageQueue.stats()`` output; returns a stub verdict
     (bottleneck null) when no stage shows pressure, so the RPC degrades

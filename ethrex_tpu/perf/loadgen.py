@@ -1,9 +1,9 @@
 """Open-loop load harness for the JSON-RPC serving layer.
 
-The legacy generator (`utils/load_test.py`, now a shim over this
-module) is CLOSED-loop: it fires the next request only after the
-previous one returns, so a slow server throttles the generator and the
-measured latencies silently omit exactly the stalls that matter
+The legacy generator (`run_load`, at the end of this module) is
+CLOSED-loop: it fires the next request only after the previous one
+returns, so a slow server throttles the generator and the measured
+latencies silently omit exactly the stalls that matter
 ("coordinated omission" — see the Tail at Scale discussion in
 docs/PERFORMANCE.md).  This harness is OPEN-loop:
 
@@ -51,8 +51,7 @@ from ..utils.overload import is_busy_error
 
 DEFAULT_KEY = 0x45A915E4D060149EB4365960E6A7A45F334393093061116B197E3240065FF2D8
 
-# counter contract: every call increments slot 0 (the "IO" load shape;
-# kept here verbatim for the utils/load_test shim)
+# counter contract: every call increments slot 0 (the "IO" load shape)
 SSTORE_RUNTIME = "5f546001015f5500"
 SSTORE_INITCODE = "67" + SSTORE_RUNTIME + "5f5260086018f3"
 
@@ -658,8 +657,8 @@ class Harness:
 
 
 # ---------------------------------------------------------------------------
-# legacy closed-loop generator (moved verbatim from utils/load_test.py;
-# measures inclusion throughput, NOT serving tail — see module docstring)
+# legacy closed-loop generator (measures inclusion throughput, NOT
+# serving tail — see module docstring)
 
 
 def _rpc(url: str, method: str, *params):

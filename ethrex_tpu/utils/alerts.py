@@ -451,9 +451,9 @@ def default_rules(node=None) -> list:
            description="Mempool backlog needs 20s+ to drain at the "
                        "current inclusion rate",
            runbook="Sustained arrival/service imbalance; compare the "
-                   "payload stage spans (ethrex_perf) against the "
-                   "inclusion bench baseline (docs/PERFORMANCE.md "
-                   "'Reading the inclusion bench')."),
+                   "payload stage spans (ethrex_perf) against "
+                   "ethrex_chainPath's queue rates "
+                   "(docs/OBSERVABILITY.md 'Chain-path telemetry')."),
         # chain-path producer stall — txs wait but no block seals;
         # distinct from sequencer_stall (which watches actor loops):
         # this watches the block producer itself
@@ -509,11 +509,10 @@ def default_rules(node=None) -> list:
            window=60.0, for_count=3, resolve_count=3, below=True,
            description="Prover throughput below 10k trace cells/s",
            runbook="Compare ethrex_perf roofline utilization against "
-                   "the last bench_history.jsonl record; a collapsed "
-                   "kernel usually means recompilation churn or a "
-                   "fallen-back backend."),
-        # RPC serving tail (the item-3 front-door SLO; thresholds match
-        # the serving bench gate in docs/PERFORMANCE.md)
+                   "PERF.md section 5 (the last benchmark/ run on the "
+                   "chip); a collapsed kernel usually means "
+                   "recompilation churn or a fallen-back backend."),
+        # RPC serving tail (the front-door SLO)
         mk("rpc_request_p99:page", "page",
            p99_signal("rpc_request_seconds", window=120.0), 2.0,
            window=120.0, for_count=2, resolve_count=3,
@@ -525,8 +524,9 @@ def default_rules(node=None) -> list:
            p99_signal("rpc_request_seconds", window=600.0), 0.5,
            window=600.0, for_count=3, resolve_count=3,
            description="JSON-RPC p99 over 10m exceeds 0.5s",
-           runbook="Compare against the serving record in "
-                   "bench_history.jsonl; see ethrex_health rpc section "
+           runbook="Compare against a perf/loadgen.py sweep of this "
+                   "node (no benchmark/ cell measures serving yet, "
+                   "PERF.md section 7); see ethrex_health rpc section "
                    "for resets/EOFs under load."),
         # mempool saturation — sustained occupancy near capacity means
         # admissions are evicting (pool churn, dropped txs)
@@ -634,9 +634,9 @@ def default_rules(node=None) -> list:
                        "above 40%",
            runbook="ethrex_perf's collectives section names the kernel "
                    "and op mix (all-gather vs all-reduce bytes); "
-                   "re-check _MeshPlan's phase-boundary shardings and "
-                   "the explain_scaling autopsy in the latest "
-                   "bench_history.jsonl scaling record."),
+                   "re-check _MeshPlan's phase-boundary shardings "
+                   "against PERF.md section 5 and the device trace of "
+                   "a benchmark/ run."),
     ]
 
 

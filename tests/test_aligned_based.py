@@ -133,7 +133,9 @@ def test_based_follower_records_fatal_divergence():
     fetcher = BlockFetcher(follower, l1)
     assert fetcher.healthy()
     fetcher.start(interval=0.01)
-    deadline = time.time() + 5
+    # a guard against a hang, not a budget: the fetch is ~2 s of host
+    # KZG alone and missed 5 s beside five other test workers
+    deadline = time.time() + 60
     while fetcher.fatal is None and time.time() < deadline:
         time.sleep(0.01)
     assert not fetcher.healthy()

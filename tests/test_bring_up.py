@@ -1,9 +1,8 @@
 """The places where a proof could leave the chip, or a failure pass as
 success, are closed — one pin each (ISSUE 25): chip_smoke.py refuses to
 run without a TPU, the compile cache can be placed from outside and sits
-in the checkout otherwise, `_aot_phases` propagates a compile error, and
-`python bench.py` publishes nothing from a host without a chip.  (The
-degradation ladder and the memory gate are pinned in
+in the checkout otherwise, and `_aot_phases` propagates a compile error.
+(The degradation ladder and the memory gate are pinned in
 tests/test_runtime_chaos.py, the peak table in tests/test_perf.py.)"""
 
 import json
@@ -26,7 +25,7 @@ SHIFT = stark_prover.StarkParams().shift
 
 def _run(argv, cwd=REPO, **env):
     full = {k: v for k, v in os.environ.items()
-            if k not in ("JAX_COMPILATION_CACHE_DIR", "BENCH_ALLOW_CPU")}
+            if k != "JAX_COMPILATION_CACHE_DIR"}
     full.update(JAX_PLATFORMS="cpu", **env)
     return subprocess.run([sys.executable, *argv], cwd=cwd, env=full,
                           capture_output=True, text=True, timeout=300)
@@ -34,6 +33,15 @@ def _run(argv, cwd=REPO, **env):
 
 # ---------------------------------------------------------------------------
 # chip_smoke.py
+
+def _import_chip_smoke():
+    sys.path.insert(0, str(REPO))
+    try:
+        import chip_smoke
+    finally:
+        sys.path.pop(0)
+    return chip_smoke
+
 
 @pytest.mark.parametrize("argv", [[], ["--chips", "4"]])
 def test_chip_smoke_without_a_tpu_fails_and_says_so(argv):
@@ -61,13 +69,31 @@ def test_chip_smoke_work_function_on_the_cpu(monkeypatch):
     with one transfer a batch, on the CPU — finds wrong wiring.  Slow:
     the 278-column TransferAir takes many minutes to compile on
     XLA:CPU, so the batch timeout is the one thing stretched."""
-    sys.path.insert(0, str(REPO))
-    try:
-        import chip_smoke
-    finally:
-        sys.path.pop(0)
+    chip_smoke = _import_chip_smoke()
     monkeypatch.setattr(chip_smoke, "BATCH_TIMEOUT", 7200.0)
     chip_smoke.run_batches(1)
+
+
+def test_chip_smoke_sums_compile_seconds_per_air_and_kernel(monkeypatch):
+    """Seconds of the phase-compile histogram summed per "Air/kernel"
+    over both sources, a mesh build under its own key, in the order the
+    rows were first recorded."""
+    from ethrex_tpu.utils import metrics
+
+    chip_smoke = _import_chip_smoke()
+    monkeypatch.setattr(metrics, "METRICS", metrics.Metrics())
+    assert chip_smoke._compile_counts()[1] == {}
+    metrics.record_phase_compile("TransferAir", "quotient", 1.5)
+    metrics.record_phase_compile("TransferAir", "quotient", 2.25)
+    metrics.record_phase_compile("TransferAir", "quotient", 0.125,
+                                 source="deserialized")
+    metrics.record_phase_compile("FibAir", "commit", 0.5, mesh="2x1")
+    metrics.record_phase_compile("FibAir", "commit", 0.25,
+                                 source="deserialized")
+    walls = chip_smoke._compile_counts()[1]
+    assert list(walls.items()) == [("TransferAir/quotient", 3.875),
+                                   ("FibAir/commit@2x1", 0.5),
+                                   ("FibAir/commit", 0.25)]
 
 
 # ---------------------------------------------------------------------------
@@ -333,19 +359,6 @@ def test_prove_asks_ahead_for_every_air_of_the_batch(monkeypatch):
     assert recorded["prove.vm_batch"] == {
         "mode": "token", "txs": 6, "tok_calls": 6, "acct_rows": 13,
         "slot_rows": 12}
-
-
-# ---------------------------------------------------------------------------
-# bench.py, default mode
-
-def test_bench_default_mode_on_a_host_without_a_chip(tmp_path):
-    """The real thing, children and all: `python bench.py` where JAX
-    finds only the CPU exits 3, prints no record, and leaves no
-    history line."""
-    proc = _run([str(REPO / "bench.py")])
-    assert proc.returncode == 3
-    assert proc.stdout.strip() == ""
-    assert "refusing to publish" in proc.stderr
 
 
 @pytest.mark.parametrize("event, key", [

@@ -1,10 +1,9 @@
 """Chain-path X-ray (docs/OBSERVABILITY.md "Chain-path telemetry"):
 StageQueue accounting + Little's-law cross-check, sampled per-tx
 lifecycle records, the bottleneck explainer, loadgen typed-rejection
-classification, the inclusion-bench record builder, and the end-to-end
-acceptance run — a real-TCP overload where the explainer must name the
-admission/producer stage and a sampled lifecycle's hop dwells must sum
-to its admitted→included wall."""
+classification, and the end-to-end acceptance run — a real-TCP overload
+where the explainer must name the admission/producer stage and a
+sampled lifecycle's hop dwells must sum to its admitted→included wall."""
 
 import json
 
@@ -309,37 +308,6 @@ def test_classify_batch_responses():
         [_busy(), {"error": {"code": -32603, "message": "boom"}}]) == \
         (True, False, None)
     assert loadgen._classify([]) == (True, False, None)
-
-
-# ---------------------------------------------------------------------------
-# inclusion-bench record builder
-
-def _run_row(tps, err=0.0):
-    return {"report": {"offeredRate": 100, "achievedRate": 99,
-                       "errorRate": err, "shed": 0, "shedRate": 0.0,
-                       "rejected": 2, "rejectionRate": 0.02,
-                       "rejections": {"sender_limit": 2}, "missed": 0},
-            "blocks": 4, "txsIncluded": int(tps * 3), "includedTps": tps}
-
-
-def test_build_inclusion_record_headline_prefers_healthy_rates():
-    from ethrex_tpu.perf.bench_suite import build_inclusion_record
-
-    rec = build_inclusion_record(
-        [_run_row(120.0), _run_row(300.0, err=0.5), _run_row(80.0)],
-        queues={"admission": {"depth": 0}},
-        explain={"bottleneck": None}, setup_s=1.0, sweep_s=9.0)
-    # 300 tps came from a 50%-error run: disqualified
-    assert rec["metric"] == "block_inclusion_tps"
-    assert rec["value"] == 120.0
-    assert rec["unit"] == "tx/s"
-    assert rec["backend"] == "cpu"
-    assert rec["stages"] == {"setup_s": 1.0, "sweep_s": 9.0}
-    assert rec["rates"][0]["rejections"] == {"sender_limit": 2}
-    assert rec["queues"]["admission"]["depth"] == 0
-    # falls back to best-overall when no rate stayed clean; empty -> 0
-    assert build_inclusion_record([_run_row(300.0, err=0.5)])["value"] == 300.0
-    assert build_inclusion_record([])["value"] == 0.0
 
 
 # ---------------------------------------------------------------------------

@@ -616,26 +616,6 @@ def test_span_shipping_overhead_under_two_percent():
         f"ingest cost {best_ingest * 1e6:.0f}us > 156us budget"
 
 
-def test_bench_measure_reports_critical_path():
-    """The headline --measure record carries a critical_path breakdown
-    next to stages (statically, like the stages lint: the full prove is
-    a slow-bench, not a tier-1 test)."""
-    import ast
-    import inspect
-
-    from ethrex_tpu.perf import bench_suite
-
-    tree = ast.parse(inspect.getsource(bench_suite))
-    fn = next(n for n in tree.body
-              if isinstance(n, ast.FunctionDef) and n.name == "measure")
-    keys = {k.value for node in ast.walk(fn) if isinstance(node, ast.Dict)
-            for k in node.keys if isinstance(k, ast.Constant)}
-    assert "critical_path" in keys and "stages" in keys
-    # and the breakdown comes from the tracing walker, not a hand-rolled
-    # sum that could drift from the RPC's attribution
-    assert "critical_path" in inspect.getsource(bench_suite.measure)
-
-
 # ---------------------------------------------------------------------------
 # leaf spans of a batch (PERF.md section 3): every host second of a
 # batch under a span that names the work, with bytes and tries as

@@ -14,7 +14,6 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 import pytest
 
 from ethrex_tpu.perf import loadgen
-from ethrex_tpu.perf.bench_suite import build_serving_record
 
 
 # ---------------------------------------------------------------------------
@@ -186,18 +185,6 @@ def test_request_latency_histogram_uses_shared_ladder():
     assert "loadgen_request_seconds" in registry.help
 
 
-# ---------------------------------------------------------------------------
-# utils/load_test is a shim over this module
-
-def test_load_test_shim_reexports_loadgen():
-    from ethrex_tpu.utils import load_test
-
-    assert load_test.run_load is loadgen.run_load
-    assert load_test.main is loadgen.main
-    assert load_test.SSTORE_INITCODE == loadgen.SSTORE_INITCODE
-    assert load_test.SSTORE_RUNTIME == loadgen.SSTORE_RUNTIME
-
-
 def test_token_initcode_returns_runtime():
     """The deploy wrapper must RETURN exactly the 8-byte runtime (same
     PUSH8/MSTORE/RETURN wrapper the sstore template uses)."""
@@ -207,52 +194,9 @@ def test_token_initcode_returns_runtime():
 
 
 # ---------------------------------------------------------------------------
-# serving record (bench_suite integration, pure part)
-
-def test_build_serving_record_picks_sustained_rate():
-    sweep = {
-        "arrivals": "poisson",
-        "maxSustainableRate": 25.0,
-        "rates": [
-            {"offeredRate": 10.0, "achievedRate": 10.0, "errorRate": 0.0,
-             "missed": 0, "latency": {"p50": 0.001, "p95": 0.002,
-                                      "p99": 0.003}},
-            {"offeredRate": 25.0, "achievedRate": 24.0, "errorRate": 0.0,
-             "missed": 1, "latency": {"p50": 0.002, "p95": 0.004,
-                                      "p99": 0.006}},
-        ],
-    }
-    rec = build_serving_record(sweep, setup_s=1.0, sweep_s=2.0)
-    assert rec["metric"] == "serving_rpc_p99_seconds"
-    assert rec["value"] == 0.006          # p99 AT the sustained rate
-    assert rec["sustained_rate"] == 25.0
-    assert rec["backend"] == "cpu"
-    assert len(rec["rates"]) == 2
-    assert rec["rates"][0]["p95"] == 0.002
-    assert rec["stages"] == {"setup_s": 1.0, "sweep_s": 2.0}
-    sub = rec["configs"]["serving_rate"]
-    assert sub["metric"] == "serving_sustained_tps"
-    assert sub["value"] == 25.0
-
-
-def test_build_serving_record_nothing_sustained():
-    sweep = {"arrivals": "fixed", "maxSustainableRate": None,
-             "rates": [{"offeredRate": 50.0, "achievedRate": 3.0,
-                        "errorRate": 0.2, "missed": 40,
-                        "latency": {"p50": 0.5, "p95": 1.0, "p99": 2.0}}]}
-    rec = build_serving_record(sweep)
-    assert rec["sustained_rate"] == 0.0
-    assert rec["value"] == 2.0            # gentlest rate still reported
-    # a zero-valued sub-metric is excluded from history series, so a
-    # collapsed run can never become the gate's baseline
-    assert rec["configs"]["serving_rate"]["value"] == 0.0
-
-
-# ---------------------------------------------------------------------------
-# many-sender tx mode: the sweep shape behind BENCH_SERVING_SENDERS
-# (ROADMAP item 3 — 10k-sender serving sweeps); funding must chunk
-# below the mempool's per-sender slot cap or the ROOT key evicts its
-# own funding tail and later senders never get funded
+# many-sender tx mode: funding must chunk below the mempool's
+# per-sender slot cap or the ROOT key evicts its own funding tail and
+# later senders never get funded
 
 def test_many_sender_funding_chunks_below_sender_cap():
     from ethrex_tpu.blockchain.mempool import MAX_SENDER_SLOTS

@@ -99,23 +99,6 @@ def test_mesh_key_distinguishes_layouts():
     assert k4 == k4b, "identical layout must hit the cache"
 
 
-def test_history_series_excludes_scaling_records(monkeypatch):
-    """bench gate hygiene: records carrying a scaling sweep or a non-1
-    devices field must stay out of the same-backend history series."""
-    from ethrex_tpu.perf import bench_suite
-
-    rows = [
-        {"backend": "cpu", "metric": "m", "value": 1.0},
-        {"backend": "cpu", "metric": "m", "value": 9.0, "devices": 8},
-        {"backend": "cpu", "metric": "m", "value": 7.0,
-         "scaling": {"1": {}}},
-        {"backend": "cpu", "metric": "m", "value": 2.0, "devices": 1},
-    ]
-    monkeypatch.setattr(bench_suite, "_read_history", lambda: rows)
-    assert bench_suite._history_series("m") == [("cpu", 1.0),
-                                                ("cpu", 2.0)]
-
-
 # ---------------------------------------------------------------------------
 # sharded-vs-single differential proving
 

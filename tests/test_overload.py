@@ -1,7 +1,7 @@
 """Overload protection units: admission-control cost classes and shed
 ladder, typed mempool admission rules (sender caps, nonce gaps, dynamic
-fee floor, replacement-by-fee), WS slow-consumer protection, loadgen
-shed classification, and the serving-bench shed surface.
+fee floor, replacement-by-fee), WS slow-consumer protection, and
+loadgen shed classification.
 
 The end-to-end 5x-overload soak lives in tests/test_overload_chaos.py.
 """
@@ -413,34 +413,6 @@ def test_sweep_counts_shed_as_not_delivered(busy_rpc):
     # 100% graceful sheds and 0% errors is still NOT a sustained rate
     assert sweep["rates"][0]["errorRate"] == 0.0
     assert sweep["maxSustainableRate"] is None
-
-
-def test_serving_record_carries_shed_rate():
-    from ethrex_tpu.perf.bench_suite import build_serving_record
-
-    sweep = {
-        "arrivals": "fixed", "maxSustainableRate": 25.0,
-        "rates": [
-            {"offeredRate": 25.0, "achievedRate": 24.9, "errorRate": 0.0,
-             "missed": 0, "shed": 3, "shedRate": 0.02,
-             "latency": {"p50": 0.001, "p95": 0.002, "p99": 0.003}},
-            {"offeredRate": 50.0, "achievedRate": 49.0, "errorRate": 0.0,
-             "missed": 2, "shed": 30, "shedRate": 0.6,
-             "latency": {"p50": 0.001, "p95": 0.002, "p99": 0.004}},
-        ],
-    }
-    rec = build_serving_record(sweep)
-    assert rec["value"] == 0.003          # accepted-only p99 at the pick
-    assert rec["shed_rate"] == 0.02
-    assert rec["rates"][1]["shed"] == 30
-    assert rec["rates"][1]["shedRate"] == 0.6
-    # sweeps recorded before shedding existed stay loadable
-    old = {"arrivals": "fixed", "maxSustainableRate": 10.0,
-           "rates": [{"offeredRate": 10.0, "achievedRate": 10.0,
-                      "errorRate": 0.0, "missed": 0,
-                      "latency": {"p50": 0.001, "p95": 0.002,
-                                  "p99": 0.003}}]}
-    assert build_serving_record(old)["shed_rate"] == 0.0
 
 
 # ---------------------------------------------------------------------------

@@ -1,17 +1,15 @@
 """Performance-observability battery (docs/PERFORMANCE.md): the
 continuous stage profiler, roofline accounting, the perf surfaces
 (metrics exposition, snapshot, ethrex_perf RPC, monitor panel, alert
-floors), and the bench suite's CPU fallback + history regression gate.
+floors).
 
 The never-raise drills matter most: every perf hook sits inside the
 prover or import hot path, so a malformed cost_analysis() or a broken
 jax.profiler must degrade to missing telemetry, never a failed prove."""
 
-import json
-
 import pytest
 
-from ethrex_tpu.perf import bench_suite, profiler, roofline
+from ethrex_tpu.perf import profiler, roofline
 from ethrex_tpu.utils import tracing
 from ethrex_tpu.utils.metrics import (
     METRICS, observe_import_stage, record_import_throughput,
@@ -457,127 +455,3 @@ def test_default_rules_include_throughput_floors():
     by_name = {r.name: r for r in default_rules(None)}
     assert by_name["l1_import_throughput_floor:warn"].below is True
     assert by_name["prover_throughput_floor:warn"].below is True
-
-
-# ---------------------------------------------------------------------------
-# bench suite: CPU fallback + history + regression gate
-
-_HEADLINE = {
-    "metric": "transfer_batch_prove_wall_s", "value": 12.3, "unit": "s",
-    "vs_baseline": 0.02, "batch_gas": 210000, "num_txs": 10,
-    "stages": {"execute": 1.0, "state_proof": 9.0},
-}
-
-
-def _history(tmp_path):
-    with open(tmp_path / "history.jsonl") as f:
-        return [json.loads(ln) for ln in f if ln.strip()]
-
-
-def test_bench_main_without_a_chip_exits_nonzero_and_prints_no_record(
-        monkeypatch, tmp_path, capsys):
-    """No chip, no record: the --measure child refused (exit 3 from
-    _guard_backend), so main() says so on stderr, exits 3, prints
-    nothing on stdout and appends nothing to the history — no CPU run
-    in its place, no degraded or replayed record."""
-    monkeypatch.setattr(bench_suite, "HISTORY_PATH",
-                        str(tmp_path / "history.jsonl"))
-    calls = []
-
-    def fake_attempt(flag, timeout, env=None):
-        calls.append(flag)
-        return {"_err": "rc=3 backend is cpu, refusing to publish"}
-
-    monkeypatch.setattr(bench_suite, "_attempt", fake_attempt)
-    with pytest.raises(SystemExit) as ei:
-        bench_suite.main()
-    assert ei.value.code == 3
-    out, err = capsys.readouterr()
-    assert out == ""
-    assert "refusing to publish" in err
-    assert calls == ["--measure"]          # one try, no fallback child
-    assert not (tmp_path / "history.jsonl").exists()
-
-
-def test_bench_main_publishes_the_childs_record_with_its_platform(
-        monkeypatch, tmp_path, capsys):
-    monkeypatch.setattr(bench_suite, "HISTORY_PATH",
-                        str(tmp_path / "history.jsonl"))
-    monkeypatch.setenv("BENCH_SKIP_EXTRAS", "1")
-    monkeypatch.setattr(
-        bench_suite, "_attempt",
-        lambda flag, timeout, env=None: {**_HEADLINE, "platform": "tpu",
-                                         "device_kind": "TPU v5 lite"})
-    bench_suite.main()
-    record = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert record["value"] == 12.3 and record["backend"] == "tpu"
-    assert "degraded" not in record and "fallback_reason" not in record
-    (entry,) = _history(tmp_path)
-    assert entry["backend"] == "tpu" and "ts" in entry
-
-
-def test_history_series_and_same_backend_gate(
-        monkeypatch, tmp_path, capsys):
-    monkeypatch.setattr(bench_suite, "HISTORY_PATH",
-                        str(tmp_path / "history.jsonl"))
-    wall = "transfer_batch_prove_wall_s"
-    cells = "stark_prove_core_trace_cells_per_sec"
-    bench_suite.append_history(
-        {"metric": wall, "value": 10.0, "backend": "tpu",
-         "configs": {"core": {"metric": cells, "value": 100.0}}})
-    bench_suite.append_history(
-        {"metric": wall, "value": 25.0, "backend": "tpu",
-         "configs": {"core": {"metric": cells, "value": 40.0}}})
-    assert bench_suite._history_series(wall) == [
-        ("tpu", 10.0), ("tpu", 25.0)]
-    # sub-config metrics are first-class series entries
-    assert bench_suite._history_series(cells) == [
-        ("tpu", 100.0), ("tpu", 40.0)]
-
-    # wall is lower-is-better: 10s -> 25s is a 0.4 ratio, a regression
-    code = bench_suite.check_history_metric(wall, 0.8,
-                                            lower_is_better=True)
-    out = json.loads(capsys.readouterr().out.strip())
-    assert (code, out["status"]) == (2, "regression")
-    assert out["ratio"] == pytest.approx(0.4)
-    # cells is higher-is-better: 100 -> 40 also regresses
-    assert bench_suite.check_history_metric(cells, 0.8) == 2
-    capsys.readouterr()
-
-    # a CPU-fallback record must NOT be judged against the chip numbers
-    bench_suite.append_history(
-        {"metric": wall, "value": 500.0, "backend": "cpu"})
-    code = bench_suite.check_history_metric(wall, 0.8,
-                                            lower_is_better=True)
-    out = json.loads(capsys.readouterr().out.strip())
-    assert (code, out["status"]) == (0, "no-baseline")
-    assert out["backend"] == "cpu"
-    # a second cpu record forms a same-backend pair
-    bench_suite.append_history(
-        {"metric": wall, "value": 510.0, "backend": "cpu"})
-    code = bench_suite.check_history_metric(wall, 0.8,
-                                            lower_is_better=True)
-    out = json.loads(capsys.readouterr().out.strip())
-    assert (code, out["status"]) == (0, "ok")
-    assert out["baseline"] == 500.0 and out["current"] == 510.0
-
-    # torn trailing line (crash mid-append) must not kill the gate
-    with open(tmp_path / "history.jsonl", "a") as f:
-        f.write('{"metric": "transfer_batch_pro')
-    assert len(bench_suite._history_series(wall)) == 4
-
-
-def test_check_regression_suite_worst_code_wins(monkeypatch):
-    def codes(mgas, wall, cells):
-        monkeypatch.setattr(bench_suite, "check_regression",
-                            lambda threshold: mgas)
-        monkeypatch.setattr(
-            bench_suite, "check_history_metric",
-            lambda metric, threshold, lower_is_better=False:
-                wall if "wall" in metric else cells)
-        return bench_suite.check_regression_suite()
-
-    assert codes(0, 0, 0) == 0
-    assert codes(1, 0, 0) == 1       # broken measurement: error, not pass
-    assert codes(0, 2, 0) == 2       # headline wall regressed
-    assert codes(1, 0, 2) == 2       # regression outranks error

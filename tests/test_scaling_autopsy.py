@@ -1,8 +1,7 @@
 """Mesh-scaling autopsy battery (PR 18, docs/PERFORMANCE.md "Reading
 the scaling autopsy"): HLO collective accounting (perf/hlo_introspect),
 device-occupancy timelines (perf/occupancy + the parallel prover
-wiring), the explain_scaling 1-vs-N diff, and every surface the autopsy
-flows through — gauges, ethrex_perf/ethrex_health stubs, the monitor
+wiring), and every surface the autopsy flows through — gauges, ethrex_perf/ethrex_health stubs, the monitor
 panel, the Perfetto device-lane view, and the occupancy/collective
 alert pair.
 
@@ -277,77 +276,6 @@ def test_run_proof_jobs_serial_path_records_occupancy():
     assert abs(lane["busySeconds"] + lane["idleSeconds"]
                - last["wallSeconds"]) \
         <= 0.05 * max(last["wallSeconds"], 1e-9)
-
-
-# ---------------------------------------------------------------------------
-# explain_scaling golden (satellite): planted dominant regressor
-
-
-def _child(ndev, value, kernels, occ_fraction):
-    return {"value": value, "devices": ndev, "kernels": kernels,
-            "occupancy": {"fraction": occ_fraction, "devices": ndev}}
-
-
-def test_explain_scaling_names_planted_collective_regressor():
-    from ethrex_tpu.perf.bench_suite import explain_scaling
-
-    base = _child(1, 192_000.0, {
-        "commit": {"wall_s": 0.10, "compile_s": 20.0,
-                   "collective_ops": 0, "collective_bytes": 0},
-        "quotient": {"wall_s": 0.50, "compile_s": 30.0,
-                     "collective_ops": 0, "collective_bytes": 0},
-    }, 0.95)
-    # 8 devices: quotient wall +38%, delta 0.19s, and the planted
-    # all-gather traffic accounts for ~92% of it at 10 GB/s
-    tgt = _child(8, 124_000.0, {
-        "commit": {"wall_s": 0.11, "compile_s": 80.0,
-                   "collective_ops": 2, "collective_bytes": int(1e8)},
-        "quotient": {"wall_s": 0.69, "compile_s": 123.0,
-                     "collective_ops": 9,
-                     "collective_bytes": int(1.75e9)},
-    }, 0.90)
-    autopsy = explain_scaling({"1": base, "8": tgt}, ici_gbps=10.0)
-    assert autopsy["baselineDevices"] == 1
-    assert autopsy["targetDevices"] == 8
-    dom = autopsy["dominant"]
-    assert dom["kernel"] == "quotient"
-    assert dom["regressor"] == "collectives"
-    q = autopsy["kernels"]["quotient"]
-    assert q["wallDeltaPct"] == pytest.approx(38.0)
-    assert q["collectiveShareOfDelta"] == pytest.approx(0.921, abs=0.01)
-    assert q["compileRatio"] == pytest.approx(4.1)
-    assert "% of delta is collective bytes" in q["summary"]
-    assert "compile x4.1" in q["summary"]
-    assert autopsy["headline"]["targetOverBaseline"] \
-        == pytest.approx(124_000.0 / 192_000.0, abs=1e-3)
-
-
-def test_explain_scaling_degrades_without_kernel_data():
-    from ethrex_tpu.perf.bench_suite import explain_scaling
-
-    # pre-autopsy children (or failed children) -> an error stub, and
-    # junk keys/records are skipped, never raised on
-    out = explain_scaling({"1": {"value": 1.0}, "8": {"error": "boom"},
-                           "x": None})
-    assert out["error"].startswith("need kernel data")
-    assert explain_scaling(None)["error"]
-
-
-def test_explain_scaling_idle_regressor_and_no_regression():
-    from ethrex_tpu.perf.bench_suite import explain_scaling
-
-    k1 = {"commit": {"wall_s": 1.0, "compile_s": 1.0,
-                     "collective_ops": 0, "collective_bytes": 0}}
-    k8 = {"commit": {"wall_s": 1.4, "compile_s": 1.0,
-                     "collective_ops": 0, "collective_bytes": 0}}
-    out = explain_scaling({"1": _child(1, 10.0, k1, 0.95),
-                           "8": _child(8, 5.0, k8, 0.2)}, ici_gbps=10.0)
-    assert out["dominant"]["regressor"] == "idle"
-    assert out["occupancy"]["drop"] == pytest.approx(0.75)
-    # faster at 8 devices: nothing regressed, dominant says so
-    out = explain_scaling({"1": _child(1, 10.0, k8, 0.9),
-                           "8": _child(8, 20.0, k1, 0.9)}, ici_gbps=10.0)
-    assert out["dominant"]["regressor"] == "none"
 
 
 # ---------------------------------------------------------------------------

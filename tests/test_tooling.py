@@ -262,11 +262,9 @@ def test_no_bare_print_in_library_modules():
     import ethrex_tpu
 
     root = pathlib.Path(ethrex_tpu.__file__).parent
-    # bench_suite is the bench.py CLI's engine: its contract is ONE JSON
-    # line on stdout per measurement, so it owns stdout like cli/repl;
-    # loadgen is the load-harness CLI printing its JSON report the same way
-    allow = {"cli.py", "repl.py", "monitor.py", "bench_suite.py",
-             "loadgen.py"}
+    # loadgen is the load-harness CLI: it prints its JSON report on
+    # stdout, so it owns stdout like cli/repl
+    allow = {"cli.py", "repl.py", "monitor.py", "loadgen.py"}
     pat = re.compile(r"(?<![A-Za-z0-9_.])print\(")
     offenders = []
     for path in sorted(root.rglob("*.py")):
@@ -328,8 +326,8 @@ def test_every_metric_helper_has_help_text():
 
     from ethrex_tpu.blockchain import fork_choice, mempool
     from ethrex_tpu.l2 import leadership
-    from ethrex_tpu.perf import (bench_suite, chain_path, hlo_introspect,
-                                 loadgen, occupancy, profiler, roofline)
+    from ethrex_tpu.perf import (chain_path, hlo_introspect, loadgen,
+                                 occupancy, profiler, roofline)
     from ethrex_tpu.prover import checkpoint, runtime_errors
     from ethrex_tpu.utils import exec_cache, metrics, overload
 
@@ -337,7 +335,7 @@ def test_every_metric_helper_has_help_text():
 
     offenders = []
     for mod in (metrics, tracing, profiler, roofline, hlo_introspect,
-                occupancy, bench_suite, loadgen, chain_path,
+                occupancy, loadgen, chain_path,
                 mempool, fork_choice, overload, exec_cache, checkpoint,
                 runtime_errors, leadership):
         tree = ast.parse(inspect.getsource(mod))
@@ -487,79 +485,6 @@ def test_chain_path_rpc_degrades_on_idle_l1_node():
         node.stop()
 
 
-def test_inclusion_bench_wired_into_cli_and_gate():
-    """--measure-inclusion must exist as a cli branch and the
-    --check-regression suite must gate block_inclusion_tps (same-backend
-    history comparison, higher is better)."""
-    import inspect
-
-    from ethrex_tpu.perf import bench_suite
-
-    assert callable(bench_suite.measure_inclusion)
-    assert "--measure-inclusion" in inspect.getsource(bench_suite.cli)
-    src = inspect.getsource(bench_suite.check_regression_suite)
-    assert "block_inclusion_tps" in src
-
-
-def test_every_bench_config_emits_stages():
-    """Every bench measurement must publish a non-empty per-stage
-    breakdown: a wall-clock number without attribution cannot drive the
-    ROADMAP speed items.  Statically require each measure_* function in
-    the bench suite to build its JSON record with a "stages" key."""
-    import ast
-    import inspect
-
-    from ethrex_tpu.perf import bench_suite
-
-    tree = ast.parse(inspect.getsource(bench_suite))
-    offenders = []
-    for fn in tree.body:
-        if not isinstance(fn, ast.FunctionDef):
-            continue
-        if not fn.name.startswith("measure"):
-            continue
-        has_stages = any(
-            isinstance(node, ast.Dict) and any(
-                isinstance(k, ast.Constant) and k.value == "stages"
-                for k in node.keys)
-            for node in ast.walk(fn))
-        if not has_stages:
-            offenders.append(fn.name)
-    assert not offenders, \
-        f"bench configs without a stages breakdown: {offenders}"
-
-
-def test_scaling_bench_emits_autopsy_fields():
-    """The scaling sweep is only useful if it stays self-explaining:
-    statically require measure_scaling to build its record with the
-    "scaling" and "autopsy" keys, and measure_scaling_one to emit the
-    per-kernel "kernels" and "occupancy" fields explain_scaling
-    consumes — dropping any of them silently re-opens the ROADMAP
-    item-1 attribution gap this layer closed."""
-    import ast
-    import inspect
-
-    from ethrex_tpu.perf import bench_suite
-
-    tree = ast.parse(inspect.getsource(bench_suite))
-    required = {"measure_scaling": {"scaling", "autopsy"},
-                "measure_scaling_one": {"kernels", "occupancy"}}
-    offenders = []
-    for fn in tree.body:
-        if not isinstance(fn, ast.FunctionDef) or fn.name not in required:
-            continue
-        keys = {k.value for node in ast.walk(fn)
-                if isinstance(node, ast.Dict)
-                for k in node.keys
-                if isinstance(k, ast.Constant) and isinstance(k.value, str)}
-        missing = required.pop(fn.name) - keys
-        if missing:
-            offenders.append(f"{fn.name} missing {sorted(missing)}")
-    offenders.extend(f"{name} not found" for name in required)
-    assert not offenders, \
-        f"scaling bench lost its autopsy fields: {offenders}"
-
-
 def test_every_env_knob_is_documented():
     """Every ETHREX_* environment variable the code reads must appear in
     docs/*.md — an undocumented knob is one an operator cannot discover.
@@ -573,7 +498,7 @@ def test_every_env_knob_is_documented():
     repo = pkg.parent
     pat = re.compile(r"ETHREX_[A-Z0-9_]+")
     used = set()
-    for path in sorted(pkg.rglob("*.py")) + [repo / "bench.py"]:
+    for path in sorted(pkg.rglob("*.py")):
         if "__pycache__" in path.parts:
             continue
         used.update(pat.findall(path.read_text()))
@@ -684,34 +609,6 @@ def test_stark_partition_specs_reference_mesh_axis():
     assert not offenders, (
         "string-literal axis names in stark/ PartitionSpec calls "
         f"(use parallel.mesh.AXIS): {sorted(set(offenders))}")
-
-
-def test_bench_check_regression_exit_codes(capsys):
-    """The CI regression gate: ok and missing-baseline pass (0), a
-    throughput drop past the threshold fails (2), a broken current
-    measurement is its own error (1)."""
-    import json as _json
-
-    import bench
-
-    def run(current, baseline, threshold=0.8):
-        code = bench.check_regression(current, baseline, threshold)
-        return code, _json.loads(capsys.readouterr().out.strip())
-
-    code, out = run({"value": 10.0}, {"value": 10.0})
-    assert (code, out["status"]) == (0, "ok")
-    assert out["ratio"] == 1.0
-    code, out = run({"value": 10.0}, {})
-    assert (code, out["status"]) == (0, "no-baseline")
-    code, out = run({"value": 5.0}, {"value": 10.0})
-    assert (code, out["status"]) == (2, "regression")
-    assert out["ratio"] == 0.5
-    # just inside the threshold: not a regression
-    code, out = run({"value": 8.5}, {"value": 10.0})
-    assert (code, out["status"]) == (0, "ok")
-    code, out = run({"value": None, "error": "probe failed"}, {"value": 10})
-    assert (code, out["status"]) == (1, "error")
-    assert out["detail"] == "probe failed"
 
 
 def test_fault_rule_after_skips_leading_occasions():
