@@ -23,10 +23,25 @@ ladder (prover/runtime_errors) must be able to resume a phase prefix
 written at mesh=2x4 on a single device, and a restarted client always
 holds a fresh token for the same batch.
 
-Records are written atomically (tempfile + os.replace) and framed as
-MAGIC | crc32 | length | pickle-blob; a torn, truncated or garbage
-blob fails the frame check and is discarded for a clean fresh prove
-(`proof_ckpt_discards_total`) — the loader never raises.
+What an envelope holds: every large array ONCE, in the row layout the
+query phase gathers from (`commit`: `lde_rows`, the trace tree's
+levels; `quotient`: `chunks`, `q_rows`, the quotient tree's levels).
+The column layouts the next device phase consumes (`lde_cols`,
+`q_lde`) are exact rearrangements of those, rebuilt on the host only
+when a phase is resumed (stark/prover.py, span `ckpt.rebuild`).
+
+Records are written atomically (tempfile + os.replace in the entry's
+own directory, on the calling thread, landed before `store` returns)
+and framed as
+  MAGIC | crc32 | count, header length, buffer lengths (u64 each)
+        | header | buffer 0 | buffer 1 | ...
+The header is a protocol-5 pickle of the record with every array's
+memory taken out of band: it holds no array data.  The buffers are the
+arrays' own memory, checksummed in place (the crc32 runs over
+everything behind it in the file, buffer by buffer) and written
+straight to the file: no blob, no frame copy.  A torn, truncated or
+garbage file fails the frame check and is discarded for a clean fresh
+prove (`proof_ckpt_discards_total`) — the loader never raises.
 
 Env knobs (documented in docs/PROVER_RESILIENCE.md):
   ETHREX_PROOF_CKPT_DIR  checkpoint directory (default
@@ -41,6 +56,7 @@ import hashlib
 import json
 import os
 import pickle
+import struct
 import tempfile
 import threading
 import time
@@ -48,8 +64,12 @@ import zlib
 
 from ..utils import tracing
 
-_SCHEMA = 1
+_SCHEMA = 2
 _MAGIC = b"ETPC"
+# magic, crc32, count of buffers, header length; a u64 length for each
+# buffer follows.  The crc covers the file from `_CRC_FROM` on.
+_HEAD = struct.Struct(">4sI2Q")
+_CRC_FROM = 8
 _SUFFIX = ".ckpt"
 
 _LOCK = threading.Lock()
@@ -224,31 +244,28 @@ def store(batch_id, parts: dict, payload, meta: dict | None = None) -> bool:
     True when the record landed."""
     if not enabled():
         return False
-    # one span for the whole write (pickle, crc, frame copy, file): the
+    # one span for the whole write (header pickle, crc, file): the
     # execute envelope and every per-proof phase pass through here
     with tracing.span("ckpt.store", stage="ckpt") as sp:
         try:
             tracing.set_attrs(sp, phase=parts.get("phase"),
                               job=parts.get("job"))
-            blob = pickle.dumps({"schema": _SCHEMA, "parts": parts,
-                                 "meta": dict(meta or {}),
-                                 "payload": payload},
-                                protocol=pickle.HIGHEST_PROTOCOL)
-            frame = (_MAGIC + zlib.crc32(blob).to_bytes(4, "big")
-                     + len(blob).to_bytes(8, "big") + blob)
+            pieces = _frame({"schema": _SCHEMA, "parts": parts,
+                             "meta": dict(meta or {}), "payload": payload})
             path = _entry_path(batch_id, parts)
             os.makedirs(os.path.dirname(path), exist_ok=True)
             fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path),
                                        suffix=".tmp")
             try:
                 with os.fdopen(fd, "wb") as f:
-                    f.write(frame)
+                    for piece in pieces:
+                        f.write(piece)
                 os.replace(tmp, path)
             except BaseException:
                 with contextlib.suppress(OSError):
                     os.unlink(tmp)
                 raise
-            tracing.set_attrs(sp, disk_bytes=len(frame))
+            tracing.set_attrs(sp, disk_bytes=sum(len(p) for p in pieces))
             with _LOCK:
                 STATS["stores"] += 1
             record_ckpt_store()
@@ -257,8 +274,44 @@ def store(batch_id, parts: dict, payload, meta: dict | None = None) -> bool:
             return False
 
 
+def _frame(rec: dict) -> list:
+    """The pieces of one envelope file, in file order.  The arrays'
+    buffers are views of the arrays' own memory: nothing is copied."""
+    out_of_band: list = []
+    header = pickle.dumps(rec, protocol=5,
+                          buffer_callback=out_of_band.append)
+    raws = [buf.raw() for buf in out_of_band]
+    table = struct.pack(f">{2 + len(raws)}Q", len(raws), len(header),
+                        *(len(raw) for raw in raws))
+    crc = 0
+    for piece in (table, header, *raws):
+        crc = zlib.crc32(piece, crc)
+    return [_MAGIC, crc.to_bytes(4, "big"), table, header, *raws]
+
+
+def _unframe(frame: bytes) -> dict:
+    """The record of one envelope file, its arrays views of `frame`;
+    raises on anything but a whole file as `_frame` laid it out."""
+    view = memoryview(frame)
+    magic, crc, count, header_len = _HEAD.unpack_from(view)
+    if magic != _MAGIC:
+        raise ValueError("bad magic")
+    if zlib.crc32(view[_CRC_FROM:]) != crc:
+        raise ValueError("torn record")
+    lengths = struct.unpack_from(f">{count}Q", view, _HEAD.size)
+    at = _HEAD.size + 8 * count
+    if at + header_len + sum(lengths) != len(view):
+        raise ValueError("torn record")
+    pieces = []
+    for length in (header_len, *lengths):
+        pieces.append(view[at:at + length])
+        at += length
+    return pickle.loads(pieces[0], buffers=pieces[1:])
+
+
 def load(batch_id, parts: dict):
-    """Load one phase envelope's payload, or None.  A torn/garbage blob
+    """Load one phase envelope's payload, or None; its arrays are
+    read-only views of the bytes read.  A torn/garbage file
     is unlinked and counted (`proof_ckpt_discards_total`) — the caller
     simply re-proves the phase; this never raises."""
     if not enabled():
@@ -273,14 +326,7 @@ def load(batch_id, parts: dict):
             STATS["misses"] += 1
         return None
     try:
-        if frame[:4] != _MAGIC or len(frame) < 16:
-            raise ValueError("bad magic")
-        crc = int.from_bytes(frame[4:8], "big")
-        length = int.from_bytes(frame[8:16], "big")
-        blob = frame[16:]
-        if len(blob) != length or zlib.crc32(blob) != crc:
-            raise ValueError("torn record")
-        rec = pickle.loads(blob)
+        rec = _unframe(frame)
         if rec.get("schema") != _SCHEMA or rec.get("parts") != parts:
             raise ValueError("key mismatch")
         with _LOCK:

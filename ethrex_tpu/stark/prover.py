@@ -325,6 +325,31 @@ def _ckpt_copy(phase: str, arrays):
     return got
 
 
+def lde_cols_from_rows(lde_rows: np.ndarray) -> np.ndarray:
+    """The (w, N) column layout from the stored (N, w) rows: the exact
+    inverse of `phase_commit`'s transpose."""
+    return np.ascontiguousarray(lde_rows.T)
+
+
+def q_lde_from_rows(q_rows: np.ndarray) -> np.ndarray:
+    """The (B, 4, N) quotient LDE from the stored (N, B * 4) rows: the
+    exact inverse of `phase_quotient`'s moveaxis + reshape."""
+    N = q_rows.shape[0]
+    return np.ascontiguousarray(np.moveaxis(q_rows.reshape(N, -1, 4), 0, -1))
+
+
+def _ckpt_rebuild(phase: str, rebuild, stored: np.ndarray) -> np.ndarray:
+    """The layout a resumed phase's consumer needs, rearranged on the
+    host from the one layout its envelope holds (u32 data moved, no
+    arithmetic: a resumed proof stays byte-identical), under its leaf
+    span (no `stage=`: it runs inside the consuming phase's stage).
+    Only a resume pays it."""
+    with tracing.span("ckpt.rebuild", phase=phase) as sp:
+        out = rebuild(stored)
+        tracing.set_attrs(sp, bytes=out.nbytes)
+    return out
+
+
 def _record_prove_throughput(cells: int, seconds: float) -> None:
     try:
         if seconds > 0:
@@ -852,7 +877,9 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
     # host numpy mirrors of the cross-phase intermediates: filled from
     # checkpoint payloads (resumed phases) or at store time (live
     # phases); the query phase reads these instead of device_get when
-    # checkpointing is on
+    # checkpointing is on.  Each large array is held (and stored) once,
+    # in the row layout the query phase gathers from; a resumed phase's
+    # consumer gets the column layout back through `_ckpt_rebuild`
     host: dict = {}
 
     # Stage spans are block_until_ready()-bounded so JAX async dispatch
@@ -868,8 +895,7 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
                           n=n, resumed=True):
             rt.note_resume("commit")
             ch.restore(commit_pay["ch"])
-            host.update(lde_cols=commit_pay["lde_cols"],
-                        lde_rows=commit_pay["lde_rows"],
+            host.update(lde_rows=commit_pay["lde_rows"],
                         levels_t=commit_pay["levels_t"])
         trace_root = host["levels_t"][-1][0]
     else:
@@ -895,11 +921,10 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
                 "trace_root": [int(x) for x in _canon(trace_root)]})
             ch.absorb_digest(trace_root)
         if store is not None:
-            lc_np, lr_np, lt_np = _ckpt_copy(
-                "commit", (lde_cols, lde_rows, tuple(levels_t)))
-            host.update(lde_cols=lc_np, lde_rows=lr_np,
-                        levels_t=list(lt_np))
-            store.store("commit", {"lde_cols": lc_np, "lde_rows": lr_np,
+            lr_np, lt_np = _ckpt_copy(
+                "commit", (lde_rows, tuple(levels_t)))
+            host.update(lde_rows=lr_np, levels_t=list(lt_np))
+            store.store("commit", {"lde_rows": lr_np,
                                    "levels_t": list(lt_np),
                                    "ch": ch.state()},
                         mesh_label=mesh_label)
@@ -915,7 +940,6 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
             rt.note_resume("quotient")
             ch.restore(quot_pay["ch"])
             host.update(chunks=quot_pay["chunks"],
-                        q_lde=quot_pay["q_lde"],
                         q_rows=quot_pay["q_rows"],
                         levels_q=quot_pay["levels_q"])
         q_root = host["levels_q"][-1][0]
@@ -926,7 +950,8 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
                 np.array([v % bb.P for (_, _, v) in bounds],
                          dtype=np.uint32))))
             if lde_cols is None:        # commit was resumed: re-place
-                lde_cols = progs.put_named("lde_cols", host["lde_cols"])
+                lde_cols = progs.put_named("lde_cols", _ckpt_rebuild(
+                    "commit", lde_cols_from_rows, host["lde_rows"]))
             alpha_dev = progs.put_small(ext.to_device(alpha))
             t_k = time.perf_counter()
             chunks, q_lde, q_rows, levels_q = rt.guard_phase(
@@ -940,12 +965,10 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
                 "quotient_root": [int(x) for x in _canon(q_root)]})
             ch.absorb_digest(q_root)
         if store is not None:
-            ck_np, ql_np, qr_np, lq_np = _ckpt_copy(
-                "quotient", (chunks, q_lde, q_rows, tuple(levels_q)))
-            host.update(chunks=ck_np, q_lde=ql_np, q_rows=qr_np,
-                        levels_q=list(lq_np))
-            store.store("quotient", {"chunks": ck_np, "q_lde": ql_np,
-                                     "q_rows": qr_np,
+            ck_np, qr_np, lq_np = _ckpt_copy(
+                "quotient", (chunks, q_rows, tuple(levels_q)))
+            host.update(chunks=ck_np, q_rows=qr_np, levels_q=list(lq_np))
+            store.store("quotient", {"chunks": ck_np, "q_rows": qr_np,
                                      "levels_q": list(lq_np),
                                      "ch": ch.state()},
                         mesh_label=mesh_label)
@@ -1018,7 +1041,8 @@ def _prove_attempt(air: Air, trace: np.ndarray, pub_inputs: list[int],
             if lde_rows is None:        # commit was resumed
                 lde_rows = progs.put_named("lde_rows", host["lde_rows"])
             if q_lde is None:           # quotient was resumed
-                q_lde = progs.put_named("q_lde", host["q_lde"])
+                q_lde = progs.put_named("q_lde", _ckpt_rebuild(
+                    "quotient", q_lde_from_rows, host["q_rows"]))
             if t_z_dev is None:         # open was resumed
                 t_z_dev = progs.put_small(jnp.asarray(host["t_z"]))
                 t_zg_dev = progs.put_small(jnp.asarray(host["t_zg"]))

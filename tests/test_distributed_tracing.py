@@ -787,12 +787,13 @@ def test_leaf_spans_count_what_they_say(leaf_batch):
     gens = {s["attrs"]["air"]: s["attrs"] for s in by_name["prove.trace_gen"]}
     assert set(gens) == set(leaf_batch["airs"])
     assert gens["TransferAir"]["width"] == 278
-    # checkpoint copies, by the arrays' shapes (Fibonacci: w=2, B=4)
+    # checkpoint copies, by the arrays' shapes (Fibonacci: w=2, B=4):
+    # one layout an array (lde_rows; chunks and q_rows) and the levels
     w, B, n = 2, 1 << SMALL.log_blowup, FIB_N
     N = n * B
     levels = (2 * N - 1) * 8 * 4
-    want = {"commit": 2 * w * N * 4 + levels,
-            "quotient": (B * n * 4 + B * 4 * N + N * B * 4) * 4 + levels,
+    want = {"commit": w * N * 4 + levels,
+            "quotient": (B * n * 4 + N * B * 4) * 4 + levels,
             "open": (2 * w + B) * 4 * 4}
     copies = by_name["prove.ckpt_copy"]
     assert len(copies) == 9

@@ -235,12 +235,33 @@ def test_memory_gate_estimate_is_the_compiled_working_set():
 # checkpoint envelope units
 # ===========================================================================
 
+PARTS = {"kind": "proof_ckpt", "job": "j", "phase": "commit"}
+
+
+def _commit_envelope(w=5, log_N=6):
+    """A commit envelope in small: one row layout, the tree's levels as
+    a nested list, the sponge snapshot."""
+    N = 1 << log_N
+    rows = RNG.integers(0, bb.P, size=(N, w), dtype=np.uint32)
+    levels = [RNG.integers(0, bb.P, size=(N >> k, 8), dtype=np.uint32)
+              for k in range(log_N + 1)]
+    return {"lde_rows": rows, "levels_t": levels,
+            "ch": {"state": list(range(16)), "absorb_pos": 3,
+                   "squeeze_pos": 0}}
+
+
 def test_checkpoint_roundtrip_torn_and_garbage(monkeypatch):
-    parts = {"kind": "proof_ckpt", "job": "j", "phase": "commit"}
-    payload = {"rows": np.arange(4, dtype=np.uint32), "ch": {"pos": 3}}
+    parts = PARTS
+    payload = _commit_envelope()
     assert ckpt.store(7, parts, payload, meta={"lease_token": "tok"})
     got = ckpt.load(7, parts)
-    assert np.array_equal(got["rows"], payload["rows"])
+    # several arrays, a nested list of levels and the sponge dict all
+    # come back equal, dtype and shape included
+    assert sorted(got) == sorted(payload) and got["ch"] == payload["ch"]
+    for want, have in zip([payload["lde_rows"]] + payload["levels_t"],
+                          [got["lde_rows"]] + got["levels_t"]):
+        assert have.dtype == want.dtype and have.shape == want.shape
+        assert np.array_equal(have, want)
     assert ckpt.STATS["stores"] == 1 and ckpt.STATS["loads"] == 1
     # different parts address a different (absent) envelope — no discard
     assert ckpt.load(7, {**parts, "phase": "open"}) is None
@@ -268,6 +289,153 @@ def test_checkpoint_roundtrip_torn_and_garbage(monkeypatch):
     assert ckpt.store(7, parts, payload) is False
     assert ckpt.load(7, parts) is None
     assert ckpt.enabled() is False
+
+
+def _header_span(path):
+    """(start, end) of the pickled header inside an envelope file."""
+    with open(path, "rb") as f:
+        _, _, count, length = ckpt._HEAD.unpack(f.read(ckpt._HEAD.size))
+    start = ckpt._HEAD.size + 8 * count
+    return start, start + length
+
+
+def _flip(path, at):
+    with open(path, "r+b") as f:
+        f.seek(at)
+        byte = f.read(1)
+        f.seek(at)
+        f.write(bytes([byte[0] ^ 0x40]))
+
+
+def _damage_truncated_in_buffer(path):
+    _, header_end = _header_span(path)
+    with open(path, "r+b") as f:        # inside lde_rows' own bytes
+        f.truncate(header_end + 100)
+
+
+def _damage_flipped_buffer_byte(path):
+    _flip(path, os.path.getsize(path) - 5)      # the last level's bytes
+
+
+def _damage_flipped_header_byte(path):
+    start, end = _header_span(path)
+    _flip(path, (start + end) // 2)
+
+
+def _damage_flipped_length(path):
+    _flip(path, ckpt._HEAD.size + 7)    # the first buffer's length
+
+
+def _damage_flipped_crc(path):
+    _flip(path, 5)
+
+
+def _damage_trailing_bytes(path):
+    with open(path, "ab") as f:
+        f.write(b"\x00" * 8)
+
+
+def _damage_garbage(path):
+    with open(path, "wb") as f:
+        f.write(os.urandom(4096))
+
+
+def _damage_empty(path):
+    with open(path, "wb"):
+        pass
+
+
+@pytest.mark.parametrize("damage", [
+    _damage_truncated_in_buffer, _damage_flipped_buffer_byte,
+    _damage_flipped_header_byte, _damage_flipped_length,
+    _damage_flipped_crc, _damage_trailing_bytes, _damage_garbage,
+    _damage_empty], ids=lambda fn: fn.__name__[len("_damage_"):])
+def test_damaged_envelope_is_discarded_never_raised(damage):
+    """Whatever happens to an envelope's bytes (the header, an array's
+    own buffer, the table of lengths), `load` unlinks it, counts it and
+    returns None; the next store lands and loads as if nothing was."""
+    payload = _commit_envelope()
+    assert ckpt.store(7, PARTS, payload)
+    path = ckpt._entry_path(7, PARTS)
+    damage(path)
+    assert ckpt.load(7, PARTS) is None
+    assert ckpt.STATS["discards"] == 1 and ckpt.STATS["loads"] == 0
+    assert not os.path.exists(path)
+    assert ckpt.store(7, PARTS, payload)
+    assert np.array_equal(ckpt.load(7, PARTS)["lde_rows"],
+                          payload["lde_rows"])
+    assert ckpt.STATS["discards"] == 1
+
+
+def test_store_never_raises_and_leaves_no_temp_file():
+    assert ckpt.store(7, {**PARTS, "phase": "open"}, {"x": 1})
+    assert ckpt.store(7, PARTS, {"f": lambda: 0}) is False   # unpicklable
+    assert ckpt.STATS["stores"] == 1
+    names = os.listdir(ckpt._batch_dir(7))
+    assert len(names) == 1 and names[0].endswith(".ckpt")
+    assert ckpt.load(7, PARTS) is None and ckpt.STATS["misses"] == 1
+
+
+def test_commit_envelope_is_one_layout_written_from_its_buffers():
+    """The file is the row layout and the levels, once each, plus a
+    header that holds no array data: an array stored twice, or pickled
+    in band, would show in its size."""
+    payload = _commit_envelope(w=278, log_N=10)
+    arrays = [payload["lde_rows"]] + payload["levels_t"]
+    assert ckpt.store(7, PARTS, payload, meta={"mesh": "none"})
+    size = os.path.getsize(ckpt._entry_path(7, PARTS))
+    data = sum(a.nbytes for a in arrays)
+    assert data == (278 + 2 * 8) * 1024 * 4 - 8 * 4
+    assert 0 < size - data < 4096
+    start, end = _header_span(ckpt._entry_path(7, PARTS))
+    assert start == ckpt._HEAD.size + 8 * len(arrays)
+    assert size == end + data
+    # the store reports what it wrote, and the ack removes the same
+    assert ckpt.complete(7) == size
+
+
+# the cells' trace widths (TransferAir, StateUpdateAir, TokenAir, the
+# sponge binding STARK) at log_blowup 3, in small
+@pytest.mark.parametrize("w,log_n", [(278, 5), (115, 6), (117, 4), (24, 3)])
+def test_resume_rebuilds_invert_the_phase_layouts(w, log_n):
+    """`lde_rows -> lde_cols` and `q_rows -> q_lde` are the exact
+    inverses of `phase_commit`'s transpose and `phase_quotient`'s
+    moveaxis + reshape: u32 data moved, none changed."""
+    B, N = 8, 8 << log_n
+    lde_cols = RNG.integers(0, bb.P, size=(w, N), dtype=np.uint32)
+    lde_rows = np.ascontiguousarray(lde_cols.T)         # phase_commit
+    back = prover.lde_cols_from_rows(lde_rows)
+    assert back.dtype == np.uint32 and back.flags.c_contiguous
+    assert np.array_equal(back, lde_cols)
+    q_lde = RNG.integers(0, bb.P, size=(B, 4, N), dtype=np.uint32)
+    q_rows = np.ascontiguousarray(                      # phase_quotient
+        np.moveaxis(q_lde, -1, 0).reshape(N, B * 4))
+    back = prover.q_lde_from_rows(q_rows)
+    assert back.dtype == np.uint32 and back.flags.c_contiguous
+    assert back.shape == (B, 4, N) and np.array_equal(back, q_lde)
+    # through an envelope: the rebuilt layout of what `load` hands back
+    assert ckpt.store(7, PARTS, {"lde_rows": lde_rows, "q_rows": q_rows})
+    got = ckpt.load(7, PARTS)
+    assert np.array_equal(prover.lde_cols_from_rows(got["lde_rows"]),
+                          lde_cols)
+    assert np.array_equal(prover.q_lde_from_rows(got["q_rows"]), q_lde)
+
+
+def test_rebuild_is_a_leaf_span_with_phase_and_bytes():
+    """A resume's rebuild is its own leaf under the consuming phase's
+    stage span, with no `stage=` of its own (a stage may not run inside
+    another stage of its component)."""
+    from ethrex_tpu.utils import tracing
+
+    q_rows = np.arange(64 * 32, dtype=np.uint32).reshape(64, 32)
+    with tracing.span("prove.fri_fold", stage="fri_fold") as root:
+        out = prover._ckpt_rebuild("quotient", prover.q_lde_from_rows,
+                                   q_rows)
+    spans = tracing.TRACER.get_trace(root.trace_id)["spans"]
+    (leaf,) = [sp for sp in spans if sp["name"] == "ckpt.rebuild"]
+    assert leaf["parentId"] == root.span_id
+    assert leaf["attrs"] == {"phase": "quotient", "bytes": out.nbytes}
+    assert out.shape == (8, 4, 64)
 
 
 def test_phase_store_requires_batch_context():
