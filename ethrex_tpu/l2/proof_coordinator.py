@@ -26,6 +26,13 @@ no-op storage), extended with the resilience layer:
     `max_lease_lifetime` past first assignment, so a prover whose prove
     call hangs (rather than crashes) is still eventually reassigned and
     counted as a failure instead of pinning the batch forever;
+  * lease reclaim — a prover that restarts on the disk its dead
+    incarnation left phase checkpoints on presents the lease token they
+    record (`reclaim` on InputRequest); where that is the batch's
+    current primary token the lease moves to it under a new token, with
+    no failure charged and the lifetime cap still anchored at the first
+    assignment, instead of the batch waiting out `lease_timeout` while
+    the restarted prover is handed the next one;
 
 and, on top of the lease substrate, a **fleet scheduler**
 (docs/AGGREGATION.md) replacing the original FCFS scan:
@@ -133,6 +140,7 @@ class ProofCoordinator:
         self.batch_traces: dict[int, str] = {}
         self.quarantined: set[int] = set()
         self.reassignments_total = 0
+        self.reclaims_total = 0
         self.heartbeats_total = 0
         self.rejected_submits_total = 0
         self.unsolicited_submits_total = 0
@@ -345,10 +353,22 @@ class ProofCoordinator:
         return self.assign(prover_type, prover_id)[0]
 
     def assign(self, prover_type: str, prover_id: str | None = None,
-               warm: bool | None = None
+               warm: bool | None = None, reclaim: dict | None = None
                ) -> tuple[int | None, str | None]:
-        """One scheduling decision: returns (batch, lease_token) or
-        (None, None).
+        """One scheduling decision: (batch, lease_token) or (None,
+        None); `_schedule` without its third answer."""
+        return self._schedule(prover_type, prover_id, warm, reclaim)[:2]
+
+    def _schedule(self, prover_type: str, prover_id: str | None,
+                  warm: bool | None, reclaim: dict | None
+                  ) -> tuple[int | None, str | None, bool]:
+        """One scheduling decision: returns (batch, lease_token,
+        reclaimed) or (None, None, False).
+
+        `reclaim` ({batch_id, lease_token}) is a restarted prover's
+        claim to the lease its dead incarnation held: granted by
+        `_reclaim` where the token is that lease's, else ignored and
+        the request is scheduled like any other.
 
         Scans batches with a stored prover input and no proof of this
         type (reference: next_batch_to_assign:149-215).  Expired leases
@@ -364,7 +384,7 @@ class ProofCoordinator:
         dedup'd at submit time."""
         faults.inject("coordinator.schedule")
         if prover_type not in self._allowed_types():
-            return None, None
+            return None, None, False
         now = self._now()
         with self.lock:
             if prover_id is not None:
@@ -376,6 +396,11 @@ class ProofCoordinator:
                     st["warm"] = warm
                     if warm:
                         st["cold_deferrals"] = 0
+            if reclaim is not None:
+                granted = self._reclaim(reclaim, prover_type, prover_id,
+                                        now, warm)
+                if granted is not None:
+                    return (*granted, True)
             candidates = sorted({
                 num for (num, ver) in self.rollup.prover_inputs
                 if ver == self.commit_hash
@@ -408,17 +433,17 @@ class ProofCoordinator:
             if unleased:
                 if self._defer_cold(prover_id, warm, len(unleased), now):
                     self._report_queue_depth()
-                    return None, None
+                    return None, None, False
                 num = self._pick_unleased(unleased, prover_id)
                 token = self._grant(num, prover_type, prover_id, now,
                                     warm)
                 self.queue_depth -= 1   # the grant is no longer waiting
                 self._report_queue_depth()
-                return num, token
+                return num, token, False
             granted = self._maybe_hedge(leased, prover_type, prover_id,
                                         now, warm)
             self._report_queue_depth()
-            return granted
+            return (*granted, False)
 
     def _defer_cold(self, prover_id: str | None, warm: bool | None,
                     queue_len: int, now: float) -> bool:
@@ -467,6 +492,60 @@ class ProofCoordinator:
         self.lease_holders[key] = prover_id
         self.lease_warm[key] = warm
         return token
+
+    def _reclaim(self, reclaim: dict, prover_type: str,
+                 prover_id: str | None, now: float,
+                 warm: bool | None) -> tuple[int, str] | None:
+        """Move a live primary lease to the restarted prover that
+        presents its token: a new token, the deadline reset, the holder
+        replaced.  Nothing is counted against the batch (its prover
+        died, the batch did nothing wrong) and `assigned_at` stays, so
+        `max_lease_lifetime` still cuts off a prover that crash-loops
+        on one batch.  None in every other case: a proven or
+        quarantined batch, a lapsed or reassigned lease, a wrong, stale
+        or hedge token.  Caller holds self.lock."""
+        from ..utils.metrics import record_lease_reclaim
+
+        num, token = reclaim.get("batch_id"), reclaim.get("lease_token")
+        key = (num, prover_type)
+        if not isinstance(num, int) or not isinstance(token, str) \
+                or num in self.quarantined \
+                or prover_type not in self.needed_types \
+                or self.assignments.get(key, 0.0) <= now \
+                or not secrets.compare_digest(
+                    token.encode(), self.lease_tokens.get(key, "").encode()) \
+                or self.rollup.get_proof(num, prover_type) is not None:
+            return None
+        hard = self.assigned_at.get(key, now) + self.max_lease_lifetime
+        if now >= hard:
+            return None     # lifetime spent: the lease lapses as it stands
+        fresh = secrets.token_hex(16)
+        self.assignments[key] = min(now + self.lease_timeout, hard)
+        self.lease_tokens[key] = fresh
+        self.lease_holders[key] = prover_id
+        self.lease_warm[key] = warm
+        # a resuming prover is making progress: the straggler clock
+        # starts again here, as on a phase transition
+        self.lease_phase[key] = ("reclaimed", now)
+        self.reclaims_total += 1
+        record_lease_reclaim()
+        self._note_event("lease-reclaimed", num, prover_type)
+        log.info("batch %d/%s reclaimed by %s on its old lease token",
+                 num, prover_type, prover_id or "<anon>")
+        return num, fresh
+
+    def _reclaim_outcome(self, reclaim: dict, prover_type: str,
+                         granted: bool) -> str:
+        """What an InputResponse tells a prover about the reclaim it
+        presented: its envelopes are garbage once the batch is proven,
+        worth keeping otherwise (a later ordinary lease resumes)."""
+        if granted:
+            return "granted"
+        num = reclaim.get("batch_id")
+        if isinstance(num, int) \
+                and self.rollup.get_proof(num, prover_type) is not None:
+            return "proven"
+        return "refused"
 
     def _maybe_hedge(self, leased: list[int], prover_type: str,
                      prover_id: str | None, now: float,
@@ -842,11 +921,17 @@ class ProofCoordinator:
             if prover_type not in self._allowed_types():
                 return {"type": protocol.TYPE_NOT_NEEDED}
             warm = msg.get("warm")
-            batch, token = self.assign(
+            reclaim = msg.get("reclaim")
+            if not isinstance(reclaim, dict):
+                reclaim = None
+            batch, token, reclaimed = self._schedule(
                 prover_type, msg.get("prover_id"),
-                warm=warm if isinstance(warm, bool) else None)
+                warm if isinstance(warm, bool) else None, reclaim)
+            told = {} if reclaim is None else {
+                "reclaim": self._reclaim_outcome(reclaim, prover_type,
+                                                 reclaimed)}
             if batch is None:
-                return {"type": protocol.TYPE_NOT_NEEDED}
+                return {"type": protocol.TYPE_NOT_NEEDED, **told}
             trace_id = self.trace_for_batch(batch)
             assign_span = None
             with tracing.trace_context(trace_id):
@@ -860,7 +945,7 @@ class ProofCoordinator:
             return {"type": protocol.INPUT_RESPONSE, "batch_id": batch,
                     "input": program_input, "format": self.proof_format,
                     "lease_token": token,
-                    "trace_id": trace_id, "span_id": assign_span}
+                    "trace_id": trace_id, "span_id": assign_span, **told}
         if mtype == protocol.HEARTBEAT:
             return self._handle_heartbeat(msg)
         if mtype == protocol.PROOF_SUBMIT:
@@ -876,6 +961,7 @@ class ProofCoordinator:
                     1 for d in self.assignments.values()
                     if d > self._now()),
                 "reassignments": self.reassignments_total,
+                "reclaims": self.reclaims_total,
                 "heartbeats": self.heartbeats_total,
                 "rejectedSubmits": self.rejected_submits_total,
                 "unsolicitedSubmits": self.unsolicited_submits_total,

@@ -21,7 +21,11 @@ are deliberately recorded as envelope metadata, NOT key material:
 proofs are bit-identical across mesh layouts, so the degradation
 ladder (prover/runtime_errors) must be able to resume a phase prefix
 written at mesh=2x4 on a single device, and a restarted client always
-holds a fresh token for the same batch.
+holds a fresh token for the same batch.  The token an envelope
+records is also how the restarted client gets that lease back without
+waiting out the dead one: `in_flight()` reads it off the disk and the
+client presents it to the coordinator (docs/PROVER_RESILIENCE.md
+"Reclaiming a lease").
 
 What an envelope holds: every large array ONCE, in the row layout the
 query phase gathers from (`commit`: `lde_rows`, the trace tree's
@@ -60,6 +64,7 @@ import struct
 import tempfile
 import threading
 import time
+import typing
 import zlib
 
 from ..utils import tracing
@@ -250,8 +255,9 @@ def store(batch_id, parts: dict, payload, meta: dict | None = None) -> bool:
         try:
             tracing.set_attrs(sp, phase=parts.get("phase"),
                               job=parts.get("job"))
-            pieces = _frame({"schema": _SCHEMA, "parts": parts,
-                             "meta": dict(meta or {}), "payload": payload})
+            pieces = _frame({"schema": _SCHEMA, "batch_id": batch_id,
+                             "parts": parts, "meta": dict(meta or {}),
+                             "payload": payload})
             path = _entry_path(batch_id, parts)
             os.makedirs(os.path.dirname(path), exist_ok=True)
             fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path),
@@ -339,12 +345,78 @@ def load(batch_id, parts: dict):
                             disk_bytes=len(frame))
         return rec["payload"]
     except Exception:
-        with contextlib.suppress(OSError):
-            os.unlink(path)
-        with _LOCK:
-            STATS["discards"] += 1
-        record_ckpt_discard()
+        _discard(path)
         return None
+
+
+def _discard(path: str) -> None:
+    """An envelope nothing can resume from (torn, garbage, another
+    code's): unlinked and counted."""
+    with contextlib.suppress(OSError):
+        os.unlink(path)
+    with _LOCK:
+        STATS["discards"] += 1
+    record_ckpt_discard()
+
+
+class InFlight(typing.NamedTuple):
+    """A batch this disk holds envelopes for: the batch a prover that
+    died here was proving."""
+
+    batch_id: int
+    lease_token: str        # of the attempt that wrote the newest envelope
+    envelopes: int
+    disk_bytes: int
+
+
+def in_flight() -> list[InFlight]:
+    """The batches with envelopes under `checkpoint_dir()` that this
+    code can resume, oldest batch first, each with the lease token its
+    newest envelope records (a batch reclaimed once and killed again
+    carries two tokens; the coordinator knows the later one).  Per
+    batch directory the envelopes are read newest first until one is
+    whole and addressed by this code's fingerprint; the ones read
+    before it (torn, garbage, or written by other code, which `load`
+    could never find) are unlinked and counted as discards, older ones
+    are left for `load` to judge.  Never raises."""
+    if not enabled():
+        return []
+    found = []
+    try:
+        dirs = [d for d in os.scandir(checkpoint_dir())
+                if d.name.startswith("batch_") and d.is_dir()]
+    except OSError:
+        return []
+    for d in dirs:
+        try:
+            entries = [(st.st_mtime_ns, st.st_size, e.path)
+                       for e in os.scandir(d.path)
+                       if e.name.endswith(_SUFFIX)
+                       for st in (e.stat(),)]
+        except OSError:
+            continue
+        entries.sort(reverse=True)
+        for at, (_, _, path) in enumerate(entries):
+            try:
+                with open(path, "rb") as f:
+                    rec = _unframe(f.read())
+                batch_id = rec["batch_id"]
+                token = rec["meta"]["lease_token"]
+                if rec.get("schema") != _SCHEMA or not os.path.samefile(
+                        _entry_path(batch_id, rec["parts"]), path):
+                    raise ValueError("another code's envelope")
+            except Exception:
+                _discard(path)
+                continue
+            if isinstance(batch_id, int) and isinstance(token, str):
+                found.append(InFlight(
+                    batch_id, token, len(entries) - at,
+                    sum(size for _, size, _ in entries[at:])))
+            break
+        else:
+            with contextlib.suppress(OSError):
+                os.rmdir(d.path)        # nothing of it was left
+    return sorted(found)
 
 
 def complete(batch_id) -> int:

@@ -13,6 +13,11 @@ for real fleets:
   * submit over a fresh connection — the socket that carried the input
     request can die during a multi-minute proof without losing the
     finished proof;
+  * lease reclaim at start — a client that finds phase checkpoints of a
+    batch on its disk (its dead incarnation's) presents the lease token
+    they record with its first request and, where the coordinator still
+    holds that lease, is handed the batch back and resumes it, instead
+    of being handed the next batch while this one waits out its lease;
   * background pre-warm before the first InputRequest — the backend's
     AOT kernels are hydrated from the on-disk executable cache
     (utils/exec_cache) while the client starts polling, and every
@@ -160,6 +165,12 @@ class ProverClient:
         # came back empty since
         self._idle_since: float | None = None
         self._idle_polls = 0
+        # the batches this disk holds phase checkpoints for, still to be
+        # presented to each endpoint (any of them may hold the lease):
+        # read once, at the first poll; one rides each request until
+        # none is left, and none is ever added
+        self._reclaims: dict[tuple[str, int],
+                             list[ckpt_mod.InFlight]] | None = None
         # pre-warm: hydrate the backend's AOT executables from the
         # on-disk cache in the background, so the first assignment can
         # run at steady-state wall; `warm` rides every InputRequest
@@ -264,18 +275,52 @@ class ProverClient:
                 self._record_success(ep, st)
         return proven
 
+    def _next_reclaim(self, ep) -> ckpt_mod.InFlight | None:
+        if self._reclaims is None:
+            found = ckpt_mod.in_flight()
+            self._reclaims = {e: list(found) for e in self.endpoints}
+        pending = self._reclaims.get(ep)
+        return pending[0] if pending else None
+
+    def _reclaim_answered(self, ep, mine: ckpt_mod.InFlight,
+                          resp: dict) -> bool:
+        """What the coordinator at `ep` said of the lease this client
+        presented (asked once, whatever the answer): granted (the
+        response is that batch, no other endpoint need be asked), or the
+        batch is proven and its envelopes are garbage; refused, or a
+        coordinator that does not know the field, leaves them for a
+        later ordinary lease on the batch to resume."""
+        outcome = resp.get("reclaim")
+        granted = outcome == "granted" \
+            and resp.get("batch_id") == mine.batch_id
+        for at, pending in self._reclaims.items():
+            if at == ep or granted or outcome == "proven":
+                pending[:] = [r for r in pending
+                              if r.batch_id != mine.batch_id]
+        if outcome == "proven":
+            ckpt_mod.complete(mine.batch_id)
+        return granted
+
     def _poll_endpoint(self, host: str, port: int) -> int:
         # connection 1: request work (closed before the proof starts)
         t_request = time.time()
+        request = {
+            "type": protocol.INPUT_REQUEST,
+            "commit_hash": self.commit_hash,
+            "prover_type": self.backend.prover_type,
+            "prover_id": self.prover_id,
+            "warm": self.warm,
+        }
+        mine = self._next_reclaim((host, port))
+        if mine is not None:
+            request["reclaim"] = {"batch_id": mine.batch_id,
+                                  "lease_token": mine.lease_token}
         with socket.create_connection((host, port), timeout=30) as sock:
-            protocol.send_msg(sock, {
-                "type": protocol.INPUT_REQUEST,
-                "commit_hash": self.commit_hash,
-                "prover_type": self.backend.prover_type,
-                "prover_id": self.prover_id,
-                "warm": self.warm,
-            })
+            protocol.send_msg(sock, request)
             resp = protocol.recv_msg(sock)
+        t_answered = time.time()
+        reclaimed = mine is not None and self._reclaim_answered(
+            (host, port), mine, resp)
         rtype = resp.get("type")
         if rtype == protocol.VERSION_MISMATCH:
             raise ValueError(
@@ -295,17 +340,28 @@ class ProverClient:
                     "prover.idle", self._idle_since,
                     t_request - self._idle_since,
                     polls=self._idle_polls, batch=resp["batch_id"])
+            if mine is not None:
+                # the reclaim's share of the fetch: the look at the disk,
+                # the request and the coordinator's answer; what is left
+                # of the fetch is the decoding of the input
+                tracing.record_span(
+                    "prover.reclaim", t_request, t_answered - t_request,
+                    batch=mine.batch_id, granted=reclaimed,
+                    envelopes=mine.envelopes, disk_bytes=mine.disk_bytes)
+                t_request = t_answered
             tracing.record_span(
                 "prover.fetch_input", t_request, t_fetched - t_request,
                 batch=resp["batch_id"])
         try:
-            return self._prove_and_submit(host, port, resp, program_input)
+            return self._prove_and_submit(host, port, resp, program_input,
+                                          attempt=2 if reclaimed else 1)
         finally:
             self._idle_since = time.time()
             self._idle_polls = 0
 
     def _prove_and_submit(self, host: str, port: int, resp: dict,
-                          program_input: ProgramInput) -> int:
+                          program_input: ProgramInput,
+                          attempt: int = 1) -> int:
         batch_id = resp["batch_id"]
         lease_token = resp.get("lease_token")
         # continue the trace the coordinator opened at assignment, so the
@@ -334,7 +390,8 @@ class ProverClient:
             with tracing.trace_context(trace_id, parent_span) as tid:
                 try:
                     with tracing.span("prover.prove", batch=batch_id,
-                                      backend=self.backend.prover_type):
+                                      backend=self.backend.prover_type,
+                                      attempt=attempt):
                         faults.inject("backend.prove")
                         proof = self.backend.prove(program_input,
                                                    resp["format"])

@@ -50,10 +50,23 @@ from ..utils import faults
 # non-finite/out-of-field outputs in the named phase, the coordinator
 # quarantines it immediately (token-gated like every lease mutation)
 # instead of burning its failure budget on doomed retries.
+# InputRequest MAY carry `reclaim` ({batch_id, lease_token}): a prover
+# that starts and finds phase checkpoints of a batch on its disk
+# presents the lease token they record.  The request is otherwise an
+# ordinary one.  Where that token is the batch's current primary lease,
+# the coordinator answers with that batch under a new token (the dead
+# holder's lease moves to the restarted prover; docs/
+# PROVER_RESILIENCE.md "Reclaiming a lease") and says so with
+# `reclaim: "granted"` on the response; in every other case it serves
+# the request as if the field were absent and answers `reclaim:
+# "proven"` (the batch has its proof: the envelopes are garbage) or
+# `"refused"`.  Old coordinators ignore the field and old provers never
+# send it; the protocol version does not change.
 INPUT_REQUEST = "InputRequest"          # {commit_hash, prover_type
-#                                          [, prover_id] [, warm]}
+#                                          [, prover_id] [, warm]
+#                                          [, reclaim]}
 INPUT_RESPONSE = "InputResponse"        # {batch_id, input, format,
-#                                          lease_token}
+#                                          lease_token [, reclaim]}
 VERSION_MISMATCH = "VersionMismatch"    # {expected}
 TYPE_NOT_NEEDED = "ProverTypeNotNeeded"
 PROOF_SUBMIT = "ProofSubmit"            # {batch_id, prover_type, proof,

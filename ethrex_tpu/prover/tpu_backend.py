@@ -579,9 +579,17 @@ class TpuBackend(ProverBackend):
         # profiler.capture is a no-op unless --profile-dir opted in to
         # device tracing
         t0 = _time.perf_counter()
-        with tracing.span("backend.prove", format=proof_format):
-            with perf_profiler.capture("prove"):
-                out = self._prove_impl(program_input, proof_format)
+        resumes0 = rt.STATS["phase_resumes"]
+        with tracing.span("backend.prove", format=proof_format) as sp:
+            try:
+                with perf_profiler.capture("prove"):
+                    out = self._prove_impl(program_input, proof_format)
+            finally:
+                # phases loaded from checkpoints in place of proved: 0
+                # in a first attempt, and what a killed one had landed
+                # in the attempt that resumes it
+                tracing.set_attrs(sp, resumed_phases=rt.STATS[
+                    "phase_resumes"] - resumes0)
         try:
             from ..utils.metrics import record_proof_wall
 

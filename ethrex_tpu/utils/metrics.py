@@ -282,6 +282,12 @@ def record_reassignment(batch_number: int, prover_type: str):
                 "rejected proof")
 
 
+def record_lease_reclaim():
+    METRICS.inc("prover_lease_reclaims_total", 1,
+                "Leases moved to a restarted prover that presented the "
+                "token its phase checkpoints record (no failure counted)")
+
+
 def record_quarantine(count: int):
     METRICS.set("quarantined_batches", count,
                 "Batches quarantined off their primary prover type onto "
