@@ -136,7 +136,14 @@ class Poseidon2Air(Air):
 
 
 def generate_trace(limbs: list[int]) -> np.ndarray:
-    """Round-by-round permutation states for P(limbs), padded to 32 rows."""
+    """Round-by-round permutation states for P(limbs), padded to 32 rows
+    (the native engine's rows, or Python's where it did not load)."""
+    rows = p2.native_trace(limbs)
+    return _generate_trace_py(limbs) if rows is None else rows
+
+
+def _generate_trace_py(limbs: list[int]) -> np.ndarray:
+    """generate_trace in Python ints: the fallback, and the tests' oracle."""
     assert len(limbs) == 16
     trace = np.zeros((PERIOD, 16), dtype=np.uint32)
     s = p2._external_linear_ref([int(v) % bb.P for v in limbs])

@@ -1,8 +1,9 @@
 """Fiat-Shamir transcript: duplex Poseidon2 sponge over BabyBear (host side).
 
 The transcript is inherently sequential (a few dozen absorb/sample calls per
-proof), so it runs on the host with the reference permutation; prover and
-verifier share this exact code, which is what makes the protocol
+proof), so it runs on the host with the host permutation (`permute_ref`:
+native/poseidon2.c, Python where it cannot load; the same bits either way);
+prover and verifier share this exact code, which is what makes the protocol
 non-interactive and deterministic.
 """
 
@@ -100,8 +101,8 @@ class Challenger:
     # keccak256(seed || nonce) having `bits` leading zero bits is found by
     # the prover and bound into the transcript before query sampling.  The
     # seed is squeezed from the sponge, so the nonce commits to everything
-    # absorbed so far; keccak (C extension) keeps the 2^bits-hash search
-    # off the slow Poseidon2 host permutation.
+    # absorbed so far; the 2^bits-hash search runs on keccak (C
+    # extension), not on the Poseidon2 sponge.
 
     def _pow_seed(self) -> bytes:
         return b"".join(int(self.sample()).to_bytes(4, "little")

@@ -147,7 +147,7 @@ def open_paths_mont(levels, idxs) -> np.ndarray:
 
 def verify_path(root_digest, index: int, leaf_digest, path,
                 depth: int | None = None) -> bool:
-    """Host-side verification with the numpy reference permutation.
+    """Host-side verification with the host permutation (`permute_ref`).
 
     Inputs are device digests in Montgomery form; since the permutation is
     built only from adds and mont-muls by mont-form constants, it commutes

@@ -18,11 +18,21 @@ chain from the Poseidon2 paper.  Internal layer: M_I = J + diag(mu)
 
 Everything is element-wise uint32 VPU work; a batch of states of shape
 (B, 16) vectorizes perfectly and XLA fuses the whole permutation.
+
+The host permutation (`permute_ref`, and the AIR trace rows through
+`native_trace`) runs in native/poseidon2.c, compiled on first use and
+loaded with ctypes as crypto/keccak.py loads its engine; the Python
+bodies (`_permute_py`, `models/poseidon2_air._generate_trace_py`) are the
+fallback where no toolchain is, and the oracle the tests hold it to.
 """
 
 from __future__ import annotations
 
+import ctypes
 import hashlib
+import os
+import subprocess
+import threading
 
 import numpy as np
 import jax.numpy as jnp
@@ -86,9 +96,98 @@ _DIAG_MU_M = bb.to_mont_host(DIAG_MU)
 
 
 # ---------------------------------------------------------------------------
-# Reference implementation (host, Python ints) — used by tests and the
-# Fiat-Shamir challenger
+# Host permutation: native/poseidon2.c through ctypes, Python ints where it
+# cannot load.  Used by the Fiat-Shamir challenger, the host Merkle and
+# sponge digests, every AIR's trace generator and the verifier.
 # ---------------------------------------------------------------------------
+
+_TRACE_ROWS = 32     # rows native_trace writes: 22 round states, 10 copies
+_FINAL_ROW = ROUNDS_F + ROUNDS_P     # 21: the permutation's output
+
+_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
+_SO_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "libposeidon2.so"))
+_SRC_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "poseidon2.c"))
+
+_lib = None
+_lock = threading.Lock()
+_State = ctypes.c_uint32 * WIDTH
+
+
+def _load_native():
+    global _lib
+    if _lib is not None:  # lock-free fast path once resolved (hot callers)
+        return _lib
+    with _lock:
+        if _lib is not None:
+            return _lib
+
+        def build():
+            # into a file of this process's own, then renamed: a process
+            # that loads the library never sees another one's half-written
+            tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
+            subprocess.run(
+                ["gcc", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC_PATH],
+                check=True, capture_output=True,
+            )
+            os.replace(tmp, _SO_PATH)
+
+        def load():
+            lib = ctypes.CDLL(_SO_PATH)
+            lib.p2_init.argtypes = [ctypes.c_void_p] * 3
+            lib.p2_init.restype = None
+            lib.p2_trace.argtypes = [ctypes.c_void_p] * 2
+            lib.p2_trace.restype = None
+            # the constants stay defined once, above: C keeps a copy
+            consts = [np.ascontiguousarray(c, dtype=np.uint32)
+                      for c in (EXT_RC, INT_RC, DIAG_MU)]
+            lib.p2_init(*(c.ctypes.data for c in consts))
+            return lib
+
+        try:
+            if not os.path.exists(_SO_PATH) or (
+                os.path.getmtime(_SRC_PATH) > os.path.getmtime(_SO_PATH)
+            ):
+                build()
+            try:
+                _lib = load()
+            except OSError:
+                # stale/foreign binary (different arch) — rebuild once
+                build()
+                _lib = load()
+        except (OSError, subprocess.CalledProcessError):
+            _lib = False  # sentinel: fall back to Python
+        return _lib
+
+
+def available() -> bool:
+    """True when the native Poseidon2 engine loaded (every native wrapper
+    exposes this probe; lint-enforced in tests/test_tooling.py)."""
+    return bool(_load_native())
+
+
+def native_trace(limbs) -> np.ndarray | None:
+    """The permutation's (32, 16) uint32 round rows of P(limbs) from the
+    native engine (row 0 = M_E(limbs), row 21 = P(limbs), rows 22-31
+    copies), or None where it did not load."""
+    lib = _load_native()
+    if not lib:
+        return None
+    s = [int(x) % bb.P for x in limbs]
+    if len(s) != WIDTH:
+        raise ValueError(f"Poseidon2 state has {len(s)} limbs, not {WIDTH}")
+    rows = np.empty((_TRACE_ROWS, WIDTH), dtype=np.uint32)
+    lib.p2_trace(_State(*s), rows.ctypes.data)
+    return rows
+
+
+def permute_ref(state):
+    """Poseidon2 on a length-16 list/array of ints; a list of canonical
+    ints back."""
+    rows = native_trace(state)
+    if rows is None:
+        return _permute_py(state)
+    return rows[_FINAL_ROW].tolist()
+
 
 def _sbox_ref(x: int) -> int:
     x2 = (x * x) % bb.P
@@ -117,8 +216,9 @@ def _external_linear_ref(state):
     return out
 
 
-def permute_ref(state):
-    """Reference Poseidon2 on a length-16 list/array of canonical ints."""
+def _permute_py(state):
+    """Poseidon2 in Python ints: permute_ref where the native engine did
+    not load, and its oracle in the tests."""
     s = [int(x) % bb.P for x in state]
     assert len(s) == WIDTH
     s = _external_linear_ref(s)

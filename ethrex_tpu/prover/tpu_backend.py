@@ -84,6 +84,7 @@ from ..guest.execution import ProgramInput, execution_program
 from ..models import poseidon2_air as pair
 from ..models import state_update_air as sua
 from ..ops import babybear as bb
+from ..ops import poseidon2 as p2
 from ..stark import prover as stark_prover
 from ..stark import verifier as stark_verifier
 from ..stark.prover import StarkParams
@@ -393,10 +394,18 @@ def expected_vm_mode(program_input: ProgramInput) -> str:
                                   output.initial_state_root)
 
 
+def _p2_path() -> str:
+    """Which host Poseidon2 ran (attribute `p2` of `prove.trace_gen` and
+    `prove.vm_batch`): a fallback to Python must not pass for a
+    regression of unknown cause."""
+    return "native" if p2.available() else "python"
+
+
 def _traced_gen(air_name: str, generate, *args):
     """A job's trace generation (host numpy) under its leaf span.  No
     `stage=`: it runs inside the job's own stage span."""
-    with tracing.span("prove.trace_gen", air=air_name) as sp:
+    with tracing.span("prove.trace_gen", air=air_name,
+                      p2=_p2_path()) as sp:
         trace = generate(*args)
         tracing.set_attrs(sp, rows=int(trace.shape[0]),
                           width=int(trace.shape[1]))
@@ -761,6 +770,7 @@ class TpuBackend(ProverBackend):
         tracing.record_span(
             "prove.vm_batch", vmb_wall0, vmb_seconds,
             mode="claimed" if vm_batch is None else _mode_of(vm_batch),
+            p2=_p2_path(),
             txs=sum(len(b.body.transactions)
                     for b in program_input.blocks),
             tok_calls=0 if vm_batch is None else len(vm_batch.tok_segs),

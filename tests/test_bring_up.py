@@ -357,8 +357,8 @@ def test_prove_asks_ahead_for_every_air_of_the_batch(monkeypatch):
     assert warmed == [16384]
     # 6 senders' rows, 6 coinbase rows and the token's; 2 slots a call
     assert recorded["prove.vm_batch"] == {
-        "mode": "token", "txs": 6, "tok_calls": 6, "acct_rows": 13,
-        "slot_rows": 12}
+        "mode": "token", "p2": tpu_backend._p2_path(), "txs": 6,
+        "tok_calls": 6, "acct_rows": 13, "slot_rows": 12}
 
 
 @pytest.mark.parametrize("event, key", [

@@ -650,9 +650,9 @@ LEAF_SPANS = {
     "prover.idle": ("polls", "batch"),
     "prover.fetch_input": ("batch",),
     "prover.ckpt_complete": ("disk_bytes",),
-    "prove.vm_batch": (),
+    "prove.vm_batch": ("p2",),
     "prove.compile_ahead": (),
-    "prove.trace_gen": ("air", "rows", "width"),
+    "prove.trace_gen": ("air", "rows", "width", "p2"),
     "prove.deep": (),
     "prove.ckpt_copy": ("phase", "d2h_bytes"),
     "ckpt.store": ("phase", "job", "disk_bytes"),
@@ -771,7 +771,7 @@ def test_batch_trace_has_leaf_span_with_attributes(leaf_batch, name):
         attrs = s.get("attrs") or {}
         for key in LEAF_SPANS[name]:
             assert key in attrs, f"{name} lacks {key}: {attrs}"
-            if key not in ("air", "phase", "job"):
+            if key not in ("air", "phase", "job", "p2"):
                 assert isinstance(attrs[key], (int, float)), (name, key)
         assert s["seconds"] >= 0 and s["status"] == "ok"
 

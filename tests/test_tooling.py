@@ -293,6 +293,7 @@ def test_every_native_source_has_probed_fallback():
         "keccak.c": "ethrex_tpu.crypto.keccak",
         "kvstore.cpp": "ethrex_tpu.storage.persistent",
         "mpt.cpp": "ethrex_tpu.trie.native_mpt",
+        "poseidon2.c": "ethrex_tpu.ops.poseidon2",
         "secp256k1.c": "ethrex_tpu.crypto.native_secp256k1",
     }
     native_dir = pathlib.Path(ethrex_tpu.__file__).parent.parent / "native"
