@@ -561,6 +561,7 @@ def start_l2_stack(args):
     cfg = SequencerConfig(
         block_time=args.block_time or 1.0,
         commit_interval=args.commit_interval,
+        batch_gas_limit=getattr(args, "batch_gas_limit", None),
         needed_prover_types=prover_types,
         ha_role=ha_role,
         leader_lease=getattr(args, "leader_lease", 3.0),
@@ -688,6 +689,15 @@ def build_parser() -> argparse.ArgumentParser:
     p_l2.add_argument("--commit-interval", type=float,
                       default=float(_env("COMMIT_INTERVAL", "2.0")),
                       help="seconds between batch commits")
+    batch_gas = _env("COMMITTER_BATCH_GAS_LIMIT")
+    p_l2.add_argument("--committer.batch-gas-limit", dest="batch_gas_limit",
+                      type=int,
+                      default=int(batch_gas) if batch_gas else None,
+                      help="most gas one batch may hold: the committer "
+                           "seals a batch at the last whole block within "
+                           "it (a lone block over it is a batch of its "
+                           "own) and leaves the rest for its next tick; "
+                           "default: every block up to the head")
     p_l2.add_argument("--l1.url", dest="l1_url",
                       default=_env("L1_URL"),
                       help="L1 JSON-RPC endpoint (omit for dev L1)")

@@ -29,10 +29,14 @@ def _load_native():
         if _lib is not None:
             return _lib
         def build():
+            # into a file of this process's own, then renamed: a process
+            # that loads the library never sees another one's half-written
+            tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
             subprocess.run(
-                ["gcc", "-O3", "-shared", "-fPIC", "-o", _SO_PATH, _SRC_PATH],
+                ["gcc", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC_PATH],
                 check=True, capture_output=True,
             )
+            os.replace(tmp, _SO_PATH)
 
         def load():
             lib = ctypes.CDLL(_SO_PATH)
@@ -40,6 +44,10 @@ def _load_native():
                 ctypes.c_char_p, ctypes.c_size_t, ctypes.c_char_p
             ]
             lib.keccak256.restype = None
+            lib.keccak_grind.argtypes = [
+                ctypes.c_char_p, ctypes.c_size_t, ctypes.c_uint
+            ]
+            lib.keccak_grind.restype = ctypes.c_uint64
             return lib
 
         try:
@@ -161,6 +169,17 @@ def keccak256(data: bytes) -> bytes:
         lib.keccak256(bytes(data), len(data), out)
         return out.raw
     return _keccak256_py(bytes(data))
+
+
+def grind(seed: bytes, bits: int) -> int | None:
+    """The smallest nonce whose keccak256(seed || nonce_le8) has `bits`
+    leading zero bits, searched in one native call that releases the
+    interpreter lock (ctypes does so around every call): other threads
+    run while it searches.  None where the native engine is absent."""
+    lib = _load_native()
+    if not lib or len(seed) > 127 or not 0 < bits <= 64:
+        return None
+    return int(lib.keccak_grind(bytes(seed), len(seed), bits))
 
 
 EMPTY_KECCAK = keccak256(b"")  # hash of empty bytes

@@ -43,6 +43,13 @@ class SettlementDivergence(RuntimeError):
 class SequencerConfig:
     block_time: float = 1.0
     commit_interval: float = 2.0
+    # the committer's bound on one batch (reference:
+    # --committer.batch-gas-limit, docs/l2/deployment/vanilla.md:94): a
+    # batch ends at the last whole block whose cumulative gas_used stays
+    # within it, a lone block over it is a batch of its own, and the
+    # blocks left over wait for the next tick.  None: every block up to
+    # the head
+    batch_gas_limit: int | None = None
     proof_send_interval: float = 2.0
     watcher_interval: float = 1.0
     needed_prover_types: tuple = (protocol.PROVER_TPU,)
@@ -520,9 +527,11 @@ class Sequencer:
     def produce_block(self):
         from ..primitives.transaction import TYPE_PRIVILEGED
 
-        with self._lock:
+        with self._lock, tracing.span("seq.block") as sp:
             forced = list(self.pending_privileged)
             block = self.node.produce_block(forced_txs=forced)
+            tracing.set_attrs(sp, txs=len(block.body.transactions),
+                              gas=block.header.gas_used)
             included = {tx.hash for tx in block.body.transactions}
             self.pending_privileged = [
                 tx for tx in self.pending_privileged
@@ -583,11 +592,12 @@ class Sequencer:
             return None
         coarse_log: list = []
         batch_receipts: list = []
-        witness = generate_witness(self.node.chain, blocks,
-                                   write_log=coarse_log,
-                                   receipts_out=batch_receipts)
-        program_input = ProgramInput(blocks=blocks, witness=witness,
-                                     config=self.node.config)
+        with tracing.span("seq.witness"):
+            witness = generate_witness(self.node.chain, blocks,
+                                       write_log=coarse_log,
+                                       receipts_out=batch_receipts)
+            program_input = ProgramInput(blocks=blocks, witness=witness,
+                                         config=self.node.config)
         state_root = blocks[-1].header.state_root
         privileged_hashes = [
             tx.hash for b in blocks for tx in b.body.transactions
@@ -603,7 +613,9 @@ class Sequencer:
         # l1_committer.rs generate_blobs_bundle + blobs_bundle.rs)
         from .blobs import generate_blobs_bundle
 
-        bundle = generate_blobs_bundle(blocks)
+        with tracing.span("seq.blobs") as sp:
+            bundle = generate_blobs_bundle(blocks)
+            tracing.set_attrs(sp, blobs=len(bundle.versioned_hashes))
         commitment = keccak256(
             b"batch" + number.to_bytes(8, "big") + state_root
             + b"".join(b.hash for b in blocks)
@@ -706,34 +718,47 @@ class Sequencer:
         first = self.last_batched_block + 1
         if head < first:
             return None
-        art = self._build_batch_artifacts(number, first, head)
-        if art is None:
-            return None
-        # L1 first: only persist the batch once the commitment is accepted,
-        # otherwise a transient L1 failure would desync the batch counter.
-        # Remember the attempt first: if the L1 accepts it but the
-        # acknowledgment is lost, the rebuild adopts these artifacts
-        # instead of re-deriving the settled range from scratch
-        self._last_commit_attempt = (number, first, art)
-        self._settle_commit(number, art.commitment, art.state_root,
-                            art.privileged_hashes, art.msgs_root,
-                            art.bundle, epoch=epoch)
-        batch = Batch(number=number, first_block=first,
-                      last_block=head, state_root=art.state_root,
-                      commitment=art.commitment, vm_mode=art.vm_mode)
-        # the local batch record is one journaled unit: a crash between
-        # these writes reopens to either the full record or none (and the
-        # none case is exactly the commit-crash window reconciliation
-        # already rebuilds from L1); the group carries the same fencing
-        # token as the L1 leg, so a leader deposed inside the commit
-        # crash-window cannot write a record the new leader won't own
-        with self.rollup.write_group(epoch=epoch):
-            self.rollup.store_batch(batch)
-            self.rollup.store_blobs_bundle(number, art.bundle)
-            self.rollup.store_prover_input(number, self.cfg.commit_hash,
-                                           art.program_input.to_json())
-            self.rollup.set_committed(number, art.commitment)
-        self.last_batched_block = head
+        last = self._batch_end(first, head)
+        # the commit joins the batch's trace, as the proof sender's spans
+        # do (docs/OBSERVABILITY.md "Spans of the sequencer")
+        with tracing.trace_context(self.coordinator.trace_for_batch(number)), \
+                tracing.span("seq.commit", batch=number,
+                             blocks=last - first + 1) as sp:
+            art = self._build_batch_artifacts(number, first, last)
+            if art is None:
+                return None
+            tracing.set_attrs(
+                sp, txs=sum(len(b.body.transactions) for b in art.blocks),
+                gas=sum(b.header.gas_used for b in art.blocks))
+            # L1 first: only persist the batch once the commitment is
+            # accepted, otherwise a transient L1 failure would desync the
+            # batch counter.  Remember the attempt first: if the L1
+            # accepts it but the acknowledgment is lost, the rebuild
+            # adopts these artifacts instead of re-deriving the settled
+            # range from scratch
+            self._last_commit_attempt = (number, first, art)
+            with tracing.span("seq.l1_commit"):
+                self._settle_commit(number, art.commitment, art.state_root,
+                                    art.privileged_hashes, art.msgs_root,
+                                    art.bundle, epoch=epoch)
+            batch = Batch(number=number, first_block=first,
+                          last_block=last, state_root=art.state_root,
+                          commitment=art.commitment, vm_mode=art.vm_mode)
+            # the local batch record is one journaled unit: a crash
+            # between these writes reopens to either the full record or
+            # none (and the none case is exactly the commit-crash window
+            # reconciliation already rebuilds from L1); the group carries
+            # the same fencing token as the L1 leg, so a leader deposed
+            # inside the commit crash-window cannot write a record the
+            # new leader won't own
+            with tracing.span("seq.store"), \
+                    self.rollup.write_group(epoch=epoch):
+                self.rollup.store_batch(batch)
+                self.rollup.store_blobs_bundle(number, art.bundle)
+                self.rollup.store_prover_input(number, self.cfg.commit_hash,
+                                               art.program_input.to_json())
+                self.rollup.set_committed(number, art.commitment)
+            self.last_batched_block = last
         from ..utils.metrics import record_batch
 
         record_batch(number)
@@ -744,11 +769,29 @@ class Sequencer:
             from ..perf.chain_path import CHAIN_PATH
 
             CHAIN_PATH.blocks_batched(
-                number, first, head,
+                number, first, last,
                 trace_id=self.coordinator.trace_for_batch(number))
         except Exception:  # noqa: BLE001 — telemetry only
             pass
         return batch
+
+    def _batch_end(self, first: int, head: int) -> int:
+        """The last block of the batch that starts at `first`: the head,
+        or under `batch_gas_limit` the last whole block whose cumulative
+        gas_used stays within the limit (never before `first`: a lone
+        block over the limit is a batch of its own)."""
+        limit = self.cfg.batch_gas_limit
+        if limit is None:
+            return head
+        gas = 0
+        for n in range(first, head + 1):
+            block = self.node.store.get_canonical_block(n)
+            if block is None:
+                return head     # _build_batch_artifacts refuses the range
+            gas += block.header.gas_used
+            if gas > limit:
+                return max(first, n - 1)
+        return head
 
     def _recommit_batch(self, number: int) -> Batch | None:
         """Re-submit a batch whose L1 commitment a reorg dropped.  The
@@ -889,7 +932,11 @@ class Sequencer:
                 for n in range(first, last + 1)]
         epoch = self._fence()
         faults.inject("l1.verify")
-        self.l1.verify_batches(first, last, proofs, epoch=epoch)
+        # one verifyBatches for the range: its span joins the trace of
+        # the range's first batch
+        with tracing.trace_context(self.coordinator.batch_traces.get(first)), \
+                tracing.span("l1.verify", first=first, last=last):
+            self.l1.verify_batches(first, last, proofs, epoch=epoch)
         faults.inject("l1.verify")
         for n in range(first, last + 1):
             with tracing.trace_context(

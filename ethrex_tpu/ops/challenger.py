@@ -102,7 +102,8 @@ class Challenger:
     # the prover and bound into the transcript before query sampling.  The
     # seed is squeezed from the sponge, so the nonce commits to everything
     # absorbed so far; the 2^bits-hash search runs on keccak (C
-    # extension), not on the Poseidon2 sponge.
+    # extension, one call a search: `keccak.grind`), not on the Poseidon2
+    # sponge.
 
     def _pow_seed(self) -> bytes:
         return b"".join(int(self.sample()).to_bytes(4, "little")
@@ -112,10 +113,14 @@ class Challenger:
         """Find, absorb and return a proof-of-work nonce for `bits`."""
         if bits <= 0:
             return 0
+        from ..crypto import keccak
+
         seed = self._pow_seed()
-        nonce = 0
-        while not pow_ok(seed, nonce, bits):
-            nonce += 1
+        nonce = keccak.grind(seed, bits)
+        if nonce is None:       # no native engine: the same search here
+            nonce = 0
+            while not pow_ok(seed, nonce, bits):
+                nonce += 1
         self.absorb_int(nonce)
         return nonce
 

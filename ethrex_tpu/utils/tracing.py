@@ -46,8 +46,14 @@ BATCH_SPAN_BUDGET = 192
 # batch was not waiting on either, so `critical_path` and the trace
 # summaries leave them out of the wall and of every component: an idle
 # fleet's hours are not prove time, and a second prover's empty polls
-# do not cover a reassigned batch's queue-wait.
-OFF_PATH_SPANS = frozenset(("prover.idle", "prover.ckpt_complete"))
+# do not cover a reassigned batch's queue-wait.  The committer's spans
+# (`seq.commit` and its four children) seal the batch before any prover
+# can ask for it: the batch's lifecycle starts at its first assignment,
+# so a backlog's wait for a prover stays out of `queue-wait`, which
+# alerts on a scheduler that leaves a batch and a prover both waiting.
+OFF_PATH_SPANS = frozenset(("prover.idle", "prover.ckpt_complete",
+                            "seq.commit", "seq.witness", "seq.blobs",
+                            "seq.l1_commit", "seq.store"))
 
 # -- span-shipping wire format (docs/OBSERVABILITY.md "Distributed
 # tracing").  A prover attaches ``export_wire(trace_id)`` to ProofSubmit
@@ -617,7 +623,7 @@ def _component(s: dict) -> str:
         return "transport"
     if name in ("proof.verify", "proof.audit") or name.startswith("aggregate"):
         return "verify"
-    if name == "proof.settle":
+    if name in ("proof.settle", "l1.verify"):
         return "settle"
     if name.startswith("prover.") or name.startswith("bench."):
         return "prove"

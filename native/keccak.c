@@ -8,6 +8,8 @@
  *   void keccak256(const uint8_t *in, size_t len, uint8_t out[32]);
  *   void keccak256_batch(const uint8_t *in, size_t stride, size_t n,
  *                        size_t len, uint8_t *out);   // n msgs, fixed len
+ *   uint64_t keccak_grind(const uint8_t *seed, size_t seed_len,
+ *                         unsigned bits);   // the STARK's proof of work
  */
 
 #include <stdint.h>
@@ -96,4 +98,36 @@ void keccak256_batch(const uint8_t *in, size_t stride, size_t n, size_t len,
                      uint8_t *out) {
     for (size_t k = 0; k < n; k++)
         keccak256(in + k * stride, len, out + 32 * k);
+}
+
+/* The smallest nonce whose keccak256(seed || nonce as 8 little-endian
+ * bytes), read as a big-endian integer, has `bits` leading zero bits:
+ * ops/challenger.pow_ok's predicate, searched from 0 in one call.  The
+ * message is one block (seed_len <= 127); bits <= 64. */
+uint64_t keccak_grind(const uint8_t *seed, size_t seed_len, unsigned bits) {
+    const size_t rate = 136;
+    uint8_t block[136];
+    memset(block, 0, sizeof(block));
+    memcpy(block, seed, seed_len);
+    block[seed_len + 8] = 0x01;
+    block[rate - 1] |= 0x80;
+    uint64_t words[17];
+    for (size_t i = 0; i < rate / 8; i++)
+        memcpy(&words[i], block + 8 * i, 8);
+    const size_t at = seed_len / 8, shift = 8 * (seed_len % 8);
+    /* the digest's first 8 bytes, big-endian, under 2^(64 - bits) */
+    const uint64_t limit = bits == 0 ? 0 : ~0ULL << (64 - bits);
+    for (uint64_t nonce = 0;; nonce++) {
+        uint64_t st[25];
+        memset(st, 0, sizeof(st));
+        for (size_t i = 0; i < rate / 8; i++)
+            st[i] = words[i];
+        /* the nonce's 8 bytes start at byte seed_len */
+        st[at] ^= shift ? nonce << shift : nonce;
+        if (shift)
+            st[at + 1] ^= nonce >> (64 - shift);
+        keccak_f1600(st);
+        if ((__builtin_bswap64(st[0]) & limit) == 0)
+            return nonce;
+    }
 }

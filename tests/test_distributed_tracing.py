@@ -889,11 +889,14 @@ def test_idle_joins_the_batch_that_ended_it(leaf_batch):
 
 def test_batch_critical_path_is_the_batchs_own(leaf_batch):
     """The client idled >= 0.3 s before batch 1 and deleted its
-    checkpoints after the ack: neither is the batch's lifecycle, so the
-    wall, `prove` and the stage components read as without them."""
+    checkpoints after the ack, and the committer sealed the batch before
+    any prover could ask for it: none of it is the batch's lifecycle, so
+    the wall, `prove` and the stage components read as without them."""
     spans = leaf_batch["spans"]
     on_path = [s for s in spans if s["name"] not in tracing.OFF_PATH_SPANS]
-    assert len(spans) - len(on_path) == 2
+    assert sorted(s["name"] for s in spans if s not in on_path) == [
+        "prover.ckpt_complete", "prover.idle", "seq.blobs", "seq.commit",
+        "seq.l1_commit", "seq.store", "seq.witness"]
     cp = critical_path({"traceId": "t", "spans": spans})
     assert cp == critical_path({"traceId": "t", "spans": on_path})
     by_name = {s["name"]: s for s in spans}
