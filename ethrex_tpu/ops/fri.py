@@ -337,25 +337,37 @@ def verify(proof: FriProof, log_n0: int, challenger: Challenger,
     if len(proof.queries) != p_.num_queries:
         raise ValueError("FRI: wrong query count")
 
-    inv2 = bb.inv_host(2)
-    layer0_values = []
-    for q, per_layer in zip(indices, proof.queries):
+    # each query's pair of values at each layer, then every layer's
+    # Merkle openings in one call, then the folds from layer to layer
+    values = []
+    for per_layer in proof.queries:
         if len(per_layer) != num_layers:
             raise ValueError("FRI: wrong layer count in query")
-        carried = None
-        raw = q  # index of the folded value inside the current layer
-        for k, opening in enumerate(per_layer):
-            log_nk = log_n0 - k
-            half = 1 << (log_nk - 1)
-            idx = raw % half
+        pairs = []
+        for opening in per_layer:
             lo, hi = (tuple(int(v) for v in x) for x in opening["values"])
             if len(lo) != 4 or len(hi) != 4:
                 raise ValueError("FRI: opening values must be 4-limb ext elements")
-            if not merkle.verify_opening(
-                proof.roots[k], idx, list(lo) + list(hi), opening["path"],
-                log_nk - 1,
-            ):
-                raise ValueError(f"FRI: bad merkle opening at layer {k}")
+            pairs.append((lo, hi))
+        values.append(pairs)
+    for k in range(num_layers):
+        depth = log_n0 - k - 1
+        if not all(merkle.verify_openings(
+            proof.roots[k], [q % (1 << depth) for q in indices],
+            [pairs[k][0] + pairs[k][1] for pairs in values],
+            [per_layer[k]["path"] for per_layer in proof.queries], depth,
+        )):
+            raise ValueError(f"FRI: bad merkle opening at layer {k}")
+
+    inv2 = bb.inv_host(2)
+    layer0_values = []
+    for q, pairs in zip(indices, values):
+        carried = None
+        raw = q  # index of the folded value inside the current layer
+        for k, (lo, hi) in enumerate(pairs):
+            log_nk = log_n0 - k
+            half = 1 << (log_nk - 1)
+            idx = raw % half
             if carried is not None:
                 got = lo if raw < half else hi
                 if got != carried:

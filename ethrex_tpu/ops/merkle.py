@@ -8,6 +8,8 @@ tree level as one batched compression call (perfect VPU vectorization); proofs
 
 from __future__ import annotations
 
+import threading
+
 import numpy as np
 
 from . import babybear as bb
@@ -186,24 +188,70 @@ def fold_path_canonical(index: int, leaf_digest, path):
     return cur
 
 
-def verify_opening(root_c, index: int, leaf_values_c, path_c, depth: int) -> bool:
-    """Fully canonical opening check: hash leaf values, fold, compare.
+_FOLDED = threading.local()   # .n: this thread's `openings_folded`
 
-    root_c / path_c / leaf_values_c are canonical ints (what proofs carry on
-    the wire); `depth` binds the path length.  Malformed input (wrong sibling
-    width, non-int limbs) returns False — never raises — since this runs on
-    untrusted proof data.
-    """
+
+def verify_openings(root_c, indices, leaves_c, paths_c,
+                    depth: int) -> list[bool]:
+    """Fully canonical check of k openings of one tree: hash each leaf
+    row, fold it up its path, compare with the root.  One verdict per
+    opening, in order.
+
+    root_c / paths_c / leaves_c are canonical ints (what proofs carry on
+    the wire); `depth` binds the path length.  This runs on untrusted
+    proof data, so each opening is checked here first (path length,
+    sibling width, int limbs, reduced mod p): a malformed one is False,
+    never an exception, and never reaches the native engine, which
+    hashes and folds the rest in one call (`p2.native_merkle_roots`;
+    `fold_path_canonical(hash_leaf_ref(...))` where it did not load).
+    The leaf rows are of one length: the caller checks the width its
+    tree commits to."""
+    _FOLDED.n = openings_folded() + len(indices)
+    verdicts = [False] * len(indices)
     try:
-        if len(path_c) != depth or len(root_c) != DIGEST_WIDTH:
-            return False
-        if any(len(sib) != DIGEST_WIDTH for sib in path_c):
-            return False
-        digest = hash_leaf_ref(leaf_values_c)
-        folded = fold_path_canonical(index, digest, path_c)
-        return folded == [int(x) % bb.P for x in root_c]
+        if len(root_c) != DIGEST_WIDTH:
+            return verdicts
+    except TypeError:
+        return verdicts
+    good, rows, sibs = [], [], []
+    for i, (leaf, path) in enumerate(zip(leaves_c, paths_c)):
+        try:
+            if len(path) != depth or any(len(sib) != DIGEST_WIDTH
+                                         for sib in path):
+                continue
+            row = [int(x) % bb.P for x in leaf]
+            path_c = [[int(x) % bb.P for x in sib] for sib in path]
+        except (TypeError, ValueError):
+            continue
+        good.append(i)
+        rows.append(row)
+        sibs.append(path_c)
+    if not good:
+        return verdicts
+    try:
+        root = [int(x) % bb.P for x in root_c]
     except (TypeError, ValueError):
-        return False
+        return verdicts
+    idx = [indices[i] for i in good]
+    folded = p2.native_merkle_roots(
+        np.array(rows, dtype=np.uint32).reshape(len(good), -1), idx,
+        np.array(sibs, dtype=np.uint32).reshape(len(good), depth,
+                                                DIGEST_WIDTH))
+    if folded is None:
+        folded = [fold_path_canonical(j, hash_leaf_ref(row), path)
+                  for j, row, path in zip(idx, rows, sibs)]
+    else:
+        folded = folded.tolist()
+    for i, got in zip(good, folded):
+        verdicts[i] = got == root
+    return verdicts
+
+
+def openings_folded() -> int:
+    """How many openings this thread's `verify_openings` calls have
+    checked so far: a caller reads it before and after a proof's check
+    (attribute `openings` of the proof sender's `proof.verify` span)."""
+    return getattr(_FOLDED, "n", 0)
 
 
 def hash_leaf_ref(leaf) -> list[int]:

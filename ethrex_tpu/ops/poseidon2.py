@@ -19,11 +19,13 @@ chain from the Poseidon2 paper.  Internal layer: M_I = J + diag(mu)
 Everything is element-wise uint32 VPU work; a batch of states of shape
 (B, 16) vectorizes perfectly and XLA fuses the whole permutation.
 
-The host permutation (`permute_ref`, and the AIR trace rows through
-`native_trace`) runs in native/poseidon2.c, compiled on first use and
-loaded with ctypes as crypto/keccak.py loads its engine; the Python
-bodies (`_permute_py`, `models/poseidon2_air._generate_trace_py`) are the
-fallback where no toolchain is, and the oracle the tests hold it to.
+The host permutation (`permute_ref`, the AIR trace rows through
+`native_trace`, and the verifier's Merkle openings through
+`native_merkle_roots`) runs in native/poseidon2.c, compiled on first use
+and loaded with ctypes as crypto/keccak.py loads its engine; the Python
+bodies (`_permute_py`, `models/poseidon2_air._generate_trace_py`,
+`merkle.fold_path_canonical`) are the fallback where no toolchain is,
+and the oracle the tests hold it to.
 """
 
 from __future__ import annotations
@@ -137,6 +139,9 @@ def _load_native():
             lib.p2_init.restype = None
             lib.p2_trace.argtypes = [ctypes.c_void_p] * 2
             lib.p2_trace.restype = None
+            u64, ptr = ctypes.c_uint64, ctypes.c_void_p
+            lib.p2_merkle_roots.argtypes = [ptr, u64, ptr, ptr, u64, u64, ptr]
+            lib.p2_merkle_roots.restype = None
             # the constants stay defined once, above: C keeps a copy
             consts = [np.ascontiguousarray(c, dtype=np.uint32)
                       for c in (EXT_RC, INT_RC, DIAG_MU)]
@@ -150,8 +155,9 @@ def _load_native():
                 build()
             try:
                 _lib = load()
-            except OSError:
-                # stale/foreign binary (different arch) — rebuild once
+            except (OSError, AttributeError):
+                # stale/foreign binary (different arch, or older than an
+                # entry point) — rebuild once
                 build()
                 _lib = load()
         except (OSError, subprocess.CalledProcessError):
@@ -163,6 +169,14 @@ def available() -> bool:
     """True when the native Poseidon2 engine loaded (every native wrapper
     exposes this probe; lint-enforced in tests/test_tooling.py)."""
     return bool(_load_native())
+
+
+def engine() -> str:
+    """Which host Poseidon2 runs, "native" or "python": attribute `p2`
+    of `prove.trace_gen` and `prove.vm_batch` and `fold` of
+    `proof.verify`, so that a fallback to Python does not pass for a
+    regression of unknown cause."""
+    return "native" if available() else "python"
 
 
 def native_trace(limbs) -> np.ndarray | None:
@@ -178,6 +192,33 @@ def native_trace(limbs) -> np.ndarray | None:
     rows = np.empty((_TRACE_ROWS, WIDTH), dtype=np.uint32)
     lib.p2_trace(_State(*s), rows.ctypes.data)
     return rows
+
+
+def native_merkle_roots(leaves, indices, siblings) -> np.ndarray | None:
+    """The roots that k openings of one Merkle tree reach, from the
+    native engine in one call, or None where it did not load.
+
+    `leaves` (k, L) leaf rows, `indices` (k,) leaf indices, `siblings`
+    (k, depth, 8) sibling digests bottom-up, every limb canonical (the
+    caller reduces and checks shapes: this runs no validation of
+    untrusted input).  Row i hashes and folds as
+    `merkle.fold_path_canonical(indices[i], merkle.hash_leaf_ref(
+    leaves[i]), siblings[i])`; returns (k, 8) uint32.  ctypes drops the
+    interpreter lock for the call."""
+    lib = _load_native()
+    if not lib:
+        return None
+    leaves = np.ascontiguousarray(leaves, dtype=np.uint32)
+    idx = np.ascontiguousarray(indices, dtype=np.uint64)
+    sibs = np.ascontiguousarray(siblings, dtype=np.uint32)
+    k, leaf_len = leaves.shape
+    depth = sibs.shape[1]
+    if idx.shape != (k,) or sibs.shape != (k, depth, RATE):
+        raise ValueError("Merkle openings of mismatched shapes")
+    roots = np.empty((k, RATE), dtype=np.uint32)
+    lib.p2_merkle_roots(leaves.ctypes.data, leaf_len, idx.ctypes.data,
+                        sibs.ctypes.data, depth, k, roots.ctypes.data)
+    return roots
 
 
 def permute_ref(state):

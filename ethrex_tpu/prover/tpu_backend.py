@@ -84,6 +84,7 @@ from ..guest.execution import ProgramInput, execution_program
 from ..models import poseidon2_air as pair
 from ..models import state_update_air as sua
 from ..ops import babybear as bb
+from ..ops import merkle
 from ..ops import poseidon2 as p2
 from ..stark import prover as stark_prover
 from ..stark import verifier as stark_verifier
@@ -394,18 +395,11 @@ def expected_vm_mode(program_input: ProgramInput) -> str:
                                   output.initial_state_root)
 
 
-def _p2_path() -> str:
-    """Which host Poseidon2 ran (attribute `p2` of `prove.trace_gen` and
-    `prove.vm_batch`): a fallback to Python must not pass for a
-    regression of unknown cause."""
-    return "native" if p2.available() else "python"
-
-
 def _traced_gen(air_name: str, generate, *args):
     """A job's trace generation (host numpy) under its leaf span.  No
     `stage=`: it runs inside the job's own stage span."""
     with tracing.span("prove.trace_gen", air=air_name,
-                      p2=_p2_path()) as sp:
+                      p2=p2.engine()) as sp:
         trace = generate(*args)
         tracing.set_attrs(sp, rows=int(trace.shape[0]),
                           width=int(trace.shape[1]))
@@ -770,7 +764,7 @@ class TpuBackend(ProverBackend):
         tracing.record_span(
             "prove.vm_batch", vmb_wall0, vmb_seconds,
             mode="claimed" if vm_batch is None else _mode_of(vm_batch),
-            p2=_p2_path(),
+            p2=p2.engine(),
             txs=sum(len(b.body.transactions)
                     for b in program_input.blocks),
             tok_calls=0 if vm_batch is None else len(vm_batch.tok_segs),
@@ -995,7 +989,17 @@ class TpuBackend(ProverBackend):
 
     def _check(self, proof: dict):
         """Shared verification core; returns the parsed raw log + claimed
-        output bytes, or raises."""
+        output bytes, or raises.  The span it runs in (the proof sender's
+        `proof.verify`) gets `fold`, the engine of the STARKs' Merkle
+        openings, and `openings`, how many this check folded."""
+        folded = merkle.openings_folded()
+        try:
+            return self._check_starks(proof)
+        finally:
+            tracing.set_attrs(tracing.current_span(), fold=p2.engine(),
+                              openings=merkle.openings_folded() - folded)
+
+    def _check_starks(self, proof: dict):
         airs, proofs, blocks_log, encoded = self._reconstruct(proof)
 
         agg_info = proof.get("aggregate")

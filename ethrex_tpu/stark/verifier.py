@@ -159,12 +159,12 @@ def _verify(air: Air, proof: dict, params: StarkParams,
     except ValueError as e:
         _fail(str(e))
 
-    # ---- DEEP cross-check at each query ----------------------------------
+    # ---- openings: Merkle paths per tree, then the DEEP cross-check -----
     openings = proof["openings"]
     if len(openings) != len(indices):
         _fail("opening count != query count")
     half = N // 2
-    zeta_g = ext.h_mul(zeta, ext.h_from_base(g_n))
+    idxs, t_rows, q_rows, t_paths, q_paths, fri_vals = [], [], [], [], [], []
     for (q, (pair_idx, fri_lo, fri_hi)), entry in zip(
         zip(indices, layer0), openings
     ):
@@ -175,35 +175,83 @@ def _verify(air: Air, proof: dict, params: StarkParams,
             q_row = [int(v) for v in entry[f"quotient_{tag}"]]
             if len(t_row) != w or len(q_row) != B * 4:
                 _fail("bad opening row width")
-            if not merkle.verify_opening(
-                proof["trace_root"], idx, t_row,
-                entry[f"trace_{tag}_path"], log_N,
-            ):
-                _fail("bad trace opening")
-            if not merkle.verify_opening(
-                proof["quotient_root"], idx, q_row,
-                entry[f"quotient_{tag}_path"], log_N,
-            ):
-                _fail("bad quotient opening")
-            x = shift * pow(g_N, idx, bb.P) % bb.P
-            x_h = ext.h_from_base(x)
-            inv_xz = ext.h_inv(ext.h_sub(x_h, zeta))
-            inv_xzg = ext.h_inv(ext.h_sub(x_h, zeta_g))
-            val = ext.ZERO_H
-            g_pow = ext.ONE_H
-            for j in range(w):
-                diff = ext.h_sub(ext.h_from_base(t_row[j]), t_at_z[j])
-                val = ext.h_add(val, ext.h_mul(g_pow, ext.h_mul(inv_xz, diff)))
-                g_pow = ext.h_mul(g_pow, gamma)
-            for j in range(w):
-                diff = ext.h_sub(ext.h_from_base(t_row[j]), t_at_zg[j])
-                val = ext.h_add(val, ext.h_mul(g_pow, ext.h_mul(inv_xzg, diff)))
-                g_pow = ext.h_mul(g_pow, gamma)
-            for i in range(B):
-                q_val = tuple(q_row[i * 4 + k] for k in range(4))
-                diff = ext.h_sub(q_val, q_at_z[i])
-                val = ext.h_add(val, ext.h_mul(g_pow, ext.h_mul(inv_xz, diff)))
-                g_pow = ext.h_mul(g_pow, gamma)
-            if val != tuple(fri_val):
-                _fail("DEEP value mismatch with FRI layer 0")
+            idxs.append(idx)
+            t_rows.append(t_row)
+            q_rows.append(q_row)
+            t_paths.append(entry[f"trace_{tag}_path"])
+            q_paths.append(entry[f"quotient_{tag}_path"])
+            fri_vals.append(tuple(fri_val))
+    if not all(merkle.verify_openings(proof["trace_root"], idxs, t_rows,
+                                      t_paths, log_N)):
+        _fail("bad trace opening")
+    if not all(merkle.verify_openings(proof["quotient_root"], idxs, q_rows,
+                                      q_paths, log_N)):
+        _fail("bad quotient opening")
+    xs = [shift * pow(g_N, idx, bb.P) % bb.P for idx in idxs]
+    deep = deep_values(xs, t_rows, q_rows, t_at_z, t_at_zg, q_at_z, gamma,
+                       zeta, ext.h_mul(zeta, ext.h_from_base(g_n)))
+    for val, fri_val in zip(deep, fri_vals):
+        if val != fri_val:
+            _fail("DEEP value mismatch with FRI layer 0")
     return True
+
+
+# x^b as an ext element: a quotient opening's limb b multiplies x^b * gamma^i
+_X_POWS = tuple(tuple(int(b == j) for j in range(4)) for b in range(4))
+
+
+def _canonical(rows: list, width: int) -> np.ndarray:
+    """Rows of wire ints as a (k, width) uint64 array of residues."""
+    return np.array([[v % bb.P for v in row] for row in rows],
+                    dtype=np.uint64).reshape(len(rows), width)
+
+
+def _base_dot_ext(rows: np.ndarray, coeffs: list) -> list:
+    """sum_j rows[i, j] * coeffs[j] for each row i: canonical base-field
+    rows against ext tuples, as k 4-limb tuples.  Each product (< 2^62)
+    is reduced before the sum, so a sum of up to 2^32 terms stays exact
+    in uint64."""
+    c = np.array(coeffs, dtype=np.uint64).reshape(len(coeffs), 4)
+    sums = (rows[:, :, None] * c[None] % bb.P).sum(axis=1) % bb.P
+    return [tuple(row) for row in sums.tolist()]
+
+
+def deep_values(xs, t_rows, q_rows, t_at_z, t_at_zg, q_at_z, gamma, zeta,
+                zeta_g) -> list:
+    """The DEEP quotient's value at each opened point x, exactly
+    sum_j g^j (t_j - a_j)/(x - z) + g^(w+j) (t_j - b_j)/(x - zg)
+    + sum_i g^(2w+i) (q_i - c_i)/(x - z), for gamma g, the trace row t
+    and quotient row q opened at x, and the openings a, b, c at z and
+    zg.  Re-associated: the gamma-weighted sums of a, b, c are the same
+    for every x and come once; what is left per x is base-by-ext dot
+    products (`_base_dot_ext`, all rows at once) and a fixed handful of
+    ext operations, whatever the width."""
+    w, nq = len(t_at_z), len(q_at_z)
+    g_pows = [ext.ONE_H]
+    for _ in range(2 * w + nq - 1):
+        g_pows.append(ext.h_mul(g_pows[-1], gamma))
+    g_z, g_zg, g_q = g_pows[:w], g_pows[w:2 * w], g_pows[2 * w:]
+
+    def weighted(ws, vals):
+        acc = ext.ZERO_H
+        for g, v in zip(ws, vals):
+            acc = ext.h_add(acc, ext.h_mul(g, v))
+        return acc
+
+    at_z = ext.h_add(weighted(g_z, t_at_z), weighted(g_q, q_at_z))
+    at_zg = weighted(g_zg, t_at_zg)
+    # a quotient row is nq ext elements of 4 base limbs each
+    q_coeffs = [ext.h_mul(g, xb) for g in g_q for xb in _X_POWS]
+    t = _canonical(t_rows, w)
+    d_z = _base_dot_ext(t, g_z)
+    d_zg = _base_dot_ext(t, g_zg)
+    d_q = _base_dot_ext(_canonical(q_rows, 4 * nq), q_coeffs)
+    out = []
+    for x, dz, dzg, dq in zip(xs, d_z, d_zg, d_q):
+        x_h = ext.h_from_base(x)
+        inv_xz = ext.h_inv(ext.h_sub(x_h, zeta))
+        inv_xzg = ext.h_inv(ext.h_sub(x_h, zeta_g))
+        out.append(ext.h_add(
+            ext.h_mul(inv_xz, ext.h_sub(ext.h_add(dz, dq), at_z)),
+            ext.h_mul(inv_xzg, ext.h_sub(dzg, at_zg))))
+    return out

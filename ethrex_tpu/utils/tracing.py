@@ -146,6 +146,19 @@ def current() -> "tuple[str, str | None] | None":
         return None
 
 
+def current_span() -> "Span | None":
+    """The innermost span this thread has open, or None (also where a
+    `trace_context` was entered inside it): for attributes that the
+    code a span wraps knows and its opener does not."""
+    try:
+        cur, opened = current(), getattr(_ctx, "open", None)
+        if cur and opened and opened[-1].span_id == cur[1]:
+            return opened[-1]
+    except Exception:
+        pass
+    return None
+
+
 def current_trace_id() -> "str | None":
     cur = current()
     return cur[0] if cur else None
@@ -430,6 +443,10 @@ class span:
             st.append((trace_id, sp.span_id))
             self._pushed = True
             self._span = sp
+            opened = getattr(_ctx, "open", None)
+            if opened is None:
+                opened = _ctx.open = []
+            opened.append(sp)
         except Exception:
             self._span = None
         return self._span
@@ -442,6 +459,9 @@ class span:
                     st.pop()
             sp = self._span
             if sp is not None:
+                opened = getattr(_ctx, "open", None)
+                if opened and opened[-1] is sp:
+                    opened.pop()
                 sp.seconds = time.perf_counter() - sp._t0
                 if exc is not None:
                     sp.status = "error"

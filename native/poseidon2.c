@@ -12,12 +12,18 @@
  *   void p2_trace(const uint32_t in[16], uint32_t rows[32*16]);
  *       row 0 = M_E(in), row r+1 = round r of row r (r = 0..20),
  *       row 21 = P(in), rows 22..31 = copies of row 21
+ *   void p2_merkle_roots(const uint32_t *leaves, uint64_t leaf_len,
+ *                        const uint64_t *indices, const uint32_t *sibs,
+ *                        uint64_t depth, uint64_t k, uint32_t *roots);
+ *       the roots that k openings of one Merkle tree reach (see below)
  */
 
+#include <stddef.h>
 #include <stdint.h>
 
 #define P 2013265921ULL /* 15 * 2^27 + 1 */
 #define WIDTH 16
+#define RATE 8
 #define HALF_F 4
 #define ROUNDS_F 8
 #define ROUNDS_P 13
@@ -88,25 +94,72 @@ static void store(uint32_t *row, const uint64_t s[WIDTH]) {
         row[i] = (uint32_t)s[i];
 }
 
+/* The permutation of s in place.  With `rows`, row 0 gets M_E(s) and
+ * row r+1 the state after round r (r = 0..20); without, nothing is
+ * stored. */
+static void permute(uint64_t s[WIDTH], uint32_t *rows) {
+    int row = 0;
+    external_linear(s);
+    if (rows)
+        store(rows, s);
+    for (int r = 0; r < HALF_F; r++) {
+        external_round(s, r);
+        if (rows)
+            store(rows + ++row * WIDTH, s);
+    }
+    for (int r = 0; r < ROUNDS_P; r++) {
+        internal_round(s, r);
+        if (rows)
+            store(rows + ++row * WIDTH, s);
+    }
+    for (int r = HALF_F; r < ROUNDS_F; r++) {
+        external_round(s, r);
+        if (rows)
+            store(rows + ++row * WIDTH, s);
+    }
+}
+
 void p2_trace(const uint32_t *in, uint32_t *rows) {
     uint64_t s[WIDTH];
     for (int i = 0; i < WIDTH; i++)
         s[i] = in[i] % P;
-    external_linear(s);
-    int row = 0;
-    store(rows, s);
-    for (int r = 0; r < HALF_F; r++) {
-        external_round(s, r);
-        store(rows + ++row * WIDTH, s);
-    }
-    for (int r = 0; r < ROUNDS_P; r++) {
-        internal_round(s, r);
-        store(rows + ++row * WIDTH, s);
-    }
-    for (int r = HALF_F; r < ROUNDS_F; r++) {
-        external_round(s, r);
-        store(rows + ++row * WIDTH, s);
-    }
-    while (++row < ROWS)
+    permute(s, rows);
+    for (int row = ROUNDS_F + ROUNDS_P + 1; row < ROWS; row++)
         store(rows + row * WIDTH, s);
+}
+
+/* k openings of one Merkle tree, each to the root it reaches.  Opening i
+ * sponge-hashes its leaf row leaves[i*leaf_len ..] as
+ * ops/merkle.hash_leaf_ref does (RATE limbs absorbed a permutation, the
+ * last chunk zero-padded), then folds the digest up its siblings
+ * sibs[(i*depth + d)*RATE ..], bottom-up, as fold_path_canonical does:
+ * the node is the right child where bit d of indices[i] is 1, and a
+ * compression is P(left || right)[:RATE] + left.  roots[i*RATE ..] gets
+ * the result.  Every limb must be canonical (< p): the caller reduces. */
+void p2_merkle_roots(const uint32_t *leaves, uint64_t leaf_len,
+                     const uint64_t *indices, const uint32_t *sibs,
+                     uint64_t depth, uint64_t k, uint32_t *roots) {
+    for (uint64_t i = 0; i < k; i++) {
+        const uint32_t *leaf = leaves + i * leaf_len;
+        uint64_t node[WIDTH] = {0};
+        for (uint64_t off = 0; off < leaf_len; off += RATE) {
+            for (uint64_t j = 0; j < RATE && off + j < leaf_len; j++)
+                node[j] = (node[j] + leaf[off + j]) % P;
+            permute(node, NULL);
+        }
+        uint64_t idx = indices[i];
+        const uint32_t *sib = sibs + i * depth * RATE;
+        for (uint64_t d = 0; d < depth; d++, sib += RATE, idx >>= 1) {
+            uint64_t s[WIDTH], left[RATE];
+            for (int j = 0; j < RATE; j++) {
+                s[j] = left[j] = (idx & 1) ? sib[j] : node[j];
+                s[RATE + j] = (idx & 1) ? node[j] : sib[j];
+            }
+            permute(s, NULL);
+            for (int j = 0; j < RATE; j++)
+                node[j] = (s[j] + left[j]) % P;
+        }
+        for (int j = 0; j < RATE; j++)
+            roots[i * RATE + j] = (uint32_t)node[j];
+    }
 }

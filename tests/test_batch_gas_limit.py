@@ -220,6 +220,53 @@ def test_the_stack_is_spanned_into_the_batch_traces():
         chain.seq.stop()
 
 
+def test_proof_verify_says_how_its_openings_were_folded(monkeypatch):
+    """`proof.verify` carries `fold`, the engine of the Merkle openings,
+    and `openings`, how many the STARK backend's check folded; the
+    sequencer names neither, so an exec proof's span has none.  Here
+    batch 2's audit runs through the STARK backend's check, stood in by
+    one that folds three openings."""
+    from ethrex_tpu.ops import merkle, poseidon2
+    from ethrex_tpu.prover.backend import ExecBackend
+    from ethrex_tpu.prover.tpu_backend import TpuBackend
+
+    def folding(self, proof):
+        merkle.verify_openings([0] * 8, [0, 1, 2], [[1]] * 3, [[]] * 3, 0)
+        return None, b""
+
+    monkeypatch.setattr(TpuBackend, "_check_starks", folding)
+    verify = ExecBackend.verify
+
+    def audited(self, proof):
+        sp = tracing.current_span()
+        if sp is not None and sp.name == "proof.verify" \
+                and sp.attrs["batch"] == 2:
+            assert TpuBackend().verify(proof)
+        return verify(self, proof)
+
+    monkeypatch.setattr(ExecBackend, "verify", audited)
+    chain = Chain(limit=TRANSFER)
+    chain.seq.coordinator.start()
+    try:
+        chain.blocks(1, 1)
+        assert len(chain.commit_all()) == 2
+        client = ProverClient(EXEC, [("127.0.0.1",
+                                      chain.seq.coordinator.port)])
+        assert client.poll_once() == 1 and client.poll_once() == 1
+        assert chain.seq.send_proofs() == (1, 2)
+        attrs = {}
+        for n in (1, 2):
+            trace = chain.seq.coordinator.batch_traces[n]
+            (span,) = [s for s in TRACER.get_trace(trace)["spans"]
+                       if s["name"] == "proof.verify"]
+            attrs[n] = span["attrs"]
+        assert "fold" not in attrs[1] and "openings" not in attrs[1]
+        assert attrs[2]["fold"] == poseidon2.engine() == "native"
+        assert attrs[2]["openings"] == 3
+    finally:
+        chain.seq.stop()
+
+
 def test_spans_never_fail_a_commit_or_a_settlement(monkeypatch):
     """Tracing that breaks inside is telemetry lost, not a batch lost."""
     def broken(*args, **kwargs):
